@@ -25,6 +25,8 @@ selected sub-heads. TPU-first decisions here:
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -70,6 +72,7 @@ class LSTMCell(nn.Module):
     features: int
     dtype: jnp.dtype = jnp.bfloat16
     impl: str = "auto"  # ops/lstm.py dispatcher: auto|scan|pallas|pallas_interpret
+    mesh: Optional[object] = None  # mesh of the surrounding jit (unroll only)
 
     @nn.compact
     def __call__(
@@ -86,7 +89,9 @@ class LSTMCell(nn.Module):
             z = x_proj + h.astype(dt) @ w_h.astype(dt)
             new_c, new_h = L.gates(z, c)
             return (new_c, new_h), new_h
-        h_seq, (c_T, h_T) = L.lstm_recurrence(x_proj, w_h.astype(dt), c, h, impl=self.impl)
+        h_seq, (c_T, h_T) = L.lstm_recurrence(
+            x_proj, w_h.astype(dt), c, h, impl=self.impl, mesh=self.mesh
+        )
         return (c_T, h_T), h_seq
 
 
@@ -168,6 +173,7 @@ class PolicyCore(nn.Module):
     matmuls; only the recurrence (ops/lstm.py) walks the time axis."""
 
     cfg: PolicyConfig
+    mesh: Optional[object] = None
 
     @nn.compact
     def __call__(
@@ -178,9 +184,9 @@ class PolicyCore(nn.Module):
 
         # LSTM output stays f32: every head computes in f32, so a bf16
         # round-trip here would be pure precision loss.
-        carry, out = LSTMCell(cfg.lstm_hidden, dtype=_dtype(cfg), impl=cfg.lstm_impl, name="lstm")(
-            carry, trunk, unroll=unroll
-        )
+        carry, out = LSTMCell(
+            cfg.lstm_hidden, dtype=_dtype(cfg), impl=cfg.lstm_impl, mesh=self.mesh, name="lstm"
+        )(carry, trunk, unroll=unroll)
         return carry, action_heads(cfg, out, unit_emb, obs)
 
 
@@ -195,12 +201,15 @@ class PolicyNet(nn.Module):
     the time axis only exists inside the temporal core). cfg.arch picks
     the core: "lstm" (flagship) or "transformer" (long-context family —
     models/transformer_policy.py; its unroll ignores `state`, context is
-    chunk-local). `sp_mesh` is only read by the transformer family's
-    unroll, to ring-shard the time axis over cfg.tf_sp_axis.
+    chunk-local). `mesh` is the mesh of the jit the unroll is traced
+    under (parallel/train_step.py); only the temporal cores read it —
+    the LSTM family to shard_map its kernel over `dp`, the transformer
+    family to shard the time axis over cfg.tf_sp_axis when that names
+    one of its axes.
     """
 
     cfg: PolicyConfig
-    sp_mesh: Optional[object] = None  # jax.sharding.Mesh; None = no SP
+    mesh: Optional[object] = None  # jax.sharding.Mesh
 
     def _assert_shapes(self, obs: F.Observation) -> None:
         assert obs.unit_feats.shape[-2:] == (F.MAX_UNITS, F.UNIT_FEATURES)
@@ -213,8 +222,8 @@ class PolicyNet(nn.Module):
             # shared trunk/heads.
             from dotaclient_tpu.models.transformer_policy import TransformerPolicyCore
 
-            return TransformerPolicyCore(self.cfg, self.sp_mesh, name="core")(state, obs, unroll)
-        return PolicyCore(self.cfg, name="core")(state, obs, unroll)
+            return TransformerPolicyCore(self.cfg, self.mesh, name="core")(state, obs, unroll)
+        return PolicyCore(self.cfg, self.mesh, name="core")(state, obs, unroll)
 
 def initial_state(cfg: PolicyConfig, batch_shape):
     """Fresh temporal state without needing a module instance (host-side
@@ -256,9 +265,16 @@ def reset_between_chunks(cfg: PolicyConfig, state):
     return state
 
 
-def init_params(cfg: PolicyConfig, rng: jax.Array):
-    """Initialize parameters with a dummy single-step batch of 1."""
-    net = PolicyNet(cfg)
+@functools.partial(jax.jit, static_argnums=0)
+def _init_params(cfg_fields: tuple, rng: jax.Array):
+    cfg = PolicyConfig(*cfg_fields)
     obs = jax.tree.map(lambda x: jnp.asarray(x)[None], F.zeros_observation())
-    state = initial_state(cfg, (1,))
-    return net.init(rng, state, obs)
+    return PolicyNet(cfg).init(rng, initial_state(cfg, (1,)), obs)
+
+
+def init_params(cfg: PolicyConfig, rng: jax.Array):
+    """Initialize parameters with a dummy single-step batch of 1. ONE
+    jitted program per policy shape (the config's fields are the static
+    key): run op by op, flax's init compiles a few hundred one-op
+    programs — most of a binary's boot on any backend."""
+    return _init_params(dataclasses.astuple(cfg), rng)

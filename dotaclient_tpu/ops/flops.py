@@ -90,35 +90,36 @@ def train_step_flops(cfg: LearnerConfig) -> float:
     return (3.0 * R + 1.0) * fwd
 
 
-# Peak dense bf16 FLOP/s for known TPU generations (public spec sheets);
-# MFU is only reported when the device maps to an entry here.
+# Peak dense bf16 FLOP/s of one chip, keyed by `device.device_kind` as
+# JAX reports it (Google Cloud TPU documentation, per-generation pages).
 PEAK_BF16_FLOPS = {
-    "v5 lite": 197e12,  # TPU v5e
-    "v5e": 197e12,
-    "v4": 275e12,
-    "v5p": 459e12,
-    "v6 lite": 918e12,  # Trillium
-    "v6e": 918e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5": 459e12,  # v5p
+    "TPU v6 lite": 918e12,  # v6e (Trillium)
 }
 
 
-def peak_flops_for(device_str: str) -> float | None:
-    s = device_str.lower()
-    for key, peak in PEAK_BF16_FLOPS.items():
-        if key in s:
-            return peak
-    return None
+def peak_flops_for(device) -> float | None:
+    """Peak bf16 FLOP/s of one jax device. None off the TPU, where MFU
+    is not asked for; a TPU whose device_kind has no entry is an error —
+    a silent None there reads as "MFU not wanted" in every artifact."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return PEAK_BF16_FLOPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s entry for TPU device_kind {device.device_kind!r}: "
+            f"add it to ops/flops.py PEAK_BF16_FLOPS with its source"
+        ) from None
 
 
 def aggregate_peak_flops(devices) -> float | None:
     """Total peak FLOP/s over a device list — the MFU denominator for a
     program spanning all of them (obs/compute.py MfuAccountant, bench).
-    None when any device has no table entry (CPU smoke, unknown TPU gen):
-    partial-fleet MFU would overstate utilization, so report none."""
-    total = 0.0
-    for d in devices:
-        peak = peak_flops_for(str(d))
-        if peak is None:
-            return None
-        total += peak
-    return total or None
+    None when the devices are not TPUs (CPU smoke)."""
+    peaks = [peak_flops_for(d) for d in devices]
+    if not peaks or None in peaks:
+        return None
+    return sum(peaks)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 LSTMState = Tuple[jnp.ndarray, jnp.ndarray]  # (c, h), each [B, H] f32
 
@@ -244,30 +245,57 @@ _lstm_pallas.defvjp(_lstm_pallas_fwd, _lstm_pallas_bwd)
 # Dispatcher.
 
 
-def _pallas_ok(x_proj) -> bool:
-    B, T, H4 = x_proj.shape
-    return _block_b(B, T, H4 // 4, x_proj.dtype.itemsize) > 0
+def resolve_impl(impl: str, x_shape, itemsize: int, mesh=None) -> str:
+    """What "auto" means for a GLOBAL x_proj shape [B, T, 4H]: the fused
+    kernel when the program runs on a TPU (the mesh's devices when a
+    mesh is given, else the default backend), H lies in the window
+    LSTM_BENCH.json covers (128 <= H < 512, B=256 T=16 bf16 on a
+    TPU v5 lite), W_h is whole on every device (a `tp` axis > 1 shards
+    its 4H axis, parallel/mesh.py) and the per-device batch slab fits
+    VMEM; lax.scan otherwise. Any other `impl` is returned as asked —
+    an explicit choice never gives way."""
+    if impl != "auto":
+        return impl
+    B, T, H4 = x_shape
+    H = H4 // 4
+    axes = dict(mesh.shape) if mesh is not None else {}
+    platform = mesh.devices.flat[0].platform if mesh is not None else jax.default_backend()
+    fits = _block_b(B // axes.get("dp", 1), T, H, itemsize) > 0
+    kernel = platform == "tpu" and 128 <= H < 512 and axes.get("tp", 1) == 1 and fits
+    return "pallas" if kernel else "scan"
 
 
-def lstm_recurrence(x_proj, w_h, c0, h0, impl: str = "auto"):
-    """Dispatch: "auto" uses the fused kernel on TPU when the block fits
-    VMEM, else lax.scan. "pallas_interpret" runs the kernel in interpret
-    mode (CPU tests)."""
-    if impl == "auto":
-        # Threshold provenance: LSTM_BENCH.json, measured ON SILICON
-        # (TPU v5 lite, 2026-07-30, B=256 T=16 bf16): pallas fwd+bwd
-        # 18.5µs vs scan 29.9µs at H=128, 18.9 vs 21.8 at H=256, tie at
-        # H=512 (25.3 vs 25.1). The kernel therefore serves the flagship
-        # H=128 hot path; above the measured range scan is at parity and
-        # avoids untested VMEM geometries. Re-run scripts/bench_lstm.py
-        # to regenerate the artifact before moving these bounds.
-        on_tpu = jax.default_backend() == "tpu"
-        H = x_proj.shape[-1] // 4
-        impl = "pallas" if on_tpu and 128 <= H < 512 and _pallas_ok(x_proj) else "scan"
+def lstm_recurrence(x_proj, w_h, c0, h0, impl: str = "auto", mesh=None):
+    """Dispatch on `impl` (auto|scan|pallas|pallas_interpret; "auto" per
+    resolve_impl, "pallas_interpret" runs the kernel in interpret mode
+    for CPU tests). `mesh` is the mesh of the surrounding jit, if any:
+    Mosaic kernels cannot be partitioned automatically, and the kernel's
+    batch slabs are independent, so under a mesh the kernel is
+    shard_mapped over `dp` with W_h replicated."""
+    impl = resolve_impl(impl, x_proj.shape, x_proj.dtype.itemsize, mesh)
     if impl == "scan":
         return lstm_scan(x_proj, w_h, c0, h0)
-    if impl == "pallas":
-        return _lstm_pallas(x_proj, w_h, c0, h0, False)
-    if impl == "pallas_interpret":
-        return _lstm_pallas(x_proj, w_h, c0, h0, True)
-    raise ValueError(f"unknown lstm impl {impl!r}")
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown lstm impl {impl!r}")
+    interpret = impl == "pallas_interpret"
+
+    def kernel(x_proj, w_h, c0, h0):
+        return _lstm_pallas(x_proj, w_h, c0, h0, interpret)
+
+    if mesh is not None and mesh.size > 1:
+        if mesh.shape.get("tp", 1) > 1:
+            raise ValueError(
+                f"lstm impl {impl!r} needs W_h whole on every device, and mesh "
+                f"{dict(mesh.shape)} shards its 4H axis over tp; use 'auto' or 'scan'"
+            )
+        rows = P("dp") if "dp" in mesh.axis_names else P()
+        # check_vma off: pallas_call declares no varying-axes rule; the
+        # dp=4 parity test pins forward and gradients to one device.
+        kernel = jax.shard_map(
+            kernel,
+            mesh=mesh,
+            in_specs=(rows, P(), rows, rows),
+            out_specs=(rows, (rows, rows)),
+            check_vma=False,
+        )
+    return kernel(x_proj, w_h, c0, h0)

@@ -39,33 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-# shard_map moved from jax.experimental to the jax namespace across the
-# versions this repo must run on; import whichever this env has. When
-# NEITHER exists (ancient/exotic jax), the module still imports — every
-# sequence-parallel entry point raises a clear error instead, and tests
-# skip on `SHARD_MAP_AVAILABLE` rather than killing collection for the
-# whole transformer family (the pre-PR-3 failure mode).
-try:
-    from jax import shard_map  # jax >= 0.6 canonical location
-except ImportError:
-    try:
-        from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-        def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-            # The experimental-era signature spells the replication-check
-            # opt-out `check_rep`; newer jax renamed it `check_vma`. Map
-            # the modern spelling onto whichever this env implements so
-            # the call sites below stay single-sourced.
-            return _experimental_shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-            )
-
-    except ImportError:
-        shard_map = None
-
-SHARD_MAP_AVAILABLE = shard_map is not None
-
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dotaclient_tpu.ops import attention as A
@@ -77,12 +51,6 @@ def _sp_shard_map(body_factory, mesh: Mesh, axis_name: str, q):
     collective re-shards are manual by design; correctness is pinned by
     the single-device parity tests). `body_factory(n)` receives the axis
     size — the single place it is derived."""
-    if shard_map is None:
-        raise NotImplementedError(
-            "sequence-parallel attention needs jax.shard_map (or "
-            "jax.experimental.shard_map), and this jax has neither — "
-            "run the LSTM family or a non-SP transformer config"
-        )
     n = dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name]
     if q.shape[1] % n:
         raise ValueError(f"time axis {q.shape[1]} not divisible by {axis_name}={n}")

@@ -9,10 +9,9 @@ rollouts whose model version has aged past its staleness bound. This
 repo's `runtime/staging.py` reproduces that policy on the host — frames
 older than `ppo.max_staleness` learner versions are discarded in
 `_ingest`, before they cost any device time. Every dropped frame is
-wasted actor work, and on scarce TPU windows (TPU_PROBE_LOG.md) the
-actor fleet and the learner are chronically mismatched: the learner's
-version counter sprints ahead inside a window, mass-staling the frames
-in flight.
+wasted actor work, and a learner that outpaces its actor fleet makes
+it chronic: the version counter sprints ahead and mass-stales the
+frames in flight.
 
 This package converts that drop-on-stale policy into a tunable
 freshness/efficiency tradeoff, following two pieces of related work:

@@ -1194,12 +1194,13 @@ class VectorActor:
 
 def main(argv=None):
     from dotaclient_tpu.config import parse_config
+    from dotaclient_tpu.runtime.device import init_devices, use_compile_cache
     from dotaclient_tpu.transport.base import connect as broker_connect
 
     logging.basicConfig(level=logging.INFO)
     cfg = parse_config(ActorConfig(), argv)
-    if cfg.platform:
-        jax.config.update("jax_platforms", cfg.platform)
+    use_compile_cache()
+    init_devices(cfg.platform, "actor")
     broker = broker_connect(cfg.broker_url, retry=RetryPolicy.from_config(cfg.retry))
     if cfg.chaos.enabled:
         # Gated IMPORT, not just gated construction: with chaos off the

@@ -855,13 +855,14 @@ class InferenceServer:
 def main(argv=None):
     from dotaclient_tpu.config import parse_config
     from dotaclient_tpu.obs import ObsRuntime
+    from dotaclient_tpu.runtime.device import init_devices, use_compile_cache
     from dotaclient_tpu.transport.base import RetryPolicy
     from dotaclient_tpu.transport.base import connect as broker_connect
 
     logging.basicConfig(level=logging.INFO)
     cfg = parse_config(InferenceConfig(), argv)
-    if cfg.platform:
-        jax.config.update("jax_platforms", cfg.platform)
+    cache = use_compile_cache()
+    init_devices(cfg.platform, "serve")
     broker = broker_connect(cfg.broker_url, retry=RetryPolicy.from_config(cfg.retry))
     if cfg.chaos.enabled:
         from dotaclient_tpu.chaos import wrap_broker
@@ -869,6 +870,12 @@ def main(argv=None):
         broker = wrap_broker(broker, cfg.chaos)
     obs = ObsRuntime.create(cfg.obs, role="serve")
     server = InferenceServer(cfg, broker, obs_runtime=obs).start()
+    _log.info(
+        "serve ready: tick compiled, compile_cache=%s hits=%d misses=%d",
+        cache.dir,
+        cache.hits,
+        cache.misses,
+    )
     # The bench/orchestration contract: ONE parseable ready line with
     # the bound port (--serve.port 0 picks a free one).
     print(json.dumps({"serving": True, "port": server.port}), flush=True)

@@ -64,15 +64,13 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")  # host-path A/B; see conftest note
-# Private per-run compilation cache: the two e2e arms compile the SAME
-# train step (they differ only in host-feed config), so arm 2 becomes a
-# cache hit instead of a second multi-minute CPU compile. A fresh
-# temp dir per run — never the pytest cache — sidesteps the
-# foreign-topology cache-entry wedge (tests/conftest.py's warning).
-import tempfile as _tempfile
+jax.config.update("jax_platforms", "cpu")  # a host-path A/B, pinned to the CPU
+# Persistent compilation cache: the two e2e arms compile the SAME train
+# step (they differ only in host-feed config), so arm 2 — and the next
+# run — is a cache hit instead of a second multi-minute CPU compile.
+from dotaclient_tpu.runtime.device import use_compile_cache
 
-jax.config.update("jax_compilation_cache_dir", _tempfile.mkdtemp(prefix="abps_xla_"))
+use_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 import numpy as np

@@ -28,7 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")  # actors/learner on host; see conftest note
+jax.config.update("jax_platforms", "cpu")  # actors and learner in one process, on the CPU
 
 import numpy as np
 

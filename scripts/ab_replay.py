@@ -6,8 +6,8 @@ at equal-or-better learning).
 Both arms run the SAME closed loop as scripts/ab_ppo_reuse.py (fake env
 → 3 actors → mem broker → learner) with the SAME number of consumed
 learner batches, under a deliberately tight ppo.max_staleness so the
-CPU smoke reproduces the TPU-window regime where the learner's version
-counter outruns the frames in flight (TPU_PROBE_LOG.md). The arms
+CPU smoke reproduces the regime where the learner's version counter
+outruns the frames in flight. The arms
 differ only in LearnerConfig.replay: off (reference drop-on-stale
 behavior) vs on at ratio 0.25 with ACER truncated importance weights.
 
@@ -31,7 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")  # actors/learner on host; see conftest note
+jax.config.update("jax_platforms", "cpu")  # actors and learner in one process, on the CPU
 
 import numpy as np
 
@@ -56,8 +56,8 @@ def run_arm(tag: str, n_updates: int, seed: int, replay_on: bool, ratio: float):
     lcfg = LearnerConfig(batch_size=16, seq_len=16, policy=SMALL, publish_every=1, seed=seed)
     lcfg.ppo.lr = 1e-3
     lcfg.ppo.entropy_coef = 0.005
-    # Tight staleness bound: reproduces the scarce-TPU-window regime on
-    # the CPU smoke — the version counter outruns frames in flight, so
+    # Tight staleness bound: reproduces a fast learner's regime on the
+    # CPU smoke — the version counter outruns frames in flight, so
     # the off arm actually drops and the on arm actually replays.
     lcfg.ppo.max_staleness = 1
     lcfg.replay.enabled = replay_on

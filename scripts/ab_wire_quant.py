@@ -50,7 +50,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")  # host-path A/B; see conftest note
+jax.config.update("jax_platforms", "cpu")  # a host-path A/B, pinned to the CPU
 
 import numpy as np
 

@@ -26,14 +26,14 @@ minutes: staleness drops, drop-oldest backpressure, queue depth,
 heartbeats, and learner progress, all sampled mid-run.
 
 Round-5 additions (VERDICT r4 items 1 and 4):
-- `--phase {all,a,b}` runs one phase alone. The silicon window runs
-  `--phase b --platform tpu`: with the train step on the chip, the lone
-  host core is freed for transport and phase B can finally chase the
-  50k CONSUMED bar — the true north-star topology (producers saturating
-  a learner that is simultaneously training) that one CPU core cannot
-  show.
+- `--phase {all,a,b}` runs one phase alone. On a chip run
+  `--phase b --platform tpu`: with the train step on the chip, the host
+  cores are freed for transport and phase B can chase the 50k CONSUMED
+  bar — the true north-star topology (producers saturating a learner
+  that is simultaneously training) that a CPU-only host cannot show.
 - `--platform tpu` asserts devices[0] is a real TPU (refuses to mislabel
-  a CPU run, mirroring bench.py's forced mode); children stay on CPU.
+  a CPU run); this process holds the chip, so every child is pinned to
+  the CPU through its environment.
 - `--batch-size 64 --phase b` is the host-ceiling variant: a
   deliberately tiny device step maximizes the consumed rate one core can
   reach, documenting the host-side ceiling the silicon run must beat.
@@ -196,12 +196,9 @@ def _spawn_children(n_replayers, n_real, rate, duration, frames_file, go_file, f
                     policy="tiny"):
     broker_url = f"tcp://127.0.0.1:{PORT}"
     common = ["--broker", broker_url, "--go-file", go_file, "--duration", str(duration)]
-    # Children are CPU-pinned (real actors jax.config-force cpu) — they
-    # must NOT inherit a JAX compilation cache aimed at the TPU parent:
-    # CPU-fallback entries in a shared dir wedge later TPU loaders with
-    # "machine features don't match" (tests/conftest.py lore; prober
-    # window-cache review finding).
-    child_env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    # One process per chip: the parent's learner may hold a TPU, so the
+    # children are pinned to the CPU before their JAX starts.
+    child_env = dict(os.environ, JAX_PLATFORMS="cpu")
     procs = []
     for i in range(n_replayers):
         procs.append(
@@ -299,9 +296,8 @@ def main(argv=None) -> int:
     from dotaclient_tpu.transport.base import connect
 
     if args.platform == "tpu" and jax.devices()[0].platform != "tpu":
-        # Mirror bench.py's forced-tpu contract: the caller (the prober,
-        # inside a verified window) asserted silicon; refuse to produce an
-        # artifact that mislabels a CPU run as the on-chip closed loop.
+        # The caller asked for the chip; refuse to produce an artifact
+        # that mislabels a CPU run as the on-chip closed loop.
         raise RuntimeError(
             f"--platform tpu but devices are {jax.devices()[0].platform!r}"
         )

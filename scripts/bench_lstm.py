@@ -10,8 +10,6 @@ real TPU backend — interpret-mode timings are meaningless and are
 refused, so a CPU run documents scan-only numbers and says why.
 
 Run: python scripts/bench_lstm.py [--out LSTM_BENCH.json]
-(The round's TPU probe loop runs this automatically if the chip ever
-answers — see TPU_PROBE_LOG.md for the probe evidence trail.)
 """
 
 from __future__ import annotations

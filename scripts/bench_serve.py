@@ -60,11 +60,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _clean_env():
-    """Subprocess env, minus the pytest-only persistent XLA cache + the
-    8-virtual-device flag (topology-mismatched cache entries segfault at
-    import — the PR-7 gotcha, tests/conftest.py clean_subprocess_env)."""
+    """Subprocess env: one CPU device per child (no 8-virtual-device
+    flag inherited from a pytest parent)."""
     env = dict(os.environ)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["XLA_FLAGS"] = env.get("XLA_FLAGS", "").replace(
         " --xla_force_host_platform_device_count=8", ""
     )

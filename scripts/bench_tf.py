@@ -1,14 +1,13 @@
 """Transformer-family train-step micro-bench: device-only fwd+bwd rates
 across context lengths, dense vs blockwise attention, remat on/off.
 
-The window task list (scripts/tpu_prober.py) runs this on silicon so the
-long-context family gets priced next to the LSTM flagship: BENCH_TPU_*
+Prices the long-context family next to the LSTM flagship: BENCH_TPU_*
 covers the e2e LSTM loop, LSTM_BENCH the recurrence kernel, and this
 artifact (TF_BENCH.json) the transformer step — env-steps/s, ms/step,
 and the analytic MFU at each shape (ops/flops.py transformer model).
 
-A CPU run writes the artifact too (rates labeled by backend) — useful as
-a relative shape study, never as a silicon claim.
+A CPU run (JAX_PLATFORMS=cpu) writes the artifact too, rates labeled by
+backend — a relative shape study, never a device number.
 
 Run: python scripts/bench_tf.py [--out TF_BENCH.json]
 """
@@ -24,10 +23,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-if os.environ.get("DOTACLIENT_TPU_BENCH_PLATFORM") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 
@@ -71,7 +66,7 @@ def bench_config(tf_context: int, attn_block: int, remat: bool, batch: int, iter
     jax.block_until_ready(metrics["loss"])
     dt = (time.perf_counter() - t0) / iters
     model_flops = flops_mod.train_step_flops(cfg)
-    peak = flops_mod.peak_flops_for(str(jax.devices()[0]))
+    peak = flops_mod.peak_flops_for(jax.devices()[0])
     return {
         "tf_context": tf_context,
         "seq_len": seq_len,

@@ -604,18 +604,12 @@ print(json.dumps({
 
 def run_part_c() -> dict:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    # The persistent XLA cache belongs to pytest processes only
-    # (tests/conftest.py): entries loaded under a different device
-    # topology have wedged standalone drivers on this host class.
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     proc = subprocess.run(
         [sys.executable, "-c", _INERTNESS_SCRIPT],
         cwd=repo,
         capture_output=True,
         text=True,
         timeout=600,
-        env=env,
     )
     if proc.returncode != 0:
         return {"error": f"inertness subprocess failed: {proc.stderr[-2000:]}"}

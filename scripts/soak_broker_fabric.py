@@ -663,10 +663,8 @@ def inertness_proof() -> dict:
         "assert 'dotaclient_tpu.transport.fabric' not in sys.modules\n"
         "print('INERT_OK')\n" % REPO_ROOT
     )
-    env = dict(os.environ)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     proc = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120, env=env
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120
     )
     return {
         "fabric_imported_on_classic_path": "INERT_OK" not in proc.stdout,

@@ -125,9 +125,6 @@ class TestRope:
         assert np.isfinite(np.asarray(A.rope(x, pos))).all()
 
 
-@pytest.mark.skipif(
-    not RA.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map (any location)"
-)
 class TestRingAttention:
     @pytest.fixture(scope="class")
     def sp_mesh(self):
@@ -187,9 +184,6 @@ class TestRingAttention:
         np.testing.assert_allclose(via_ring, via_full, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.skipif(
-    not RA.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map (any location)"
-)
 class TestUlyssesAttention:
     @pytest.fixture(scope="class")
     def sp_mesh(self):
@@ -293,9 +287,6 @@ class TestBlockwiseAttention:
 @pytest.mark.nightly  # blockwise-vs-dense parity is covered in the default
 # gate at the op level (TestBlockwiseAttention); this is the ulysses composition
 @pytest.mark.slow  # nightly-heavy must ALSO be slow (tier-1 -m override)
-@pytest.mark.skipif(
-    not RA.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map (any location)"
-)
 def test_ulysses_blockwise_matches_dense():
     """kv_block threading through the ulysses path changes memory only."""
     mesh = mesh_lib.make_mesh("sp=8")

@@ -647,10 +647,6 @@ def test_learner_incarnations_term_then_kill_and_ledgers():
     env["XLA_FLAGS"] = env.get("XLA_FLAGS", "").replace(
         " --xla_force_host_platform_device_count=8", ""
     )
-    # The persistent XLA cache is for the 8-device pytest processes only
-    # (conftest): entries loaded under a different device topology have
-    # wedged/killed standalone drivers on this host.
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     proc = subprocess.run(
         [sys.executable, "-c", _LINC_SCRIPT],
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -143,8 +143,11 @@ def test_mfu_accountant_no_peak_no_mfu():
 def test_aggregate_peak_flops_table():
     from dotaclient_tpu.ops.flops import aggregate_peak_flops
 
-    assert aggregate_peak_flops(["TPU v5e chip 0", "TPU v5e chip 1"]) == pytest.approx(2 * 197e12)
-    assert aggregate_peak_flops(["TFRT_CPU_0"]) is None  # no table entry
+    from types import SimpleNamespace as Dev
+
+    v5e = Dev(platform="tpu", device_kind="TPU v5 lite")
+    assert aggregate_peak_flops([v5e, v5e]) == pytest.approx(2 * 197e12)
+    assert aggregate_peak_flops([Dev(platform="cpu", device_kind="cpu")]) is None
     assert aggregate_peak_flops([]) is None
 
 

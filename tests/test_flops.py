@@ -129,5 +129,11 @@ def test_reuse_model_tracks_xla_count_unrolled():
 
 
 def test_peak_lookup():
-    assert flops_mod.peak_flops_for("TPU v5 lite0") == 197e12
-    assert flops_mod.peak_flops_for("TFRT_CPU_0") is None
+    """Keyed on device_kind: "TPU v5 lite" is what a v5e chip reports.
+    The CPU keeps None (no MFU there); an unknown TPU is an error."""
+    from types import SimpleNamespace as Dev
+
+    assert flops_mod.peak_flops_for(Dev(platform="tpu", device_kind="TPU v5 lite")) == 197e12
+    assert flops_mod.peak_flops_for(jax.devices()[0]) is None
+    with pytest.raises(ValueError, match="TPU v9"):
+        flops_mod.peak_flops_for(Dev(platform="tpu", device_kind="TPU v9"))

@@ -183,8 +183,8 @@ def test_multihost_learner_slice_consistency():
 
 
 def test_learner_manifests_keep_pipelined_loop():
-    """Production learner deploys pin the overlapped loop (ISSUE 15,
-    OVERLAP_AB.json): --learner.prefetch true explicitly (the loop shape
+    """Production learner deploys pin the overlapped loop (ISSUE 15):
+    --learner.prefetch true explicitly (the loop shape
     must survive a default change, and rollback is exactly this flag —
     MIGRATION item 15), and --obs.step_phases true WITH it — phase
     attribution is free under the pipelined loop (obs/compute.py overlap

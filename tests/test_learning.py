@@ -19,7 +19,6 @@ import numpy as np
 import pytest
 
 from dotaclient_tpu.config import ActorConfig, LearnerConfig, PolicyConfig
-from dotaclient_tpu.ops import ring_attention
 from dotaclient_tpu.env.fake_dotaservice import FakeDotaService
 from dotaclient_tpu.env.service import LocalDotaServiceStub
 from dotaclient_tpu.runtime.actor import Actor
@@ -123,9 +122,6 @@ def test_full_stack_learning_improves_return():
 @pytest.mark.nightly
 @pytest.mark.slow  # nightly-heavy must ALSO be slow: tier-1's -m 'not slow'
 # REPLACES the addopts nightly exclusion (revived by the PR-3 shard_map fix)
-@pytest.mark.skipif(
-    not ring_attention.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map"
-)
 def test_transformer_family_learning_improves_return():
     """The long-context family closes the same loop: KV-cache acting,
     chunk-local teacher-forced re-eval, PPO — return must rise. Smaller
@@ -150,9 +146,6 @@ def test_transformer_family_learning_improves_return():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not ring_attention.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map"
-)
 def test_sequence_parallel_learning_smoke_thin():
     """Default-gate SP smoke (VERDICT r3 item 10): the judge must see the
     closed-loop sequence-parallel path green WITHOUT trusting notes — a
@@ -191,9 +184,6 @@ def test_sequence_parallel_learning_smoke_thin():
 @pytest.mark.nightly
 @pytest.mark.slow  # nightly-heavy must ALSO be slow: tier-1's -m 'not slow'
 # REPLACES the addopts nightly exclusion (revived by the PR-3 shard_map fix)
-@pytest.mark.skipif(
-    not ring_attention.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map"
-)
 def test_context128_full_longcontext_stack_learns():
     """The longest-context closed loop in the suite: 127-step chunks
     (8x the LSTM flagship chunk) acted through the KV cache, learned
@@ -240,9 +230,6 @@ def test_context128_full_longcontext_stack_learns():
 @pytest.mark.nightly
 @pytest.mark.slow  # nightly-heavy must ALSO be slow: tier-1's -m 'not slow'
 # REPLACES the addopts nightly exclusion (revived by the PR-3 shard_map fix)
-@pytest.mark.skipif(
-    not ring_attention.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map"
-)
 def test_long_chunk_sequence_parallel_learning():
     """The long-context regime END TO END: 31-step chunks (double the
     flagship) acted through the KV cache, learned with the time axis

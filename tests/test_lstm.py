@@ -97,10 +97,10 @@ def test_dispatcher_rejects_unknown():
 
 
 def test_vmem_guard():
-    # shape-only probes: _pallas_ok reads .shape/.dtype.itemsize, so
-    # ShapeDtypeStruct avoids materializing the 32 GB "too big" case
+    # shape-only probes: nothing materializes the 32 GB "too big" case
     def probe(shape):
-        return L._pallas_ok(jax.ShapeDtypeStruct(shape, jnp.float32))
+        B, T, H4 = shape
+        return L._block_b(B, T, H4 // 4, 4) > 0
 
     assert probe((128, 16, 512))
     # an odd batch still fits as one (padded) slab
@@ -137,3 +137,82 @@ def test_bf16_scan_and_pallas_compute_identical_function():
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=2e-2, atol=1e-3
         )
+
+
+# ------------------------------------------------------------------ mesh
+
+
+class _FakeDevice:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+class _FakeMesh:
+    """resolve_impl reads a mesh's axis sizes and its devices' platform
+    and nothing else — enough to ask what a TPU mesh would get."""
+
+    def __init__(self, platform, **axes):
+        self.shape = axes
+        self.devices = np.array([_FakeDevice(platform)], dtype=object)
+
+
+@pytest.mark.parametrize(
+    "asked,shape,mesh,expect",
+    [
+        ("auto", (256, 17, 512), None, "scan"),  # CPU default backend
+        ("auto", (256, 17, 512), _FakeMesh("cpu", dp=4), "scan"),
+        ("auto", (256, 17, 512), _FakeMesh("tpu", dp=1), "pallas"),
+        ("auto", (256, 17, 512), _FakeMesh("tpu", dp=4), "pallas"),
+        ("auto", (256, 17, 512), _FakeMesh("tpu", dp=2, tp=2), "scan"),  # w_h sharded
+        ("auto", (256, 17, 256), _FakeMesh("tpu", dp=4), "scan"),  # H=64 below the window
+        ("auto", (256, 17, 2048), _FakeMesh("tpu", dp=4), "scan"),  # H=512 above it
+        ("auto", (4096, 2048, 1024), _FakeMesh("tpu", dp=1), "scan"),  # no slab fits VMEM
+        ("pallas", (256, 17, 256), _FakeMesh("cpu", dp=2, tp=2), "pallas"),  # never gives way
+        ("scan", (256, 17, 512), _FakeMesh("tpu", dp=4), "scan"),
+    ],
+)
+def test_resolve_impl(asked, shape, mesh, expect):
+    assert L.resolve_impl(asked, shape, 2, mesh) == expect
+
+
+def _dp_mesh(n, names=("dp",), shape=None):
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(jax.devices()[:n]).reshape(shape or (n,)), names)
+
+
+def test_kernel_shard_maps_over_dp_forward_and_grads():
+    """Under a dp=4 mesh the kernel runs shard_mapped (Mosaic kernels
+    cannot be partitioned automatically): forward and every input
+    gradient — W_h's is a psum over dp — match the one-device kernel."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = _dp_mesh(4)
+    args = make_inputs(B=8, T=5, H=8, seed=6)
+    rows, rep = NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
+    sharded = jax.device_put(args, (rows, rep, rows, rows))
+
+    def loss(mesh):
+        def go(xp, w, c, h):
+            h_seq, (c_T, h_T) = L.lstm_recurrence(xp, w, c, h, "pallas_interpret", mesh)
+            return jnp.sum(h_seq**2) + jnp.sum(c_T * 0.3) + jnp.sum(h_T * 0.7), h_seq
+
+        return jax.jit(jax.value_and_grad(go, argnums=(0, 1, 2, 3), has_aux=True))
+
+    (ref_l, ref_h), ref_g = loss(None)(*args)
+    (out_l, out_h), out_g = loss(mesh)(*sharded)
+    assert len(out_h.sharding.device_set) == 4
+    np.testing.assert_allclose(np.asarray(out_h), np.asarray(ref_h), rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(float(out_l), float(ref_l), rtol=1e-5)
+    for name, a, b in zip(("x_proj", "w_h", "c0", "h0"), ref_g, out_g):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6, err_msg=name
+        )
+
+
+def test_explicit_kernel_refuses_tp_mesh():
+    """Asked for by name the kernel never turns into scan without a
+    word: a mesh that shards W_h is an error, not a quiet fallback."""
+    mesh = _dp_mesh(4, ("dp", "tp"), (2, 2))
+    with pytest.raises(ValueError, match="tp"):
+        L.lstm_recurrence(*make_inputs(B=8), "pallas_interpret", mesh)

@@ -1,8 +1,7 @@
 """Overlapped learner pipeline (ISSUE 15, --learner.prefetch): the
 PrefetchLane loop's bitwise parity with the serial loop, the PR-7
 zero-loss drain contract through the new prefetch station, the overlap
-phase accounting, the flag-off inertness, and the OVERLAP_AB.json
-committed-artifact guard."""
+phase accounting and the flag-off inertness."""
 
 import json
 import os
@@ -92,8 +91,8 @@ def test_pipelined_bitwise_identical_to_serial(tmp_path):
     """The tentpole contract: the PrefetchLane is the same single FIFO
     staging consumer, so batch order is unchanged and K pipelined steps
     produce BITWISE the serial params + optimizer state over the same
-    frame schedule (the RESUME_SOAK-style lockstep argument; the
-    committed OVERLAP_AB.json runs the same proof on both transfer
+    frame schedule (the RESUME_SOAK-style lockstep argument;
+    scripts/ab_overlap.py runs the same proof on both transfer
     layouts)."""
     h_serial, _ = _run_arm("pf_par_ser", tmp_path, False, 3)
     h_pipe, learner = _run_arm("pf_par_pipe", tmp_path, True, 3)
@@ -370,42 +369,7 @@ def test_pipeline_family_registered():
         assert registry.is_registered(name), name
 
 
-# --------------------------------------------------- committed artifact
-
-
-def test_committed_overlap_ab_verdicts_hold():
-    """OVERLAP_AB.json (committed) must stay all-green: bitwise parity
-    across both transfer layouts, the probe-keyed overlap bar, the
-    no-regression floor, both default flips, and the PrefetchModel
-    schedcheck evidence."""
-    path = os.path.join(REPO_ROOT, "OVERLAP_AB.json")
-    with open(path) as f:
-        art = json.load(f)
-    v = art["verdict"]
-    assert v["all_green"] is True
-    assert v["params_bitwise_identical"] is True
-    assert v["prefetch_default_on"] is True
-    assert v["fused_single_h2d_default_on"] is True
-    assert v["schedcheck_ok"] is True
-    assert v["no_regression_ok"] is True
-    # probe-keyed bar: either the 0.98 ratio held, or the host
-    # concurrency probe excused it IN-ARTIFACT (never silently)
-    if v["e2e_over_device_only_pipelined"] < v["bar_e2e_over_device_only"]:
-        assert not v["host_can_express_overlap"]
-        assert v["overlap_caveat"]
-    # parity evidence covers BOTH transfer layouts
-    for layout in ("single_buffer", "groups_4_buffers"):
-        assert art["parity"][layout]["state_bitwise_identical"] is True
-        assert art["parity"][layout]["loss_history_identical"] is True
-    # schedcheck: HEAD clean, all three mutants caught
-    sc = art["schedcheck_prefetch"]
-    assert sc["head_exhausted"] and sc["head_violations"] == 0
-    assert set(sc["mutants"]) == {
-        "release_before_retire",
-        "train_consumes_inflight",
-        "drain_ignores_prefetch",
-    }
-    assert all(m["caught"] for m in sc["mutants"].values())
+# ------------------------------------------------------ nightly re-run
 
 
 @pytest.mark.nightly  # full A/B re-run: two learners x two layouts + compiles

@@ -38,12 +38,9 @@ SMALL = PolicyConfig(unit_embed_dim=16, lstm_hidden=16, mlp_hidden=16, dtype="fl
 
 
 def _subprocess_env():
-    """Env for child python processes: drop the pytest-only persistent
-    XLA cache (conftest: entries loaded under a different device
-    topology have wedged/killed standalone processes on this host) and
-    the 8-virtual-device flag (children pick their own count)."""
+    """Env for child python processes: drop the 8-virtual-device flag
+    (children pick their own count)."""
     env = dict(os.environ)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["XLA_FLAGS"] = env.get("XLA_FLAGS", "").replace(
         " --xla_force_host_platform_device_count=8", ""
     )

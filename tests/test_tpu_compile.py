@@ -1,0 +1,161 @@
+"""Ask the TPU's compiler, without a chip: the kernels and steps of the
+main path at their real sizes, compiled for a described `v5e:2x2`
+topology (libtpu ships the compiler; nothing executes, so this says
+nothing about results or times — a compile that passes is not a chip
+run). It refuses what the chip would refuse: a Mosaic kernel the
+partitioner cannot split, a slice off the tiling, a program that does
+not fit. The whole file is meant to stay inside 40 s.
+
+The code under test sees `jax.default_backend() == "cpu"` here; what it
+is compiled FOR comes from the described devices handed to it (a mesh
+for the train steps, argument shardings for the rest).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from dotaclient_tpu.config import ActorConfig, LearnerConfig, PolicyConfig
+from dotaclient_tpu.env import featurizer as F
+from dotaclient_tpu.models import policy as P
+from dotaclient_tpu.ops import lstm as L
+from dotaclient_tpu.parallel import mesh as mesh_lib
+from dotaclient_tpu.parallel.train_step import build_single_train_step, init_train_state
+from dotaclient_tpu.runtime.actor import make_batched_actor_step
+
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever libtpu raises where it cannot describe one
+        pytest.skip(f"no v5e:2x2 topology description here: {type(e).__name__}: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described TPU is written to the persistent cache
+    but cannot be read back without a chip: the next run would warn and
+    compile again. Keep these compiles out of it."""
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    """Shapes of `tree` placed by `sharding` (one sharding, or a
+    matching tree of them) — described devices hold no arrays."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), tree, sharding
+        )
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
+
+
+def _fits(compiled) -> bool:
+    m = compiled.memory_analysis()
+    return (
+        m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes < HBM_BYTES
+    )
+
+
+# (B, T, H): the learner's unroll with and without the bootstrap frame,
+# and the one-step shapes of an actor row and a small tick.
+@pytest.mark.parametrize("B,T,H", [(256, 17, 128), (256, 16, 128), (1, 1, 128), (8, 1, 128)])
+def test_lstm_kernel_forward_and_vjp_compile(topo, B, T, H):
+    one = SingleDeviceSharding(topo.devices[0])
+    args = _on(
+        one,
+        (
+            jax.ShapeDtypeStruct((B, T, 4 * H), jnp.bfloat16),
+            jax.ShapeDtypeStruct((H, 4 * H), jnp.bfloat16),
+            jax.ShapeDtypeStruct((B, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H), jnp.float32),
+        ),
+    )
+
+    def forward(x_proj, w_h, c0, h0):
+        return L.lstm_recurrence(x_proj, w_h, c0, h0, impl="pallas")
+
+    def loss(*a):
+        h_seq, (c_T, h_T) = forward(*a)
+        return jnp.sum(h_seq) + jnp.sum(c_T) + jnp.sum(h_T)
+
+    for fn in (forward, jax.grad(loss, argnums=(0, 1, 2, 3))):
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def _compile_train_step(cfg: LearnerConfig, devices):
+    mesh = mesh_lib.make_mesh(cfg.mesh_shape, devices=devices)
+    step, state_shardings, io = build_single_train_step(cfg, mesh)
+    state = jax.eval_shape(lambda: init_train_state(cfg, jax.random.PRNGKey(0)))
+    payload, _ = io.alloc_transfer()
+    return step.lower(
+        _on(state_shardings, state), _on(io.transfer_shardings(), payload)
+    ).compile()
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_flagship_train_step_compiles_with_the_kernel(topo, n_devices):
+    """LearnerConfig() as it stands — 256x16, H=128, bf16, lstm_impl
+    "auto", mesh "dp=-1" — on one described chip and on all four. "auto"
+    must find the kernel from the mesh's devices, and on dp=4 the kernel
+    must survive partitioning (it is shard_mapped over dp)."""
+    compiled = _compile_train_step(LearnerConfig(), topo.devices[:n_devices])
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("all-reduce" in text) == (n_devices > 1)
+    assert _fits(compiled)
+
+
+def test_explicit_scan_compiles_without_the_kernel(topo):
+    """What was asked is what runs: lstm_impl="scan" on a TPU mesh puts
+    no Mosaic kernel into the program."""
+    cfg = LearnerConfig(policy=PolicyConfig(lstm_impl="scan"))
+    assert "tpu_custom_call" not in _compile_train_step(cfg, topo.devices).as_text()
+
+
+def test_serve_tick_compiles_at_default_capacity(topo):
+    """The batched serve tick at serve.max_batch (16): `lax.map` over
+    [1, ...] rows — on a TPU one program holding a sequential loop of 16
+    one-row steps, none of them the Pallas kernel (single steps run the
+    gate math inline)."""
+    from dotaclient_tpu.config import ServeConfig
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = ActorConfig()
+    M = ServeConfig().max_batch
+    rows = lambda x: jax.ShapeDtypeStruct((M, 1) + np.shape(x), np.asarray(x).dtype)
+    params = jax.eval_shape(lambda: P.init_params(cfg.policy, jax.random.PRNGKey(0)))
+    H = cfg.policy.lstm_hidden
+    state = (jax.ShapeDtypeStruct((M, 1, H), jnp.float32),) * 2
+    obs = jax.tree.map(rows, F.zeros_observation())
+    rngs = jax.ShapeDtypeStruct((M, 2), jnp.uint32)
+    compiled = (
+        make_batched_actor_step(cfg).lower(*_on(one, (params, state, obs, rngs))).compile()
+    )
+    text = compiled.as_text()
+    assert " while(" in text and "tpu_custom_call" not in text
+    assert _fits(compiled)
+
+
+def test_transformer_train_step_compiles(topo):
+    """The second family builds for the chip too (the smoke does not run
+    it): --policy.arch transformer --seq_len 127 --policy.tf_context 128
+    on one device."""
+    cfg = LearnerConfig(seq_len=127, policy=PolicyConfig(arch="transformer", tf_context=128))
+    assert _fits(_compile_train_step(cfg, topo.devices[:1]))

@@ -13,7 +13,6 @@ from dotaclient_tpu.config import ActorConfig, LearnerConfig, PolicyConfig
 from dotaclient_tpu.env import featurizer as F
 from dotaclient_tpu.models import policy as P
 from dotaclient_tpu.models.transformer_policy import KVCache
-from dotaclient_tpu.ops import ring_attention
 from dotaclient_tpu.parallel import mesh as mesh_lib
 from dotaclient_tpu.parallel.train_step import (
     build_train_step,
@@ -190,9 +189,6 @@ def _run_one_step(cfg, seed=0):
     return {k: float(v) for k, v in jax.device_get(metrics).items()}
 
 
-@pytest.mark.skipif(
-    not ring_attention.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map (any location)"
-)
 class TestSequenceParallelTrainStep:
     @pytest.mark.slow  # two full train-step compiles — default gate only
     def test_sp_matches_dp_only(self):
@@ -287,9 +283,6 @@ class TestRemat:
     @pytest.mark.nightly  # remat bit-parity is in the default gate; this
     # is the remat x sp composition (second big compile)
     @pytest.mark.slow  # nightly-heavy must ALSO be slow (tier-1 -m override)
-    @pytest.mark.skipif(
-        not ring_attention.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map"
-    )
     def test_remat_composes_with_sequence_parallelism(self):
         cfg = _tf_learner_cfg("dp=2,sp=4", "sp")
         cfg.policy.tf_remat = True
@@ -299,9 +292,6 @@ class TestRemat:
             assert m[k] == pytest.approx(ref[k], rel=1e-4, abs=1e-5), k
 
 
-@pytest.mark.skipif(
-    not ring_attention.SHARD_MAP_AVAILABLE, reason="this jax has no shard_map (any location)"
-)
 class TestUlyssesTrainStep:
     @pytest.mark.nightly  # ring train-step parity guards the default gate;
     # ulysses parity at op level is default too — this is the composition
