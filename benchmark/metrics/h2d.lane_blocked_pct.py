@@ -1,0 +1,15 @@
+"""H2D: share of the time the prefetch lane spends blocked handing a batch
+to the loop over the full queue: the lane's slack, the program's own twin of
+`gen.throttled_pct` (the program's span `lane.handoff`: cumulative seconds,
+last metrics window of the run's window minus the first, over the time
+between those two)."""
+
+KEYS = ("span_lane_handoff_s_total",)
+
+
+def read(run):
+    syncs = [s for s in run["syncs"] if all(k in s[2] for k in KEYS)]
+    if len(syncs) < 2 or syncs[-1][0] <= syncs[0][0]:
+        return None
+    busy = sum(syncs[-1][2][k] - syncs[0][2][k] for k in KEYS)
+    return 100.0 * busy / (syncs[-1][0] - syncs[0][0])

@@ -542,8 +542,7 @@ class ObsConfig:
     # block_until_ready fence for causal attribution.
     step_phases: bool = True
     # Where POST /profile?seconds=N captures land (jax.profiler.trace
-    # TensorBoard dirs). "" = dump_dir (or cwd). Replaces the deprecated
-    # learner profile_port always-on server.
+    # TensorBoard dirs). "" = dump_dir (or cwd).
     profile_dir: str = ""
     # Hard cap on a single on-demand profile capture; /profile clamps to
     # this (an unbounded capture would fill the pod disk).
@@ -633,9 +632,6 @@ class LearnerConfig:
     # against each other (ROADMAP S2 does); set false to fall back to
     # the 4-buffer layout.
     fused_single_h2d: bool = True
-    # jax.profiler server port (0 = off); connect with TensorBoard's
-    # profile plugin or jax.profiler.trace to capture device traces
-    profile_port: int = 0
     # JAX backend of this process (runtime/device.py init_devices): ""
     # = JAX's default — JAX_PLATFORMS if set, else the best backend
     # present, which on a host without a chip is the CPU; the first log

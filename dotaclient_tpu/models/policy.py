@@ -180,14 +180,20 @@ class PolicyCore(nn.Module):
         self, carry: LSTMState, obs: F.Observation, unroll: bool = False
     ) -> Tuple[LSTMState, PolicyOutput]:
         cfg = self.cfg
-        trunk, unit_emb = obs_trunk(cfg, obs)
+        # Named scopes: the layer an operation belongs to, in its
+        # `op_name` (parallel/train_step.py has the rest). They name
+        # operations only; parameter paths are flax's and do not change.
+        with jax.named_scope("trunk"):
+            trunk, unit_emb = obs_trunk(cfg, obs)
 
         # LSTM output stays f32: every head computes in f32, so a bf16
         # round-trip here would be pure precision loss.
-        carry, out = LSTMCell(
-            cfg.lstm_hidden, dtype=_dtype(cfg), impl=cfg.lstm_impl, mesh=self.mesh, name="lstm"
-        )(carry, trunk, unroll=unroll)
-        return carry, action_heads(cfg, out, unit_emb, obs)
+        with jax.named_scope("lstm"):
+            carry, out = LSTMCell(
+                cfg.lstm_hidden, dtype=_dtype(cfg), impl=cfg.lstm_impl, mesh=self.mesh, name="lstm"
+            )(carry, trunk, unroll=unroll)
+        with jax.named_scope("heads"):
+            return carry, action_heads(cfg, out, unit_emb, obs)
 
 
 class PolicyNet(nn.Module):

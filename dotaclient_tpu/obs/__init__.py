@@ -6,6 +6,15 @@ and no scrape endpoint — you could see THAT throughput was low
 (env_steps_per_sec), never WHERE a rollout spent its time. This package
 is the measurement layer:
 
+- obs/spans.py        `span(name, **ids)`: the one primitive the learner
+                      process times its threads' work with. Always on
+                      (not behind --obs.*): a jax.profiler
+                      TraceAnnotation on the device trace's clock while
+                      a profiler session is open, cumulative span_*
+                      scalars with every metrics window, the long ones
+                      mirrored into the flight recorder where one
+                      exists; also the compile counters and the OS
+                      thread names;
 - obs/trace.py        per-stage latency histograms from trace-stamped
                       rollout chunks (DTR2 wire extension) + the e2e
                       actor→apply scalar that decomposes staleness;
@@ -20,7 +29,7 @@ is the measurement layer:
                       log → dump → 503) behind --obs.watchdog.*;
 - obs/registry        the documented scalar-name contract + drift guard.
 
-Everything is opt-in via --obs.* and default-off with zero hot-path
+Everything else is opt-in via --obs.* and default-off with zero hot-path
 overhead: no tracer/recorder objects exist, wire frames stay
 byte-identical DTR1, staging/learner take their pre-obs paths
 unchanged (asserted in tests/test_obs.py).

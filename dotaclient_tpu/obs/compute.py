@@ -30,8 +30,8 @@ causes:
                    per-platform peak table (TPU only; no peak entry →
                    no compute_mfu, achieved FLOP/s still reported).
 - ProfileCapture   on-demand jax.profiler.trace windows for the obs
-                   HTTP server's POST /profile?seconds=N — replaces the
-                   always-on-or-nothing cfg.profile_port server.
+                   HTTP server's POST /profile?seconds=N: the one way
+                   to open a profiler session on a running learner.
 
 Everything logs through the existing MetricsLogger stream under the
 compute_* names documented in obs/registry.py.

@@ -96,6 +96,26 @@ SCALARS: Dict[str, str] = {
     ),
     "weights_published": "weight fanout frames actually sent",
     "weights_coalesced": "weight publishes superseded before sending",
+    "weights_publish_failed": (
+        "weight publishes that raised on the publisher thread (the broker "
+        "refused the frame); logged and counted, the next one is tried"
+    ),
+    "loop_dispatch_gap_max_s": (
+        "longest interval between two consecutive train-step dispatches "
+        "in the metrics window, less the time blocked in a metrics sync "
+        "inside it (pipelined loop): how long a publish, or anything else "
+        "on the host, kept the loop from handing the device its next step"
+    ),
+    # --- compiles (obs/spans.py, a jax.monitoring listener the learner
+    #     registers once; process-wide, cumulative) ---------------------
+    "compile_count_total": (
+        "programs this process compiled or loaded from the persistent "
+        "cache (MUST stay flat in steady state)"
+    ),
+    "compile_s_total": (
+        "seconds spent tracing, lowering and compiling or loading them "
+        "(at the first metrics window: what set-up spent on its programs)"
+    ),
     "mean_episode_return": "mean per-episode return over consumed frames",
     # --- evaluator (eval/evaluator.py) ---------------------------------
     "win_rate": "evaluation win rate vs the scripted yardstick",
@@ -265,6 +285,18 @@ SCALARS: Dict[str, str] = {
 
 # Documented dynamic families (prefix → meaning of the family).
 PREFIXES: Dict[str, str] = {
+    # program spans (obs/spans.py `span(name, **ids)`, emitted by the
+    # learner loop with every metrics window): span_<name>_s_total and
+    # span_<name>_n_total (cumulative seconds and count), the name's `.`
+    # written `_`. Names: loop.dispatch / .publish_submit / .sync /
+    # .checkpoint; lane.retire / .handoff; staging.pop / .ingest / .pack
+    # / .ready_wait; publish.d2h / .serialize / .send / .latency;
+    # setup.learner_init / .init_params / .restore / .publish0. The same
+    # spans lie on the profiler's timeline while a session is open (there
+    # also loop.take, lane.wait_batch and lane.device_put, whose sums are
+    # pipeline_device_idle_s, time_wait_batch_s and time_device_put_s). A
+    # family: one span more is one name more.
+    "span_": "program spans: cumulative seconds and count (obs/spans.py)",
     # replay reservoir stats + age histogram, re-prefixed by staging:
     # replay_occupancy, replay_admitted, replay_age_le_<edge>, ...
     "replay_": "replay reservoir health (runtime/staging.py stats passthrough)",

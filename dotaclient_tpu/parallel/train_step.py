@@ -143,16 +143,25 @@ def _build_core(cfg: LearnerConfig, mesh):
     net = PolicyNet(policy, mesh=mesh)
     opt = make_optimizer(cfg)
 
+    # Named scopes: every operation of the step carries its layer in its
+    # `op_name`, forward and transpose alike, and keeps it across
+    # compiles, where the compiler's own names (`fusion.437`) change.
+    # `unpack` (below) and `optimizer` stand alone; `loss` encloses the
+    # forward pass, whose `trunk`, `lstm` and `heads` are the policy's
+    # (models/policy.py), so an operation's layer is the innermost of the
+    # six in its name and what is under `loss` alone is GAE and PPO.
     if R * M == 1:
 
         def step_fn(state: TrainState, batch: TrainBatch) -> Tuple[TrainState, Dict]:
-            (loss, metrics), grads = jax.value_and_grad(ppo_loss, has_aux=True)(
-                state.params, net.apply, batch, cfg.ppo
-            )
-            updates, opt_state = opt.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            metrics["grad_norm"] = optax.global_norm(grads)
-            return TrainState(params, opt_state, state.step + 1), metrics
+            with jax.named_scope("loss"):
+                (loss, metrics), grads = jax.value_and_grad(ppo_loss, has_aux=True)(
+                    state.params, net.apply, batch, cfg.ppo
+                )
+            with jax.named_scope("optimizer"):
+                updates, opt_state = opt.update(grads, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
+                metrics["grad_norm"] = optax.global_norm(grads)
+                return TrainState(params, opt_state, state.step + 1), metrics
 
     else:
         step_fn = _build_reuse_step_fn(cfg, mesh, net, opt, use_sp, sp)
@@ -226,16 +235,19 @@ def _build_reuse_step_fn(cfg: LearnerConfig, mesh, net, opt, use_sp: bool, sp: s
         return mbs
 
     def update(params, opt_state, mb):
-        (_, metrics), grads = jax.value_and_grad(ppo_minibatch_loss, has_aux=True)(
-            params, net.apply, mb, cfg.ppo
-        )
-        updates, new_opt = opt.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("loss"):
+            (_, metrics), grads = jax.value_and_grad(ppo_minibatch_loss, has_aux=True)(
+                params, net.apply, mb, cfg.ppo
+            )
+        with jax.named_scope("optimizer"):
+            updates, new_opt = opt.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+            metrics["grad_norm"] = optax.global_norm(grads)
         return new_params, new_opt, metrics
 
     def step_fn(state: TrainState, batch: TrainBatch) -> Tuple[TrainState, Dict]:
-        rb = precompute_reuse(state.params, net.apply, batch, cfg.ppo)
+        with jax.named_scope("loss"):
+            rb = precompute_reuse(state.params, net.apply, batch, cfg.ppo)
         # Deterministic per-step shuffle stream; no rng carried in
         # TrainState (checkpoint layout unchanged).
         rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), state.step)
@@ -363,7 +375,9 @@ def _build_fused(cfg: LearnerConfig, mesh, single: bool):
     unpack = io.unpack_single if single else io.unpack
 
     def fused_fn(state: TrainState, payload):
-        return step_fn(state, unpack(payload))
+        with jax.named_scope("unpack"):
+            batch = unpack(payload)
+        return step_fn(state, batch)
 
     step = jax.jit(
         fused_fn,

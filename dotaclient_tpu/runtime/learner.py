@@ -52,6 +52,8 @@ import jax
 import numpy as np
 
 from dotaclient_tpu.config import LearnerConfig
+from dotaclient_tpu.obs import spans
+from dotaclient_tpu.obs.spans import span, timeline
 from dotaclient_tpu.parallel import mesh as mesh_lib
 from dotaclient_tpu.parallel.train_step import (
     TrainState,
@@ -148,11 +150,12 @@ class WeightPublisher:
         # loop by construction. None = no extra work per publish.
         self._on_published = on_published
         self._cond = threading.Condition()
-        self._slot = None  # (np_params, version) — latest pending
+        self._slot = None  # (np_params, version, submit time) — latest pending
         self._stop = False
         self._thread: Optional[threading.Thread] = None
         self.published = 0  # versions actually sent (telemetry/tests)
         self.coalesced = 0  # versions superseded before sending
+        self.failed = 0  # publishes that raised (the broker refused, a bad buffer)
 
     def start(self) -> "WeightPublisher":
         # restartable after stop(), same contract as StagingBuffer.start.
@@ -185,10 +188,11 @@ class WeightPublisher:
         with self._cond:
             if self._slot is not None:
                 self.coalesced += 1
-            self._slot = (np_params, version)
+            self._slot = (np_params, version, time.perf_counter_ns())
             self._cond.notify()
 
     def _run(self) -> None:
+        spans.name_thread("weight-publisher")
         while True:
             with self._cond:
                 while self._slot is None and not self._stop:
@@ -199,20 +203,30 @@ class WeightPublisher:
                     # thread that is alive but already committed to exit
                     self._thread = None
                     return
-                np_params, version = self._slot
+                np_params, version, t_submit = self._slot
                 self._slot = None
             try:
-                frame = serialize_weights(
-                    self._materialize(np_params),
-                    version=version,
-                    boot_epoch=self._boot_epoch,
-                    legacy_dtw1=self._legacy_dtw1,
-                )
-                self._broker.publish_weights(frame)
+                with span("publish.d2h", version=version):
+                    named = self._materialize(np_params)
+                with span("publish.serialize", version=version):
+                    frame = serialize_weights(
+                        named,
+                        version=version,
+                        boot_epoch=self._boot_epoch,
+                        legacy_dtw1=self._legacy_dtw1,
+                    )
+                del named  # the host copy goes before the send, as it always did
+                with span("publish.send", version=version):
+                    self._broker.publish_weights(frame)
+                # Submit on the loop thread to sent: how old the version
+                # is when an actor can first read it. No timeline span:
+                # it starts on another thread.
+                spans.add("publish.latency", time.perf_counter_ns() - t_submit)
                 self.published += 1
                 if self._on_published is not None:
                     self._on_published(version)
             except Exception:
+                self.failed += 1
                 _log.exception("weight publish failed (version %d); continuing", version)
 
     def stop(self, flush: bool = True) -> None:
@@ -403,14 +417,18 @@ class PrefetchLane:
         return self._out.get(timeout=timeout)
 
     def _put(self, item: _LaneItem) -> None:
-        while not self.stop_event.is_set():
-            try:
-                self._out.put(item, timeout=0.2)
-                return
-            except queue.Full:
-                continue
+        # The time in here is the lane blocked on the full queue: the
+        # loop and the device pushing back (next to nothing otherwise).
+        with span("lane.handoff"):
+            while not self.stop_event.is_set():
+                try:
+                    self._out.put(item, timeout=0.2)
+                    return
+                except queue.Full:
+                    continue
 
     def _run(self) -> None:
+        spans.name_thread("learner-prefetch")
         while not self.stop_event.is_set():
             if self._limit is not None and self.fetched >= self._limit:
                 # Budget consumed: every batch the loop will train is
@@ -459,6 +477,11 @@ class PrefetchLane:
 
 class Learner:
     def __init__(self, cfg: LearnerConfig, broker: Broker, mesh=None):
+        spans.count_compiles()
+        with span("setup.learner_init"):
+            self._init(cfg, broker, mesh)
+
+    def _init(self, cfg: LearnerConfig, broker: Broker, mesh) -> None:
         self.cfg = cfg
         self.broker = broker
         self.mesh = mesh if mesh is not None else mesh_lib.make_mesh(cfg.mesh_shape)
@@ -506,8 +529,9 @@ class Learner:
         # apply_weight_frame). Time ^ pid so two boots in the same second
         # still differ.
         self.boot_epoch = (int(time.time()) << 8 ^ os.getpid()) & 0xFFFFFFFF
-        state = init_train_state(cfg, jax.random.PRNGKey(cfg.seed))
-        self.state: TrainState = jax.device_put(state, self.state_shardings)
+        with span("setup.init_params"):
+            state = init_train_state(cfg, jax.random.PRNGKey(cfg.seed))
+            self.state: TrainState = jax.device_put(state, self.state_shardings)
         # Multi-process (--multihost over DCN): batch_size stays GLOBAL;
         # each process's staging packs its share and _fetch_next stitches
         # the shares into one global array (standard multihost DP). The
@@ -582,6 +606,9 @@ class Learner:
         from dotaclient_tpu.obs import ObsRuntime
 
         self.obs = ObsRuntime.create(cfg.obs, role="learner")
+        # Long closed spans also go into the flight recorder's ring,
+        # where one exists: a crash dump then holds the last stalls.
+        spans.mirror_to(self.obs.recorder if self.obs is not None else None)
         self.staging = StagingBuffer(
             staging_cfg,
             broker,
@@ -657,16 +684,10 @@ class Learner:
                 [self.metrics.latest, self._obs_gauges], health_provider=self._health
             )
         self.env_steps_done = 0  # total real (unmasked) env steps trained on
-        if cfg.profile_port:
-            # DEPRECATED (MIGRATION.md): the always-on profiler server is
-            # superseded by on-demand POST /profile?seconds=N on the obs
-            # metrics port, which needs no TensorBoard round-trip to
-            # start a capture. Kept functional for one deprecation cycle.
-            _log.warning(
-                "--profile_port is deprecated; use POST /profile?seconds=N on "
-                "the obs metrics port (--obs.metrics_port) instead"
-            )
-            jax.profiler.start_server(cfg.profile_port)
+        # The version the next fetched batch is trained into (the lane's
+        # n-th batch is the loop's n-th step, FIFO): the `step` that feed,
+        # lane and loop spans of one batch share. Set at each run()'s start.
+        self._fetch_step = 0
         # SIGTERM drain / kill plumbing (--ckpt.*): `_drain` asks run()
         # to stop fetching, train out already-staged batches, and return
         # (the caller then drain_save()s); `_abort` asks run() to return
@@ -708,13 +729,14 @@ class Learner:
                 remote_push=self._primary,
             )
             t_restore = time.monotonic()
-            restored = self.checkpointer.restore_latest(self.state)
-            if restored is not None:
-                self.state = jax.device_put(restored, self.state_shardings)
-                self.version = int(jax.device_get(restored.step))
-                _log.info("restored checkpoint at step %d", self.version)
-                if cfg.ckpt.full_state:
-                    self._restore_full_state(t_restore)
+            with span("setup.restore"):
+                restored = self.checkpointer.restore_latest(self.state)
+                if restored is not None:
+                    self.state = jax.device_put(restored, self.state_shardings)
+                    self.version = int(jax.device_get(restored.step))
+                    _log.info("restored checkpoint at step %d", self.version)
+                    if cfg.ckpt.full_state:
+                        self._restore_full_state(t_restore)
         if self._n_proc > 1:
             # Restore is per-process and a partial host restart (one pod
             # with a fresh disk) would leave processes at DIFFERENT
@@ -1035,6 +1057,24 @@ class Learner:
 
     # --------------------------------------------------------------- loop
 
+    def _dispatch(self, batch_dev):
+        """The loop's one call of the compiled step (async: returns once
+        the step is handed to the device)."""
+        with span("loop.dispatch", step=self.version + 1):
+            return self.train_step(self.state, batch_dev)
+
+    def _submit_publish(self) -> None:
+        """One async on-device flatten dispatch; the blocking host read
+        of the single buffer happens on the publisher thread.
+        Donation-safe because this dispatch precedes the next
+        (state-donating) train step in the loop thread's stream order
+        (ParamFlattener docstring; the lane only ever touches batch
+        buffers, never the state)."""
+        with span("loop.publish_submit", version=self.version):
+            self.publisher.submit(
+                self.flattener.flatten_on_device(self.state.params), self.version
+            )
+
     def _fetch_next(self, batch_timeout: float, lane: bool = False, cancel=None):
         """Pull one batch off staging and device_put it (dp-sharded).
 
@@ -1061,13 +1101,16 @@ class Learner:
             # lane (its own fenced wall, hidden behind the device step);
             # the serial timer keeps the loop-lane single-writer path.
             add = timer.add_overlap if lane else timer.add
+        step = self._fetch_step
         t0 = time.perf_counter()
-        batch, groups = self.staging.get_batch_groups(timeout=batch_timeout, cancel=cancel)
+        with timeline("lane.wait_batch", step=step):
+            batch, groups = self.staging.get_batch_groups(timeout=batch_timeout, cancel=cancel)
         t1 = time.perf_counter()
         if add is not None:
             add("fetch", t1 - t0)
         if batch is None:
             return None, 0, t1 - t0, 0.0, None
+        self._fetch_step = step + 1
         trace = self.staging.last_batch_trace
         # Ring lease (--staging.pack_workers > 1, fused mode): the batch
         # lives in a TransferRing slot that must go back to the packers
@@ -1088,17 +1131,18 @@ class Learner:
             if add is not None:
                 add("pack", t2 - t1)
             shardings = self.fused_io.transfer_shardings()
-            if self._n_proc > 1:
-                # Each process contributes its local rows; the result is
-                # ONE global array per buffer whose dp shards live where
-                # each host put them — no cross-host data movement.
-                batch_dev = jax.tree.map(
-                    lambda arr, sh: jax.make_array_from_process_local_data(sh, arr),
-                    groups,
-                    shardings,
-                )
-            else:
-                batch_dev = jax.device_put(groups, shardings)
+            with timeline("lane.device_put", step=step):
+                if self._n_proc > 1:
+                    # Each process contributes its local rows; the result is
+                    # ONE global array per buffer whose dp shards live where
+                    # each host put them — no cross-host data movement.
+                    batch_dev = jax.tree.map(
+                        lambda arr, sh: jax.make_array_from_process_local_data(sh, arr),
+                        groups,
+                        shardings,
+                    )
+                else:
+                    batch_dev = jax.device_put(groups, shardings)
             if add is not None:
                 # Fence: the phase is the real transfer, not its dispatch.
                 # On the prefetch lane the fence blocks only the lane —
@@ -1115,19 +1159,21 @@ class Learner:
                 # in-flight device step, so the wait hides behind compute
                 # (the ParamFlattener stream-ordering argument, applied
                 # on the host side).
-                jax.block_until_ready(batch_dev)
-                lease.release()
+                with span("lane.retire", step=step):
+                    jax.block_until_ready(batch_dev)
+                    lease.release()
             if self.obs is not None and trace is not None:
                 self.obs.tracer.hop_batch("h2d", trace)
             return batch_dev, env_steps, t2 - t0, time.perf_counter() - t2, trace
-        if self._n_proc > 1:
-            batch_dev = jax.tree.map(
-                lambda arr, sh: jax.make_array_from_process_local_data(sh, np.asarray(arr)),
-                batch,
-                self.batch_sharding,
-            )
-        else:
-            batch_dev = jax.device_put(batch, self.batch_sharding)
+        with timeline("lane.device_put", step=step):
+            if self._n_proc > 1:
+                batch_dev = jax.tree.map(
+                    lambda arr, sh: jax.make_array_from_process_local_data(sh, np.asarray(arr)),
+                    batch,
+                    self.batch_sharding,
+                )
+            else:
+                batch_dev = jax.device_put(batch, self.batch_sharding)
         if add is not None:
             jax.block_until_ready(batch_dev)
             add("h2d", time.perf_counter() - t1)
@@ -1169,7 +1215,9 @@ class Learner:
             # Inside the try so a failed publish or first fetch still
             # stops the staging/publisher threads (a leaked consumer
             # would silently eat broker frames for the process lifetime).
-            self.publish_weights()  # version 0, synchronous, so actors align immediately
+            self._fetch_step = self.version + 1
+            with span("setup.publish0", version=self.version):
+                self.publish_weights()  # synchronous, so actors align immediately
             deadline = time.monotonic() + max_seconds if max_seconds is not None else None
 
             def _bt() -> float:
@@ -1266,7 +1314,7 @@ class Learner:
             batch_dev, env_steps, batch_trace = next_batch, next_env_steps, next_trace
             t_pass = time.perf_counter()
             # Async dispatch: returns immediately, device runs the step.
-            self.state, metrics = self.train_step(self.state, batch_dev)
+            self.state, metrics = self._dispatch(batch_dev)
             metrics_box[0] = metrics
             if timer is not None:
                 # Fence: device_step is dispatch + execution wall. The
@@ -1300,18 +1348,12 @@ class Learner:
 
             t_host = time.perf_counter()
             if self.version % cfg.publish_every == 0 and self._primary:
-                # One async on-device flatten dispatch; the blocking
-                # host read of the single buffer happens on the
-                # publisher thread. Donation-safe because this
-                # dispatch precedes the next (state-donating) train
-                # step in stream order (ParamFlattener docstring).
                 # Non-primary processes skip: weights are replicated
                 # and one fanout per version is the contract.
-                self.publisher.submit(
-                    self.flattener.flatten_on_device(self.state.params), self.version
-                )
+                self._submit_publish()
             if self.checkpointer is not None and self.version % cfg.checkpoint_every == 0:
-                self.checkpoint()
+                with span("loop.checkpoint", version=self.version):
+                    self.checkpoint()
 
             if timer is not None:
                 # Close the pass BEFORE a possible metrics window so
@@ -1370,6 +1412,13 @@ class Learner:
         lane.start()
         done_steps = 0
         win_wait = win_put = win_take = 0.0
+        # The longest interval between two consecutive dispatches in the
+        # metrics window, less what the loop spent blocked in a metrics
+        # sync inside it (that is the device's time, and `loop.sync` has
+        # it): how long a publish, or anything else on the host, kept the
+        # loop from handing the device its next step.
+        win_gap = 0.0
+        t_dispatch = None
         win_env_steps = 0
         win_steps = 0
         t_win = time.perf_counter()
@@ -1382,15 +1431,16 @@ class Learner:
                 # park against _bt() on its own thread).
                 item = None
                 t_take0 = time.perf_counter()
-                while item is None:
-                    if self._abort.is_set():
-                        break
-                    if deadline is not None and time.monotonic() >= deadline:
-                        break
-                    try:
-                        item = lane.get(timeout=0.2)
-                    except queue.Empty:
-                        continue
+                with timeline("loop.take", step=self.version + 1):
+                    while item is None:
+                        if self._abort.is_set():
+                            break
+                        if deadline is not None and time.monotonic() >= deadline:
+                            break
+                        try:
+                            item = lane.get(timeout=0.2)
+                        except queue.Empty:
+                            continue
                 if item is None:
                     break  # abort / deadline
                 take_s = time.perf_counter() - t_take0
@@ -1438,9 +1488,12 @@ class Learner:
                     timer.add("fetch", take_s)
                 batch_dev, env_steps, batch_trace = item.batch, item.env_steps, item.trace
                 t_pass = time.perf_counter()
+                if t_dispatch is not None:
+                    win_gap = max(win_gap, t_pass - t_dispatch)
+                t_dispatch = t_pass
                 # Async dispatch: returns immediately, device runs the
                 # step; the lane is already staging batch N+1 beside it.
-                self.state, metrics = self.train_step(self.state, batch_dev)
+                self.state, metrics = self._dispatch(batch_dev)
                 metrics_box[0] = metrics
                 if self.obs is not None and batch_trace is not None:
                     self.obs.tracer.hop_batch("apply", batch_trace)
@@ -1454,15 +1507,10 @@ class Learner:
 
                 t_host = time.perf_counter()
                 if self.version % cfg.publish_every == 0 and self._primary:
-                    # Same donation-safety as the serial loop: the
-                    # flatten dispatch precedes the next state-donating
-                    # train step in THIS thread's stream order (the lane
-                    # only ever touches batch buffers, never the state).
-                    self.publisher.submit(
-                        self.flattener.flatten_on_device(self.state.params), self.version
-                    )
+                    self._submit_publish()
                 if self.checkpointer is not None and self.version % cfg.checkpoint_every == 0:
-                    self.checkpoint()
+                    with span("loop.checkpoint", version=self.version):
+                        self.checkpoint()
 
                 if timer is not None:
                     # Overlap mode: no per-step fence. device_step is
@@ -1480,11 +1528,11 @@ class Learner:
 
                 if self.version % cfg.metrics_every == 0 or last:
                     now = time.perf_counter()
-                    self._log_window(
+                    t_dispatch += self._log_window(
                         metrics, now, t_win, win_steps, win_env_steps,
-                        win_wait, win_put, win_take=win_take,
+                        win_wait, win_put, win_take=win_take, win_gap=win_gap,
                     )
-                    win_wait = win_put = win_take = 0.0
+                    win_wait = win_put = win_take = win_gap = 0.0
                     win_env_steps = win_steps = 0
                     t_win = now
         finally:
@@ -1502,13 +1550,18 @@ class Learner:
         win_wait: float,
         win_put: float,
         win_take: Optional[float] = None,
-    ) -> None:
+        win_gap: Optional[float] = None,
+    ) -> float:
         """One metrics window — the ONLY routine device sync in the loop
         (jax.device_get of the step metrics). Shared by both loop shapes;
         `win_take` is the pipelined loop's exposed take-wait accumulator
-        (None = serial split)."""
+        and `win_gap` its longest interval between two dispatches (None =
+        serial split). Returns the seconds the sync blocked."""
         compute = self.obs.compute if self.obs is not None else None
-        scalars = {k: float(v) for k, v in jax.device_get(metrics).items()}
+        t_sync = time.perf_counter()
+        with span("loop.sync", step=self.version):
+            scalars = {k: float(v) for k, v in jax.device_get(metrics).items()}
+        sync_s = time.perf_counter() - t_sync
         stats = self.staging.stats()
         dt = max(now - t_win, 1e-9)
         n = max(win_steps, 1)
@@ -1534,6 +1587,7 @@ class Learner:
             scalars["pipeline_overlap_ratio"] = (
                 max(0.0, min(1.0, 1.0 - win_take / lane_s)) if lane_s > 0 else 1.0
             )
+            scalars["loop_dispatch_gap_max_s"] = win_gap
         scalars["active_actors"] = stats["active_actors"]
         scalars["staleness_dropped"] = stats["dropped_stale"]
         scalars["staging_quarantined"] = stats["quarantined"]
@@ -1570,6 +1624,10 @@ class Learner:
                 scalars[k] = v
         scalars["weights_published"] = self.publisher.published
         scalars["weights_coalesced"] = self.publisher.coalesced
+        scalars["weights_publish_failed"] = self.publisher.failed
+        # Every span of this process (obs/spans.py), cumulative, and the
+        # compile counters: loop, lane, staging, publisher, set-up.
+        scalars.update(spans.scalars())
         if self.checkpointer is not None:
             # Remote-mirror health (ADVICE r4): a growing lag means
             # uploads can't keep the checkpoint cadence and durability
@@ -1613,6 +1671,7 @@ class Learner:
             scalars["env_steps_per_sec"],
             scalars["time_step_s"],
         )
+        return sync_s
 
     def close(self) -> None:
         if self._ckpt_worker is not None:

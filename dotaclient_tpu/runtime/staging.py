@@ -52,6 +52,8 @@ from dotaclient_tpu.config import LearnerConfig
 from dotaclient_tpu.ops.batch import BatchLayoutError, TrainBatch, zeros_train_batch
 
 _log = logging.getLogger(__name__)
+from dotaclient_tpu.obs import spans
+from dotaclient_tpu.obs.spans import span
 from dotaclient_tpu.obs.trace import TraceRef
 from dotaclient_tpu.transport.base import Broker
 from dotaclient_tpu.transport.serialize import (
@@ -180,6 +182,7 @@ class _PackPool:
             t.start()
 
     def _run(self, i: int) -> None:
+        spans.name_thread(threading.current_thread().name)
         while True:
             t0 = time.perf_counter()
             try:
@@ -652,7 +655,8 @@ class StagingBuffer:
                 break
             t_pack = time.perf_counter()
             try:
-                batch, groups, lease = self._pack(items)
+                with span("staging.pack"):
+                    batch, groups, lease = self._pack(items)
             except BatchLayoutError:
                 # layout/config mismatch: fails every batch, not this
                 # batch — propagate to the fatal handler in the caller
@@ -683,12 +687,15 @@ class StagingBuffer:
                     self._stats["pack_wall_s"] += time.perf_counter() - t_pack
                 if staleness is not None:
                     self._stats["rows_replayed"] += sum(1 for s in staleness if s > 0)
-            while not self._stop.is_set():
-                try:
-                    self._ready.put((batch, groups, traces, lease), timeout=0.2)
-                    break
-                except queue.Full:
-                    continue
+            # The time in here is this thread blocked on the full ready
+            # queue: the lane and the loop pushing back.
+            with span("staging.ready_wait"):
+                while not self._stop.is_set():
+                    try:
+                        self._ready.put((batch, groups, traces, lease), timeout=0.2)
+                        break
+                    except queue.Full:
+                        continue
             self._packing = False  # batch visible in _ready (or dead with _stop)
 
     def _drain_residual(self, max_items: int, sink) -> None:
@@ -717,10 +724,11 @@ class StagingBuffer:
     def _run(self) -> None:
         """Classic single consumer thread (pack_workers=1): pop → parse →
         pack, all here — byte-for-byte the pre-pool behavior."""
+        spans.name_thread("staging-consumer")
         B = self.cfg.batch_size
 
         def _ingest_sink(frames):
-            with self._mutate_lock:
+            with self._mutate_lock, span("staging.ingest"):
                 self._ingest(frames)
 
         while not self._stop.is_set():
@@ -733,10 +741,10 @@ class StagingBuffer:
                     self._drain_residual(B, _ingest_sink)
                     frames = None
                 else:
-                    frames = self.broker.consume_experience(max_items=B, timeout=0.2)
+                    with span("staging.pop"):
+                        frames = self.broker.consume_experience(max_items=B, timeout=0.2)
                 if frames:
-                    with self._mutate_lock:
-                        self._ingest(frames)
+                    _ingest_sink(frames)
                 self._pack_pending_loop(B)
             except BatchLayoutError as e:
                 self._die_on_layout(e)
@@ -754,6 +762,7 @@ class StagingBuffer:
         (the single-consumer serialization the parallel feed removes).
         The intake bound (4 drains) is the backpressure that stops an
         outrun learner from buffering the broker into learner RAM."""
+        spans.name_thread("staging-consumer")
         B = self.cfg.batch_size
 
         def _intake_sink(frames):
@@ -779,7 +788,8 @@ class StagingBuffer:
                     # same visibility contract as _packing.
                     self._popping = True
                 try:
-                    frames = self.broker.consume_experience(max_items=B, timeout=0.2)
+                    with span("staging.pop"):
+                        frames = self.broker.consume_experience(max_items=B, timeout=0.2)
                     if frames:
                         _intake_sink(frames)
                 finally:
@@ -797,6 +807,7 @@ class StagingBuffer:
         header parse releases the GIL, so this genuinely overlaps the
         pop thread and the pack workers), forms batches, and dispatches
         row-sharded packs to the worker pool."""
+        spans.name_thread("staging-assembler")
         B = self.cfg.batch_size
         while not self._stop.is_set():
             try:
@@ -806,7 +817,7 @@ class StagingBuffer:
                     frames = None
                 if frames is not None:
                     try:
-                        with self._mutate_lock:
+                        with self._mutate_lock, span("staging.ingest"):
                             self._ingest(frames)
                     finally:
                         # unfinished_tasks hits 0 only after the frames

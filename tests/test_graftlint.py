@@ -630,9 +630,9 @@ def test_early_lease_release_mutant_fails_lint(tmp_path):
     lp = root / "dotaclient_tpu" / "runtime" / "learner.py"
     src = lp.read_text()
     mutant = src.replace(
-        "                jax.block_until_ready(batch_dev)\n"
-        "                lease.release()",
-        "                lease.release()",
+        "                    jax.block_until_ready(batch_dev)\n"
+        "                    lease.release()",
+        "                    lease.release()",
     )
     assert mutant != src, "learner release site moved — update this pin"
     lp.write_text(mutant)
