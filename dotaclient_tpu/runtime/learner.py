@@ -76,12 +76,14 @@ class ParamFlattener:
     The flagship params tree has ~30 leaves, and every D2H read pays a
     per-transfer overhead (the same one parallel/fused_io.py removes on
     the H2D side), so a per-leaf device_get would pay it ~30 times — ON
-    THE LOOP THREAD, every publish_every steps; what it costs on a chip
-    is ROADMAP S4's to measure. Instead a tiny jit concatenates the
-    raveled leaves into one
-    f32 buffer ON DEVICE (async dispatch, ~1 copy of ~1 MB); the
-    blocking host read of that single buffer happens on the publisher
-    thread. Stream ordering makes this donation-safe: the flatten
+    THE LOOP THREAD, every publish_every steps. Instead a tiny jit
+    concatenates the raveled leaves into one f32 buffer ON DEVICE
+    (async dispatch, one copy: 0.9 MB at the 128-wide policy, 546 MB =
+    136,584,631 f32 at the 4096-wide one); the blocking host read of
+    that single buffer happens on the publisher thread (`publish.d2h`,
+    0.45-0.73 s a publish at 4096 on a v5e; PERF.md section 5), and
+    `serialize_weights` then copies each leaf's slice of it once into
+    the frame. Stream ordering makes this donation-safe: the flatten
     program is dispatched BEFORE the next (state-donating) train step,
     so it reads the params before donation can reuse them.
     """

@@ -198,8 +198,12 @@ class RmqBroker(Broker):
             return self._run_with_reconnect(op)
 
     def publish_weights(self, data: bytes) -> None:
+        # pika frames a `bytes` body and nothing else; the learner's
+        # frame is a read-only buffer (serialize_weights)
+        body = data if isinstance(data, bytes) else bytes(data)
+
         def op():
-            self._ch.basic_publish(exchange=MODEL_EXCHANGE, routing_key="", body=data)
+            self._ch.basic_publish(exchange=MODEL_EXCHANGE, routing_key="", body=body)
 
         with self._lock:
             self._run_with_reconnect(op)

@@ -684,7 +684,7 @@ def serialize_weights(
     version: int,
     boot_epoch: int = 0,
     legacy_dtw1: bool = False,
-) -> bytes:
+) -> memoryview:
     """Weight fanout frame. `boot_epoch` identifies the publishing
     learner PROCESS (drawn once at learner boot): subscribers resync on
     an epoch change — the deterministic learner-restart signal that
@@ -699,23 +699,42 @@ def serialize_weights(
     stale-weights kill switch turns a botched ordering into loud pod
     restarts instead of a silent cluster-wide policy freeze."""
     if legacy_dtw1:
-        parts = [struct.pack("<4sII", _WEIGHTS_MAGIC, version, len(named_arrays))]
+        top = struct.pack("<4sII", _WEIGHTS_MAGIC, version, len(named_arrays))
     else:
-        parts = [
-            struct.pack(
-                "<4sIII", _WEIGHTS_MAGIC2, version, boot_epoch & 0xFFFFFFFF, len(named_arrays)
-            )
-        ]
+        top = struct.pack(
+            "<4sIII", _WEIGHTS_MAGIC2, version, boot_epoch & 0xFFFFFFFF, len(named_arrays)
+        )
+    leaves = []  # (small header, contiguous array) per leaf
+    total = len(top)
     for name, arr in named_arrays:
         arr = np.ascontiguousarray(arr)
         nb = name.encode()
-        parts.append(struct.pack("<H", len(nb)))
-        parts.append(nb)
-        parts.append(struct.pack("<B", arr.ndim))
-        parts.append(struct.pack(f"<{arr.ndim}I", *arr.shape) if arr.ndim else b"")
-        parts.append(struct.pack("<B", _dtype_code(arr.dtype)))
-        parts.append(arr.tobytes())
-    return b"".join(parts)
+        head = (
+            struct.pack("<H", len(nb))
+            + nb
+            + struct.pack(f"<B{arr.ndim}IB", arr.ndim, *arr.shape, _dtype_code(arr.dtype))
+        )
+        leaves.append((head, arr))
+        total += len(head) + arr.nbytes
+    # One buffer of the frame's length, written once, by copies that
+    # drop the GIL (`np.copyto` between plain dtypes does; `tobytes`,
+    # `bytes(...)` and a memoryview slice assignment hold it), so the
+    # train loop keeps dispatching under a 546 MB publish. `np.empty`
+    # touches no page: `bytearray(n)` is a memset with the GIL held,
+    # 0.6 s at that size on a v5e's host (PERF.md, PR 28). A fresh
+    # buffer per frame: the broker's slot and any subscriber may still
+    # hold the last one.
+    buf = np.empty(total, np.uint8)
+    buf[: len(top)] = np.frombuffer(top, np.uint8)
+    off = len(top)
+    for head, arr in leaves:
+        buf[off : off + len(head)] = np.frombuffer(head, np.uint8)
+        off += len(head)
+        np.copyto(buf[off : off + arr.nbytes], arr.reshape(-1).view(np.uint8))
+        off += arr.nbytes
+    # Read-only, as `bytes` was: in-process subscribers share one frame,
+    # and `deserialize_weights` hands out views of it.
+    return memoryview(buf).toreadonly()
 
 
 def deserialize_weights(data: bytes) -> Tuple[List[Tuple[str, np.ndarray]], int, int]:
@@ -735,7 +754,7 @@ def deserialize_weights(data: bytes) -> Tuple[List[Tuple[str, np.ndarray]], int,
     for _ in range(n):
         (name_len,) = struct.unpack_from("<H", data, off)
         off += 2
-        name = data[off : off + name_len].decode()
+        name = str(data[off : off + name_len], "utf-8")  # any buffer, not bytes alone
         off += name_len
         (ndim,) = struct.unpack_from("<B", data, off)
         off += 1

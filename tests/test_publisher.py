@@ -185,3 +185,36 @@ def test_legacy_dtw1_transition_flag():
     named, version, boot_epoch = deserialize_weights(broker.frames[-1])
     assert version == 6 and boot_epoch == 0  # DTW1 carries no epoch
     np.testing.assert_array_equal(named[0][1], np.full((4, 4), 2.5, np.float32))
+
+
+def test_frame_through_the_memory_broker_reads_back_equal():
+    """Whatever type the frame has, `mem://` takes it as it is and a
+    subscriber reads every leaf back."""
+    from dotaclient_tpu.transport import memory as mem
+    from dotaclient_tpu.transport.base import connect
+    from dotaclient_tpu.transport.serialize import flatten_params
+
+    mem.reset("pubframe")
+    params = {
+        "core": {"kernel": np.arange(48, dtype=np.float32).reshape(6, 8), "bias": np.ones(8, np.float32)},
+        "head": {"kernel": np.linspace(-1, 1, 24, dtype=np.float32).reshape(8, 3)},
+    }
+    pub = WeightPublisher(connect("mem://pubframe"), boot_epoch=77).start()
+    sub = connect("mem://pubframe")
+    try:
+        pub.submit(params, version=5)
+        deadline = time.monotonic() + 10.0
+        while pub.published < 1 and time.monotonic() < deadline:
+            time.sleep(0.005)
+    finally:
+        pub.stop()
+    assert pub.published == 1 and pub.failed == 0
+    frame = sub.poll_weights()
+    assert frame is not None and sub.poll_weights() is None
+    named, version, boot_epoch = deserialize_weights(frame)
+    assert (version, boot_epoch) == (5, 77)
+    want = flatten_params(params)
+    assert [n for n, _ in named] == [n for n, _ in want]
+    for (_, got), (_, leaf) in zip(named, want):
+        np.testing.assert_array_equal(got, leaf)
+    mem.reset("pubframe")

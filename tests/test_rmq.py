@@ -49,6 +49,24 @@ def test_consume_respects_max_items(rmq):
     assert first + rest == [bytes([i]) for i in range(10)]
 
 
+def test_weight_frame_reaches_pika_as_bytes(rmq):
+    """serialize_weights returns a read-only buffer; pika frames `bytes`
+    and nothing else, so the conversion is this transport's."""
+    import numpy as np
+
+    from dotaclient_tpu.transport.serialize import deserialize_weights, serialize_weights
+
+    learner, actor = rmq(), rmq()
+    frame = serialize_weights([("w", np.arange(4, dtype=np.float32))], version=3, boot_epoch=8)
+    assert not isinstance(frame, bytes)
+    learner.publish_weights(frame)
+    got = actor.poll_weights()
+    assert type(got) is bytes and got == bytes(frame)
+    named, version, boot_epoch = deserialize_weights(got)
+    assert (version, boot_epoch) == (3, 8)
+    np.testing.assert_array_equal(named[0][1], np.arange(4, dtype=np.float32))
+
+
 def test_weights_fanout_latest_wins(rmq):
     learner = rmq()
     actor_a, actor_b = rmq(), rmq()

@@ -1,5 +1,7 @@
+import struct
 import threading
 import time
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -530,6 +532,117 @@ def test_weight_frame_legacy_dtw1_golden_bytes():
     np.testing.assert_array_equal(named[0][1], [1.0, -2.0])
 
 
+def _serialize_weights_reference(named_arrays, version, boot_epoch=0, legacy_dtw1=False) -> bytes:
+    """The frame as it was built before it was written once: one
+    `tobytes` per leaf, then a join. Kept as the plain reference."""
+    if legacy_dtw1:
+        parts = [struct.pack("<4sII", b"DTW1", version, len(named_arrays))]
+    else:
+        parts = [
+            struct.pack("<4sIII", b"DTW2", version, boot_epoch & 0xFFFFFFFF, len(named_arrays))
+        ]
+    codes = {np.dtype(np.float32): 0, np.dtype(np.int32): 1, np.dtype(np.uint8): 2}
+    for name, arr in named_arrays:
+        arr = np.ascontiguousarray(arr)
+        nb = name.encode()
+        parts.append(struct.pack("<H", len(nb)))
+        parts.append(nb)
+        parts.append(struct.pack("<B", arr.ndim))
+        parts.append(struct.pack(f"<{arr.ndim}I", *arr.shape) if arr.ndim else b"")
+        parts.append(struct.pack("<B", codes[arr.dtype]))
+        parts.append(arr.tobytes())
+    return b"".join(parts)
+
+
+def _f32(*shape):
+    return np.arange(int(np.prod(shape)), dtype=np.float32).reshape(shape) - 3.5
+
+
+_WEIGHT_FRAME_CASES = {
+    "dtw2": ([("core/kernel", _f32(3, 4)), ("core/bias", _f32(4))], {}),
+    "legacy_dtw1": ([("core/kernel", _f32(3, 4)), ("core/bias", _f32(4))], {"legacy_dtw1": True}),
+    "zero_d_leaf": ([("scale", np.array(2.5, np.float32)), ("w", _f32(2, 2))], {}),
+    "non_contiguous_leaf": ([("t", _f32(3, 4).T), ("strided", _f32(10)[::3])], {}),
+    "int32_leaf": ([("steps", np.arange(-3, 4, dtype=np.int32).reshape(7, 1)), ("w", _f32(2))], {}),
+    "uint8_leaf": ([("mask", np.arange(250, 256, dtype=np.uint8)), ("w", _f32(3))], {}),
+    "empty_list": ([], {}),
+    "boot_epoch_above_u32": ([("w", _f32(5))], {"boot_epoch": 2**32 + 0xDEADBEEF}),
+    "zero_size_leaf": ([("none", np.zeros((0, 3), np.float32)), ("w", _f32(2, 3))], {}),
+    "unicode_name": ([("h\u00e9ro/\u6838", _f32(2))], {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WEIGHT_FRAME_CASES))
+def test_weight_frame_is_byte_for_byte_the_reference(case):
+    leaves, kw = _WEIGHT_FRAME_CASES[case]
+    frame = serialize_weights(leaves, version=41, **kw)
+    want = _serialize_weights_reference(leaves, version=41, **kw)
+    assert bytes(frame) == want
+    assert frame == want  # and compares as the buffer it is, no copy asked of the caller
+    named, version, boot_epoch = deserialize_weights(frame)
+    assert version == 41
+    legacy = kw.get("legacy_dtw1", False)
+    assert boot_epoch == (0 if legacy else kw.get("boot_epoch", 0) & 0xFFFFFFFF)
+    assert [n for n, _ in named] == [n for n, _ in leaves]
+    for (_, got), (_, leaf) in zip(named, leaves):
+        sent = np.ascontiguousarray(leaf)  # a 0-d leaf travels as shape (1,)
+        assert got.dtype == sent.dtype and got.shape == sent.shape
+        np.testing.assert_array_equal(got, sent)
+        assert not got.flags.writeable  # subscribers share one frame
+
+
+def test_weight_frame_is_allocated_once():
+    """The mechanism, not a speed: building the frame allocates the
+    frame and nothing else of its size (per-leaf `tobytes` and a join
+    allocated it twice)."""
+    tree = [(f"layer{i}/kernel", np.full((2048, 2048), i, np.float32)) for i in range(4)]  # 64 MB
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+        frame = serialize_weights(tree, version=1)
+        peak = tracemalloc.get_traced_memory()[1] - before
+    finally:
+        tracemalloc.stop()
+    assert len(frame) > 64 * 2**20
+    # the lower bound shows the frame's own buffer is among what was traced
+    assert len(frame) <= peak < 1.25 * len(frame), (peak, len(frame))
+
+
+def test_weight_frame_copies_release_the_gil():
+    """The mechanism, not a speed: while another thread builds a frame
+    with a 256 MB leaf, this thread keeps running. Its longest wait for
+    the interpreter is under a third of the time the frame took; best
+    of three, so that a loaded machine does not fail it."""
+    tree = [("core/kernel", np.zeros((8192, 8192), np.float32)), ("core/bias", _f32(64))]
+    shares = []
+    for _ in range(3):
+        go, done, took = threading.Event(), threading.Event(), []
+
+        def build():
+            go.wait(10)
+            t0 = time.perf_counter()
+            frame = serialize_weights(tree, version=1)
+            took.append(time.perf_counter() - t0)
+            assert len(frame) > 256 * 2**20
+            done.set()
+
+        worker = threading.Thread(target=build)
+        worker.start()
+        longest, last = 0.0, time.perf_counter()
+        go.set()
+        while not done.is_set():
+            time.sleep(0.0005)
+            now = time.perf_counter()
+            longest, last = max(longest, now - last), now
+        worker.join(timeout=30)
+        assert not worker.is_alive() and took
+        shares.append(longest / took[0])
+        if shares[-1] < 1 / 3:
+            break
+    assert min(shares) < 1 / 3, shares
+
+
 def test_weights_roundtrip_with_params_tree():
     import jax
 
@@ -615,6 +728,20 @@ class TestTcpBroker:
         pub.publish_weights(b"W2")
         assert sub.poll_weights() == b"W2"
         assert sub.poll_weights() is None
+        pub.close(), sub.close()
+
+    def test_weight_frame_as_serialize_weights_builds_it(self, server):
+        # the frame is a read-only buffer, not `bytes`: the client takes
+        # it as it is and a subscriber reads the same bytes back
+        pub = TcpBroker(port=server.port)
+        sub = TcpBroker(port=server.port)
+        frame = serialize_weights([("w", np.arange(6, dtype=np.float32))], version=9, boot_epoch=3)
+        pub.publish_weights(frame)
+        got = sub.poll_weights()
+        assert got == bytes(frame)
+        named, version, boot_epoch = deserialize_weights(got)
+        assert (version, boot_epoch) == (9, 3)
+        np.testing.assert_array_equal(named[0][1], np.arange(6, dtype=np.float32))
         pub.close(), sub.close()
 
     def test_consume_blocks_for_first_frame(self, server):
