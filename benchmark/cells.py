@@ -45,6 +45,17 @@ def _find(bench: dict, relative: str, root: str) -> str:
     raise FileNotFoundError(f"{relative} under none of {bench['paths']}")
 
 
+def load_scopes(bench: dict, config: str, root: str = ROOT):
+    """The layer scopes that `scopes/<config>.json` declares for the
+    configuration's step program, or None where it has no such file."""
+    try:
+        path = _find(bench, os.path.join("scopes", config + ".json"), root)
+    except FileNotFoundError:
+        return None
+    with open(path) as f:
+        return list(json.load(f)["scopes"])
+
+
 def metrics_for(bench: dict, workload: str, group: str):
     """The metric entries of `group` (end_to_end | per_layer) that this
     cell reports: all without a `workloads` key, and those that list it."""

@@ -398,6 +398,9 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
         "device": device, "peak_bytes": peak, "publish_every": cfg.publish_every,
         "rows_per_step": B, "trace": None,
         "step_flops": reference.train_step_flops(config, B),
+        # one chip's share of a step, by layer scope, where the reference counts it
+        "scope_costs": reference.scope_costs(config, B // chips)
+        if hasattr(reference, "scope_costs") else {},
     }
     result = {
         "correct": bool(correct),
@@ -418,8 +421,14 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
             size = os.path.getsize(found[-1])
         finally:
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        run["trace"] = trace_reduce.reduce(events, chips=chips)
+        run["trace"] = trace_reduce.reduce(
+            events, chips=chips, scopes=cells.load_scopes(bench, cell["config"], root))
         say(f"trace: {size / 1e6:.1f} MB read and reduced in {time.perf_counter() - t:.2f} s")
+        for at, gap, under in run["trace"]["longest_gaps"]:
+            say(f"trace: device idle {1e3 * gap:.3f} ms at +{at:.3f} s under {under}")
+        by_scope = run["trace"]["scope_self_s"]  # None: no scopes declared, or they carry too little
+        say("trace: step by scope, ms: " + json.dumps(by_scope and {
+            k: round(1e3 * v / run["trace"]["step_count"], 4) for k, v in by_scope.items()}))
         device["busy_s"] = run["trace"]["busy_s"]
         device["window_s"] = run["trace"]["window_s"]
         result["breakdown"] = run["trace"]["breakdown"]
@@ -427,6 +436,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
             value = cells.load_reader(bench, m["name"], root)(run)
             if value is not None:
                 result["metrics"][m["name"]] = {"value": float(value), "unit": m["unit"]}
+            else:
+                say(f"note {m['name']}: its reader found nothing to read in this run")
     else:
         for m in cells.metrics_for(bench, workload, "end_to_end"):
             value = cells.load_reader(bench, m["name"], root)(run)

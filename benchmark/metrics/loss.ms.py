@@ -1,0 +1,9 @@
+"""Device step: the device's self time in the scope `loss` outside the
+policy's own scopes (GAE, the surrogate, the gathers), ms a step; device
+trace by scope (`trace_reduce.reduce`'s `scope_self_s`)."""
+
+from benchmark import trace_reduce
+
+
+def read(run):
+    return trace_reduce.scope_ms(run["trace"], "loss")

@@ -292,3 +292,32 @@ def train_step_flops(config: dict, rows: int) -> float:
     percent)."""
     frames = rows * (int(config["learner"]["seq_len"]) + 1)
     return 3.0 * frames * forward_flops_per_frame(config)
+
+
+def n_params(config: dict) -> int:
+    leaves = jax.tree.leaves(param_shapes(config), is_leaf=lambda x: isinstance(x, tuple))
+    return sum(math.prod(shape) for shape in leaves)
+
+
+def scope_costs(config: dict, rows: int) -> dict:
+    """What one optimizer step over `rows` rows on one chip needs at the
+    least inside a layer scope of `scopes/<config>.json`, for the scope's
+    share of its roofline: matrix-multiply operations counted as
+    `train_step_flops` counts them, and bytes to and from the chip's memory.
+
+    `lstm`: the input and the recurrent product of every observation,
+    forward and twice that backward; the two matrices and the bias read
+    and their gradients written once in float32, the layer's input and
+    output and their two cotangents passed once in the compute type.
+    `optimizer`: Adam reads parameter, gradient and both moments and writes
+    parameter and both moments, 7 float32 values a parameter; it multiplies
+    no matrices, so its bytes alone bound it."""
+    H = int(config["policy"]["lstm_hidden"])
+    frames = rows * (int(config["learner"]["seq_len"]) + 1)
+    itemsize = jnp.dtype(config["policy"]["dtype"]).itemsize
+    lstm_params = 2 * H * 4 * H + 4 * H
+    return {
+        "lstm": {"flops": 3.0 * frames * 2 * (2.0 * H * 4 * H),
+                 "bytes": 8.0 * lstm_params + 4.0 * frames * H * itemsize},
+        "optimizer": {"flops": 0.0, "bytes": 28.0 * n_params(config)},
+    }

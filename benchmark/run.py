@@ -23,7 +23,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def setup_jax(cache_dir: str) -> None:
     """The compile cache at its fixed place inside the checkout (or
-    where JAX_COMPILATION_CACHE_DIR says), every program kept."""
+    where JAX_COMPILATION_CACHE_DIR says), every program kept, and keyed
+    with its metadata: an executable that another tree compiled carries
+    that tree's `op_name`s and source lines into the trace, and the
+    per-scope metrics would read them as this tree's."""
     import jax
 
     os.makedirs(cache_dir, exist_ok=True)
@@ -31,6 +34,7 @@ def setup_jax(cache_dir: str) -> None:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def main(argv=None, platform: str = "tpu", root: str = None) -> int:

@@ -100,7 +100,6 @@ def test_files_are_found_by_name(bench):
         assert cfg["name"] == w["config"]
         assert cell["traffic_data"]["name"] == w["traffic"]
         assert cfg["source"] and cfg["deployment"] and cfg["assumed"]
-        assert cfg["policy"]["lstm_layers"] == 1 and cfg["learner"]["seq_len"] == 16
         assert cfg["ppo"]["max_staleness"] == 3 * cfg["learner"]["publish_every"]
         traffic = cell["traffic_data"]
         assert cells.load_module(bench, "references", cfg["reference"]).run_reference
