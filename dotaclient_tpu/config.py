@@ -58,6 +58,54 @@ class PolicyConfig:
     # activation memory — the standard long-context lever. No effect on
     # actor stepping (no backward) or on the math (tested identical).
     tf_remat: bool = False
+    # The block's own sizes, as a published model's config states them;
+    # at these defaults the block is the family's first one (pre-LN,
+    # heads of lstm_hidden // tf_heads, full causal attention, a dense
+    # GELU MLP of 4x, rotary base 10,000, biases, no final norm).
+    tf_kv_heads: int = 0  # key/value heads (grouped-query attention); 0 = tf_heads
+    tf_head_dim: int = 0  # width of one head; 0 = lstm_hidden // tf_heads
+    # Kind of each layer, a comma list repeated over tf_layers: "full"
+    # (causal) or "sliding" (causal, the last tf_window keys). "" = all full.
+    tf_layer_kinds: str = ""
+    tf_window: int = 0  # keys a query of a sliding layer sees, itself included
+    tf_rope_theta: float = 10000.0  # rotary base, both kinds
+    # YaRN on the full layers' rotary table (0 = the default table there
+    # too): inverse frequencies blended between the default and default /
+    # factor by the linear ramp over the correction range of (beta_fast,
+    # beta_slow) at the original context; cos and sin times
+    # 0.1 ln(factor) + 1 (ops/attention.py rope_table).
+    tf_yarn_factor: float = 0.0
+    tf_yarn_original_context: int = 0
+    tf_yarn_beta_fast: float = 32.0
+    tf_yarn_beta_slow: float = 1.0
+    tf_norm: str = "layernorm"  # or "rmsnorm"
+    tf_norm_eps: float = 1e-6
+    tf_bias: bool = True  # biases on the block's projections
+    tf_final_norm: bool = False  # a norm after the last block
+    # Routed-expert feed-forward layer in place of the dense MLP
+    # (ops/moe.py); 0 experts = the dense MLP. The router scores all
+    # moe_experts; this process holds moe_experts_held of them (0 = all),
+    # from moe_first_expert on, and computes their part of the sum: the
+    # share of an expert-parallel group that one chip holds.
+    moe_experts: int = 0
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
+    moe_top_k: int = 0  # experts a frame is routed to
+    moe_hidden: int = 0  # width of one expert (SwiGLU)
+    # The router's scores are standardised per expert before the softmax:
+    # less their running mean, over the root of the running mean of that
+    # difference's square, over the chunk's frames so far (causal: the actor's step carries the
+    # sums and agrees with the learner's unroll). Under seeded weights the
+    # residual stream is mostly one vector that every frame shares (a relu
+    # trunk's output, then the attention layers' averages) and the rest
+    # favours some experts several times over others; a trained model's
+    # load-balancing loss prevents both, and this stands in for it.
+    moe_standardize_router: bool = False
+    # Grouped products of the expert layer (ops/moe.py): "auto" = the
+    # Pallas grouped-matmul kernel where the program runs on a TPU,
+    # jax.lax.ragged_dot elsewhere; "ragged_dot" | "megablox" |
+    # "megablox_interpret" (the kernel on the CPU, for tests) as asked.
+    moe_impl: str = "auto"
     n_move_bins: int = 9  # 9-way discretized move offsets per axis
     move_step: float = 350.0  # map units per outermost move-grid cell
     # Auxiliary value heads (benchmark config 5: win-prob, last-hit, net-worth).

@@ -54,6 +54,9 @@ class PolicyOutput(NamedTuple):
     dist: Dist
     value: jnp.ndarray  # [...] f32
     aux: Optional[AuxOutputs]
+    # Counters of the forward pass for the step's metrics (scalars by
+    # name; a routed-expert core's routing load), or None.
+    stats: Optional[dict] = None
 
 
 def _dtype(cfg: PolicyConfig):

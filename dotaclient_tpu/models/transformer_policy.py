@@ -41,6 +41,7 @@ from jax.sharding import Mesh
 
 from dotaclient_tpu.config import PolicyConfig
 from dotaclient_tpu.ops import attention as A
+from dotaclient_tpu.ops import moe
 from dotaclient_tpu.ops import ring_attention as RA
 
 
@@ -48,56 +49,158 @@ class KVCache(NamedTuple):
     """Actor-side attention state. Every leaf is BATCH-LEADING (like the
     LSTM's (c, h)) so the generic state plumbing — selfplay's per-side
     concat/slice batching, the actor's row resets — works unchanged:
-    k/v [B, L, C, N, Dh]; pos [B, C] holds absolute positions with
+    k/v [B, L, C, G, Dh] (G key/value heads); pos [B, C] holds absolute positions with
     EMPTY_POS in unwritten slots (shared across layers — every layer
-    sees the same timeline); idx [B] is each row's next write slot."""
+    sees the same timeline); idx [B] is each row's next write slot and the
+    count of frames stepped."""
 
     k: jnp.ndarray
     v: jnp.ndarray
     pos: jnp.ndarray
     idx: jnp.ndarray
+    rsum: jnp.ndarray  # [B, L, 2, E] f32: each layer's sums of `ops.moe.standardize` over the frames so far
+
+
+def head_shape(cfg: PolicyConfig) -> Tuple[int, int, int]:
+    """(query heads, key/value heads, head width) of the block."""
+    N = cfg.tf_heads
+    G = cfg.tf_kv_heads or N
+    if not cfg.tf_head_dim and cfg.lstm_hidden % N:
+        raise ValueError(
+            f"transformer width lstm_hidden={cfg.lstm_hidden} must divide by tf_heads={N}"
+        )
+    Dh = cfg.tf_head_dim or cfg.lstm_hidden // N
+    if N % G:
+        raise ValueError(f"tf_heads={N} must divide by tf_kv_heads={G}")
+    if Dh % 2:
+        raise ValueError(f"head dim {Dh} must be even (RoPE rotates half-pairs)")
+    return N, G, Dh
+
+
+def layer_kinds(cfg: PolicyConfig) -> Tuple[str, ...]:
+    """The kind of each of the tf_layers layers: cfg.tf_layer_kinds'
+    comma list, repeated."""
+    period = [k.strip() for k in (cfg.tf_layer_kinds or "full").split(",")]
+    for k in period:
+        if k not in ("full", "sliding"):
+            raise ValueError(f"tf_layer_kinds: unknown kind {k!r} (full|sliding)")
+        if k == "sliding" and cfg.tf_window <= 0:
+            raise ValueError("a sliding layer needs tf_window > 0")
+    return tuple(period[i % len(period)] for i in range(cfg.tf_layers))
 
 
 def init_cache(cfg: PolicyConfig, batch_shape) -> KVCache:
     B = int(batch_shape[0]) if len(batch_shape) else 1
-    L, C, N = cfg.tf_layers, cfg.tf_context, cfg.tf_heads
+    L, C = cfg.tf_layers, cfg.tf_context
     # Fail at config time, not as a confusing shape error deep in a later
     # trace: a host-side init_cache with indivisible width would silently
-    # build a mis-shaped cache (ADVICE r3 item 1). RoPE additionally
-    # needs an even head dim.
-    if cfg.lstm_hidden % N:
-        raise ValueError(
-            f"transformer width lstm_hidden={cfg.lstm_hidden} must divide by "
-            f"tf_heads={N}"
-        )
-    Dh = cfg.lstm_hidden // N
-    if Dh % 2:
-        raise ValueError(f"head dim {Dh} must be even (RoPE rotates half-pairs)")
+    # build a mis-shaped cache (ADVICE r3 item 1).
+    _, G, Dh = head_shape(cfg)
     # K/V live in the COMPUTE dtype: the values written are Dense outputs
     # in that dtype anyway, so f32 storage was pure memory/H2D overhead
     # (2x actor cache bytes); scores still accumulate in f32 inside
     # attention (ADVICE r3 item 3). pos/idx stay int32.
     dt = jnp.dtype(cfg.dtype)
     return KVCache(
-        k=jnp.zeros((B, L, C, N, Dh), dt),
-        v=jnp.zeros((B, L, C, N, Dh), dt),
+        k=jnp.zeros((B, L, C, G, Dh), dt),
+        v=jnp.zeros((B, L, C, G, Dh), dt),
         pos=jnp.full((B, C), A.EMPTY_POS, jnp.int32),
         idx=jnp.zeros((B,), jnp.int32),
+        rsum=jnp.zeros((B, L, 2, cfg.moe_experts), jnp.float32),
     )
 
 
-class Block(nn.Module):
-    """Pre-LN transformer block: LN → causal MHA (+residual) → LN →
-    GELU MLP (+residual). Matmuls in `dtype` (MXU); LN, softmax and the
-    residual stream in f32."""
+class RMSNorm(nn.Module):
+    """x / rms(x) * g in f32. The parameter is g - 1, zero at the start
+    (g = 1), so that a seeded tree whose vectors are all zero, as the
+    program's own initialiser and the benchmark's both make them, is the
+    published initial state."""
 
-    d_model: int
-    n_heads: int
-    dtype: jnp.dtype = jnp.bfloat16
+    eps: float
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        x = x.astype(jnp.float32)
+        scale = self.param("scale", nn.initializers.zeros_init(), (x.shape[-1],))
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps) * (1.0 + scale)
+
+
+def _norm(cfg: PolicyConfig, name: str) -> nn.Module:
+    """The block's norm of the config's kind, in f32."""
+    if cfg.tf_norm == "rmsnorm":
+        return RMSNorm(cfg.tf_norm_eps, name=name)
+    if cfg.tf_norm == "layernorm":
+        return nn.LayerNorm(epsilon=cfg.tf_norm_eps, dtype=jnp.float32, name=name)
+    raise ValueError(f"unknown tf_norm {cfg.tf_norm!r} (layernorm|rmsnorm)")
+
+
+def _fan_in_first(key, shape, dtype=jnp.float32):
+    """Normal with variance 1 / shape[0], whatever follows the input axis."""
+    return jax.random.normal(key, shape, dtype) / jnp.sqrt(jnp.asarray(shape[0], dtype))
+
+
+class ExpertLayer(nn.Module):
+    """The routed-expert feed-forward layer (ops/moe.py): a router over
+    all cfg.moe_experts, and the SwiGLU experts held here. The expert
+    matrices are kept input axis first, [D, held, I] and [I, held, D]."""
+
+    cfg: PolicyConfig
+    platform: str = ""
+
+    @nn.compact
+    def __call__(self, h: jnp.ndarray, seen=None):
+        """h [B, T, D] f32, normed. `seen` (step mode, T == 1): the
+        sums `moe.standardize` carries over the frames before this one
+        [B, 2, E], and their count [B]. Returns ([B, T, D] f32,
+        pairs per held expert, the sums with this frame's or None)."""
+        cfg = self.cfg
+        D, E, I = h.shape[-1], cfg.moe_experts, cfg.moe_hidden
+        held = cfg.moe_experts_held or E
+        if not 0 <= cfg.moe_first_expert <= E - held or not 0 < cfg.moe_top_k <= E:
+            raise ValueError(
+                f"moe: experts [{cfg.moe_first_expert}, {cfg.moe_first_expert + held}) of {E}, "
+                f"top_k={cfg.moe_top_k}"
+            )
+        dt = jnp.dtype(cfg.dtype)
+        router = self.param("router", _fan_in_first, (D, E))
+        w_gate = self.param("w_gate", _fan_in_first, (D, held, I))
+        w_up = self.param("w_up", _fan_in_first, (D, held, I))
+        w_down = self.param("w_down", _fan_in_first, (I, held, D))
+        scores = moe.router_scores(h, router)  # [B, T, E] f32
+        sums = None
+        if cfg.moe_standardize_router and seen is None:
+            n = jnp.arange(1, h.shape[1] + 1, dtype=jnp.float32)
+            scores, _ = moe.standardize(scores, None, n, axis=1)
+        elif cfg.moe_standardize_router:
+            n = (seen[1] + 1).astype(jnp.float32)[:, None]
+            one, sums = moe.standardize(scores[:, 0], (seen[0][:, 0], seen[0][:, 1]), n)
+            scores, sums = one[:, None], jnp.stack(sums, axis=1)
+        elif seen is not None:
+            sums = seen[0]
+        impl = cfg.moe_impl
+        if impl == "auto":
+            impl = "megablox" if (self.platform or jax.default_backend()) == "tpu" else "ragged_dot"
+        x = h.reshape(-1, D)
+        by_expert = lambda w: jnp.swapaxes(w.astype(dt), 0, 1)
+        y, sizes = moe.expert_layer(
+            x.astype(dt), moe.route(scores.reshape(-1, E), cfg.moe_top_k),
+            by_expert(w_gate), by_expert(w_up), by_expert(w_down), cfg.moe_first_expert, impl,
+        )
+        return y.reshape(h.shape), sizes, sums
+
+
+class Block(nn.Module):
+    """Pre-norm transformer block: norm → causal attention (+residual) →
+    norm → feed-forward (+residual). The sizes are the config's: grouped
+    key/value heads, a head width of its own, a full or a sliding layer
+    with that kind's rotary table, LayerNorm or RMSNorm, a dense GELU MLP
+    of 4x or a routed-expert layer. Matmuls in cfg.dtype (MXU); norms,
+    softmax, router and the residual stream in f32."""
+
+    cfg: PolicyConfig
+    kind: str = "full"
     sp_mesh: Optional[Mesh] = None
-    sp_axis: str = ""
-    sp_mode: str = "ring"
-    kv_block: int = 0
+    platform: str = ""  # of the devices the surrounding program runs on, where known
 
     @nn.compact
     def __call__(
@@ -107,52 +210,81 @@ class Block(nn.Module):
         cache: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
     ):
         """cache=None: causal self-attention over the T axis (unroll
-        mode; ring-sharded when sp_mesh/sp_axis are set). Otherwise
-        cache=(k_cache [B,C,N,Dh], v_cache, cache_pos [B,C] ALREADY
-        including this token's position, write_onehot [B,C]): T==1
-        stepping — the block writes its fresh K/V into the cache at
-        write_onehot and attends over the merged cache. Returns
-        (x_out, None) in unroll mode, (x_out, (k_cache', v_cache')) in
-        step mode."""
-        D, N = self.d_model, self.n_heads
-        Dh = D // N
-        dt = self.dtype
+        mode; ring-sharded when sp_mesh and cfg.tf_sp_axis are set).
+        Otherwise cache=(k_cache [B,C,G,Dh], v_cache, cache_pos [B,C]
+        ALREADY including this token's position, write_onehot [B,C],
+        the router's running sums [B,2,E]):
+        T==1 stepping — the block writes its fresh K/V into the cache at
+        write_onehot and attends over the merged cache; a sliding layer
+        masks by position. Returns (x_out, new cache or None, pairs per
+        held expert or None)."""
+        cfg = self.cfg
+        D = cfg.lstm_hidden
+        N, G, Dh = head_shape(cfg)
+        dt = jnp.dtype(cfg.dtype)
+        sliding = self.kind == "sliding"
+        window = cfg.tf_window if sliding else 0
+        dense = lambda n, name: nn.Dense(n, dtype=dt, use_bias=cfg.tf_bias, name=name)
+        table = A.rope_table(Dh, cfg.tf_rope_theta) if sliding or not cfg.tf_yarn_factor else (
+            A.rope_table(Dh, cfg.tf_rope_theta, cfg.tf_yarn_factor, cfg.tf_yarn_original_context,
+                         cfg.tf_yarn_beta_fast, cfg.tf_yarn_beta_slow))
 
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x)
-        qkv = nn.Dense(3 * D, dtype=dt, name="qkv")(h.astype(dt))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        # RoPE at this token's absolute position; cached K were rotated
-        # at write time, so angles are consistent across modes.
-        q = A.rope(q.reshape(q.shape[:-1] + (N, Dh)), positions)
-        k = A.rope(k.reshape(k.shape[:-1] + (N, Dh)), positions)
-        v = v.reshape(v.shape[:-1] + (N, Dh))
+        # Named scopes: the layer an operation belongs to, in its
+        # `op_name`, the norm that feeds a part inside that part's scope.
+        with jax.named_scope("attn_window" if sliding else "attn_full"):
+            h = _norm(cfg, "ln1")(x)
+            qkv = dense((N + 2 * G) * Dh, "qkv")(h.astype(dt))
+            q, k, v = jnp.split(qkv, [N * Dh, (N + G) * Dh], axis=-1)
+            # RoPE at this token's absolute position; cached K were rotated
+            # at write time, so angles are consistent across modes.
+            q = A.rope(q.reshape(q.shape[:-1] + (N, Dh)), positions, table=table)
+            k = A.rope(k.reshape(k.shape[:-1] + (G, Dh)), positions, table=table)
+            v = v.reshape(v.shape[:-1] + (G, Dh))
 
-        new_cache = None
-        if cache is None:
-            attn = RA.attend(
-                q, k, v, positions, positions,
-                mesh=self.sp_mesh, sp_axis=self.sp_axis, sp_mode=self.sp_mode,
-                kv_block=self.kv_block,
-            )
-        else:
-            k_cache, v_cache, cache_pos, onehot = cache
-            # Write in the cache's own dtype (compute dtype — init_cache):
-            # jnp.where avoids the f32 promotion a mask-blend would cause.
-            sel = onehot[:, :, None, None]  # [B, C, 1, 1] bool
-            k_cache = jnp.where(sel, k.astype(k_cache.dtype), k_cache)
-            v_cache = jnp.where(sel, v.astype(v_cache.dtype), v_cache)
-            attn = RA.attend(q, k_cache, v_cache, positions, cache_pos)
-            new_cache = (k_cache, v_cache)
-        out = nn.Dense(D, dtype=dt, name="attn_out")(
-            attn.astype(dt).reshape(attn.shape[:-2] + (D,))
-        )
-        x = x + out.astype(jnp.float32)
+            new_cache = None
+            if cache is None:
+                attn = RA.attend(
+                    q, k, v, positions, positions,
+                    mesh=self.sp_mesh, sp_axis=cfg.tf_sp_axis, sp_mode=cfg.tf_sp_mode,
+                    kv_block=cfg.tf_attn_block, window=window,
+                )
+            else:
+                k_cache, v_cache, cache_pos, onehot, _ = cache
+                # Write in the cache's own dtype (compute dtype — init_cache):
+                # jnp.where avoids the f32 promotion a mask-blend would cause.
+                sel = onehot[:, :, None, None]  # [B, C, 1, 1] bool
+                k_cache = jnp.where(sel, k.astype(k_cache.dtype), k_cache)
+                v_cache = jnp.where(sel, v.astype(v_cache.dtype), v_cache)
+                attn = RA.attend(q, k_cache, v_cache, positions, cache_pos, window=window)
+                new_cache = (k_cache, v_cache)
+            out = dense(D, "attn_out")(attn.astype(dt).reshape(attn.shape[:-2] + (N * Dh,)))
+            x = x + out.astype(jnp.float32)
 
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x)
-        h = nn.Dense(4 * D, dtype=dt, name="mlp_up")(h.astype(dt))
-        h = nn.gelu(h)
-        h = nn.Dense(D, dtype=dt, name="mlp_down")(h)
-        return x + h.astype(jnp.float32), new_cache
+        if cfg.moe_experts:
+            with jax.named_scope("moe"):
+                seen = None if cache is None else (cache[4], positions[:, 0])
+                y, sizes, rsum = ExpertLayer(cfg, self.platform, name="moe")(_norm(cfg, "ln2")(x), seen)
+            return x + y, new_cache and new_cache + (rsum,), sizes
+        with jax.named_scope("mlp"):
+            h = _norm(cfg, "ln2")(x)
+            h = dense(4 * D, "mlp_up")(h.astype(dt))
+            h = nn.gelu(h)
+            h = dense(D, "mlp_down")(h)
+            return x + h.astype(jnp.float32), new_cache and new_cache + (cache[4],), None
+
+
+def _moe_stats(sizes) -> Optional[dict]:
+    """The step's routing counters from each layer's pairs per held
+    expert: the most loaded held expert over the mean, of the worst
+    layer, and the pairs computed here over all layers."""
+    if sizes[0] is None:
+        return None
+    per_layer = jnp.stack(sizes).astype(jnp.float32)  # [L, held]
+    mean = jnp.maximum(jnp.mean(per_layer, axis=-1), 1e-9)
+    return {
+        "moe_load_max_over_mean": jnp.max(jnp.max(per_layer, axis=-1) / mean),
+        "moe_local_pairs": jnp.sum(per_layer),
+    }
 
 
 class TransformerCore(nn.Module):
@@ -160,6 +292,7 @@ class TransformerCore(nn.Module):
 
     Unroll: x [B, T, D] → [B, T, D], carry passed through untouched
     (chunk-local context). Step: x [B, D] → [B, D], carry is a KVCache.
+    Third result: the routing counters of a routed-expert core, or None.
     """
 
     cfg: PolicyConfig
@@ -168,15 +301,10 @@ class TransformerCore(nn.Module):
     @nn.compact
     def __call__(self, carry, x: jnp.ndarray, unroll: bool = False):
         cfg = self.cfg
-        D, N, L = cfg.lstm_hidden, cfg.tf_heads, cfg.tf_layers
-        if D % N:
-            raise ValueError(f"lstm_hidden={D} not divisible by tf_heads={N}")
-        if (D // N) % 2:
-            raise ValueError(
-                f"head dim {D // N} (lstm_hidden={D} / tf_heads={N}) must be "
-                f"even — RoPE rotates feature pairs"
-            )
-        dt = jnp.dtype(cfg.dtype)
+        kinds = layer_kinds(cfg)
+        head_shape(cfg)  # refuses a bad shape before any block is traced
+        platform = self.sp_mesh.devices.flat[0].platform if self.sp_mesh is not None else ""
+        final = _norm(cfg, "ln_f") if cfg.tf_final_norm else (lambda h: h)
 
         if unroll:
             B, T = x.shape[0], x.shape[1]
@@ -186,12 +314,11 @@ class TransformerCore(nn.Module):
             # backward instead of storing them (jax.checkpoint) —
             # O(T·D) residuals per block instead of every intermediate.
             block_cls = nn.remat(Block) if cfg.tf_remat else Block
-            for i in range(L):
-                h, _ = block_cls(
-                    D, N, dt, self.sp_mesh, cfg.tf_sp_axis, cfg.tf_sp_mode,
-                    cfg.tf_attn_block, name=f"block{i}"
-                )(h, positions)
-            return carry, h
+            sizes = []
+            for i, kind in enumerate(kinds):
+                h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, name=f"block{i}")(h, positions)
+                sizes.append(n)
+            return carry, final(h), _moe_stats(sizes)
 
         assert isinstance(carry, KVCache), "transformer step mode needs a KVCache carry"
         C = carry.pos.shape[1]
@@ -206,17 +333,20 @@ class TransformerCore(nn.Module):
         new_pos = jnp.where(onehot > 0, positions, carry.pos).astype(jnp.int32)
 
         h = x.astype(jnp.float32)[:, None, :]  # [B, 1, D]
-        ks, vs = [], []
-        for i in range(L):
-            h, (k_i, v_i) = Block(D, N, dt, name=f"block{i}")(
-                h, positions, cache=(carry.k[:, i], carry.v[:, i], new_pos, onehot)
+        ks, vs, rs = [], [], []
+        for i, kind in enumerate(kinds):
+            h, (k_i, v_i, r_i), _ = Block(cfg, kind, platform=platform, name=f"block{i}")(
+                h, positions,
+                cache=(carry.k[:, i], carry.v[:, i], new_pos, onehot, carry.rsum[:, i]),
             )
             ks.append(k_i)
             vs.append(v_i)
+            rs.append(r_i)
         new_carry = KVCache(
-            k=jnp.stack(ks, axis=1), v=jnp.stack(vs, axis=1), pos=new_pos, idx=carry.idx + 1
+            k=jnp.stack(ks, axis=1), v=jnp.stack(vs, axis=1), pos=new_pos, idx=carry.idx + 1,
+            rsum=jnp.stack(rs, axis=1),
         )
-        return new_carry, h[:, 0, :]
+        return new_carry, final(h)[:, 0, :], None
 
 
 class TransformerPolicyCore(nn.Module):
@@ -230,6 +360,9 @@ class TransformerPolicyCore(nn.Module):
     def __call__(self, carry, obs, unroll: bool = False):
         from dotaclient_tpu.models.policy import action_heads, obs_trunk
 
-        trunk, unit_emb = obs_trunk(self.cfg, obs)
-        carry, out = TransformerCore(self.cfg, self.sp_mesh, name="tf")(carry, trunk, unroll)
-        return carry, action_heads(self.cfg, out, unit_emb, obs)
+        # Named scopes, as models/policy.py PolicyCore has them.
+        with jax.named_scope("trunk"):
+            trunk, unit_emb = obs_trunk(self.cfg, obs)
+        carry, out, stats = TransformerCore(self.cfg, self.sp_mesh, name="tf")(carry, trunk, unroll)
+        with jax.named_scope("heads"):
+            return carry, action_heads(self.cfg, out, unit_emb, obs)._replace(stats=stats)

@@ -32,10 +32,12 @@ Design, TPU-first:
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Sentinel position for "no key here" (empty KV-cache slot). Any real
 # query position is < this, so the causal test k_pos <= q_pos masks it.
@@ -48,21 +50,60 @@ EMPTY_POS = jnp.iinfo(jnp.int32).max
 _NEG = -1e30
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0) -> jnp.ndarray:
+def rope_table(
+    head_dim: int,
+    theta: float = 10000.0,
+    yarn_factor: float = 0.0,
+    yarn_original_context: int = 0,
+    yarn_beta_fast: float = 32.0,
+    yarn_beta_slow: float = 1.0,
+) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies [head_dim // 2] float32, factor on cos and sin).
+
+    The default table is theta ** (-2i / head_dim). With `yarn_factor`
+    (YaRN, Peng et al. 2023, as the published configs state it): the
+    default frequencies and those divided by the factor, blended per
+    frequency by a linear ramp over the correction range, which is where
+    a rotation makes `beta_fast` and `beta_slow` turns within the
+    original context (floor and ceiling taken, clipped to the table); cos
+    and sin are multiplied by 0.1 ln(factor) + 1. Worked out in float64
+    at trace time: a constant of the program.
+    """
+    half = head_dim // 2
+    inv = theta ** (-np.arange(half, dtype=np.float64) / half)
+    if not yarn_factor:
+        return inv.astype(np.float32), 1.0
+
+    def turns_at(rotations):  # the (fractional) index whose frequency makes that many turns
+        return (head_dim * math.log(yarn_original_context / (rotations * 2 * math.pi))) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(turns_at(yarn_beta_fast)), 0)
+    high = min(math.ceil(turns_at(yarn_beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv = inv / yarn_factor * ramp + inv * (1.0 - ramp)
+    return inv.astype(np.float32), 0.1 * math.log(yarn_factor) + 1.0
+
+
+def rope(
+    x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0, table=None
+) -> jnp.ndarray:
     """Rotary position embedding.
 
     x [.., T, N, Dh] (Dh even), positions [.., T] int32 absolute
-    positions. Angle math in f32; result cast back to x.dtype.
+    positions. `table`: a `rope_table` in place of `base`'s default one.
+    Angle math in f32; result cast back to x.dtype.
     """
     half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)  # [half]
+    freqs, scale = table if table is not None else rope_table(x.shape[-1], base)
     # Sentinel positions would produce garbage angles; they belong to
     # empty cache slots whose scores are masked anyway, so zero them to
     # keep the trig finite.
     pos = jnp.where(positions == EMPTY_POS, 0, positions).astype(jnp.float32)
     ang = pos[..., None] * freqs  # [.., T, half]
-    cos = jnp.cos(ang)[..., None, :]  # [.., T, 1, half] — broadcast over heads
-    sin = jnp.sin(ang)[..., None, :]
+    cos = (jnp.cos(ang) * scale)[..., None, :]  # [.., T, 1, half] — broadcast over heads
+    sin = (jnp.sin(ang) * scale)[..., None, :]
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
@@ -78,21 +119,34 @@ def accumulate_block(
     m: jnp.ndarray,
     l: jnp.ndarray,
     acc: jnp.ndarray,
+    window: int = 0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One streaming-softmax step over a K/V block.
 
-    q [.., Tq, N, Dh]; k, v [.., Tk, N, Dh]; q_pos [.., Tq]; k_pos [.., Tk].
+    q [.., Tq, N, Dh]; k, v [.., Tk, G, Dh], G dividing N: query head n
+    reads key/value head n // (N // G), and K/V are read once, not
+    repeated; q_pos [.., Tq]; k_pos [.., Tk]. A key counts where
+    k_pos <= q_pos and, with `window`, q_pos - k_pos < window.
     Carries (all f32): m [.., N, Tq] running max, l [.., N, Tq] running
     normalizer, acc [.., N, Tq, Dh] unnormalized output. Returns updated
     carries; `finalize_attention` turns them into the attention output.
     """
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
+    Tq, N, Dh = q.shape[-3:]
+    Tk, G = k.shape[-3:-1]
+    lead = q.shape[:-3]
+    scale = 1.0 / jnp.sqrt(jnp.asarray(Dh, jnp.float32))
     # [.., N, Tq, Tk] — matmul in the input dtype (MXU), scores in f32.
-    s = jnp.einsum("...qnd,...knd->...nqk", q, k, preferred_element_type=jnp.float32)
+    if G == N:
+        s = jnp.einsum("...qnd,...knd->...nqk", q, k, preferred_element_type=jnp.float32)
+    else:
+        qg = q.reshape(lead + (Tq, G, N // G, Dh))
+        s = jnp.einsum("...qgrd,...kgd->...grqk", qg, k, preferred_element_type=jnp.float32)
+        s = s.reshape(lead + (N, Tq, Tk))
     s = s.astype(jnp.float32) * scale
-    valid = (k_pos[..., None, None, :] <= q_pos[..., None, :, None]) & (
-        k_pos[..., None, None, :] != EMPTY_POS
-    )
+    kp, qp = k_pos[..., None, None, :], q_pos[..., None, :, None]
+    valid = (kp <= qp) & (kp != EMPTY_POS)
+    if window:
+        valid = valid & (qp - kp < window)
     s = jnp.where(valid, s, _NEG)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     # Explicit where: if an entire row is masked, m_new == _NEG-ish and
@@ -100,10 +154,13 @@ def accumulate_block(
     p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
     corr = jnp.exp(m - m_new)
     l_new = l * corr + jnp.sum(p, axis=-1)
-    acc_new = acc * corr[..., None] + jnp.einsum(
-        "...nqk,...knd->...nqd", p, v.astype(jnp.float32)
-    )
-    return m_new, l_new, acc_new
+    if G == N:
+        pv = jnp.einsum("...nqk,...knd->...nqd", p, v.astype(jnp.float32))
+    else:
+        pg = p.reshape(lead + (G, N // G, Tq, Tk))
+        pv = jnp.einsum("...grqk,...kgd->...grqd", pg, v.astype(jnp.float32))
+        pv = pv.reshape(lead + (N, Tq, Dh))
+    return m_new, l_new, acc * corr[..., None] + pv
 
 
 def init_carry(q: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -131,17 +188,30 @@ def causal_attention(
     v: jnp.ndarray,
     q_pos: jnp.ndarray,
     k_pos: jnp.ndarray,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Position-masked causal attention, single block.
 
-    q [.., Tq, N, Dh], k/v [.., Tk, N, Dh], q_pos [.., Tq], k_pos [.., Tk]
-    → [.., Tq, N, Dh] in q.dtype. This is both the reference the ring
-    path is tested against and the shipping implementation whenever the
-    whole time axis fits one device.
+    q [.., Tq, N, Dh], k/v [.., Tk, G, Dh], q_pos [.., Tq], k_pos [.., Tk]
+    → [.., Tq, N, Dh] in q.dtype; `window` as in `accumulate_block`.
+    This is both the reference the ring and blocked paths are tested
+    against and the shipping implementation whenever the whole time axis
+    fits one device's memory densely.
     """
     m, l, acc = init_carry(q)
-    m, l, acc = accumulate_block(q, k, v, q_pos, k_pos, m, l, acc)
+    m, l, acc = accumulate_block(q, k, v, q_pos, k_pos, m, l, acc, window)
     return finalize_attention(m, l, acc, dtype=q.dtype)
+
+
+def block_key_range(i: int, block: int, T: int, window: int = 0) -> Tuple[int, int]:
+    """The keys [lo, hi) that query block `i` (queries i*block up to
+    (i+1)*block, cut at T) meets in a key block that holds an unmasked
+    pair, where query t sits at position t: key blocks above the diagonal,
+    and with `window` those wholly more than window - 1 behind the
+    block's first query, hold none."""
+    hi = min((i + 1) * block, T)
+    lo = max(i * block - (window - 1), 0) // block * block if window else 0
+    return lo, hi
 
 
 def blockwise_causal_attention(
@@ -151,39 +221,33 @@ def blockwise_causal_attention(
     q_pos: jnp.ndarray,
     k_pos: jnp.ndarray,
     kv_block: int,
+    window: int = 0,
 ) -> jnp.ndarray:
-    """Flash-formulation local attention: `lax.scan` of the streaming
-    primitive over key blocks.
+    """Flash-formulation local attention over query and key blocks of
+    `kv_block`, for a chunk whose frame t is query and key t (the
+    learner's unroll; positions still do the masking).
 
-    Same function as `causal_attention`, but peak intermediate memory is
-    [.., N, Tq, kv_block] instead of [.., N, Tq, Tk] — the lever for
-    long chunks on ONE device (the sequence-parallel paths in
-    ops/ring_attention.py get the same blockwise behavior from the ring
-    structure itself). A ragged final block is padded with EMPTY_POS
-    keys, which the position masking erases — no special-casing. The
-    compiler-friendly formulation (static shapes, scan) is deliberate:
-    XLA schedules it well on TPU; a hand-written Pallas kernel is the
+    Same function as `causal_attention`. A key block is computed for a
+    query block only where it holds an unmasked pair (`block_key_range`):
+    half of the blocks of a causal layer, and about
+    (window + kv_block) / T of them in a windowed one. Each query block
+    is one dense masked softmax over its contiguous key range under
+    `jax.checkpoint`: what is kept for the backward pass is q, k and v,
+    and the [N, kv_block, keys] scores exist for one query block at a
+    time, forward and backward. The shapes are static and a ragged last
+    block is a shorter slice. The compiler-friendly formulation (static
+    slices, no kernel) is deliberate: a hand-written Pallas kernel is the
     step to take only if a profile shows the fusion falling short
     (ops/lstm.py precedent: measure on silicon first).
     """
-    B_lead = k.shape[:-3]
-    Tk, N, Dh = k.shape[-3:]
-    nb = -(-Tk // kv_block)
-    pad = nb * kv_block - Tk
-    if pad:
-        pad_cfg = [(0, 0)] * (len(B_lead)) + [(0, pad), (0, 0), (0, 0)]
-        k = jnp.pad(k, pad_cfg)
-        v = jnp.pad(v, pad_cfg)
-        k_pos = jnp.pad(k_pos, [(0, 0)] * len(B_lead) + [(0, pad)], constant_values=EMPTY_POS)
-    # time-major blocks for the scan: [nb, .., kv_block, N, Dh]
-    kb = jnp.moveaxis(k.reshape(B_lead + (nb, kv_block, N, Dh)), len(B_lead), 0)
-    vb = jnp.moveaxis(v.reshape(B_lead + (nb, kv_block, N, Dh)), len(B_lead), 0)
-    pb = jnp.moveaxis(k_pos.reshape(B_lead + (nb, kv_block)), len(B_lead), 0)
-
-    def step(carry, xs):
-        m, l, acc = carry
-        k_i, v_i, p_i = xs
-        return accumulate_block(q, k_i, v_i, q_pos, p_i, m, l, acc), None
-
-    carry, _ = jax.lax.scan(step, init_carry(q), (kb, vb, pb))
-    return finalize_attention(*carry, dtype=q.dtype)
+    T = q.shape[-3]
+    if k.shape[-3] != T:
+        raise ValueError(f"blocked attention is over one chunk: {T} queries, {k.shape[-3]} keys")
+    one_block = jax.checkpoint(causal_attention, static_argnums=(5,))
+    out = []
+    for i in range(-(-T // kv_block)):
+        lo, hi = block_key_range(i, kv_block, T, window)
+        rows, keys = slice(i * kv_block, hi), slice(lo, hi)
+        out.append(one_block(q[..., rows, :, :], k[..., keys, :, :], v[..., keys, :, :],
+                             q_pos[..., rows], k_pos[..., keys], window))
+    return jnp.concatenate(out, axis=-3)

@@ -20,15 +20,26 @@ from dotaclient_tpu.config import LearnerConfig, PolicyConfig
 from dotaclient_tpu.env import featurizer as F
 
 
-def policy_forward_flops_per_frame(cfg: PolicyConfig) -> float:
+def attended_pairs(frames: int, window: int = 0) -> float:
+    """(query, key) pairs a chunk of `frames` frames keeps under the causal
+    mask, and within `window` where a sliding layer has one."""
+    if not window or window >= frames:
+        return frames * (frames + 1) / 2.0
+    return window * (window + 1) / 2.0 + (frames - window) * float(window)
+
+
+def policy_forward_flops_per_frame(cfg: PolicyConfig, chunk_frames: int = 0) -> float:
     """Matmul FLOPs for ONE batch element, ONE time frame, forward only.
 
     Mirrors models/policy.py layer-for-layer (trunk + temporal core +
     heads). The LSTM recurrence's per-frame cost is the [1,H]x[H,4H]
     hidden projection; the hoisted x-projection is counted in the cell's
-    input matmul. The transformer family instead pays QKV/out/MLP
-    projections per frame plus attention scores against its (chunk-local)
-    context.
+    input matmul. The transformer family instead pays its projections
+    and feed-forward per frame plus attention over its (chunk-local)
+    context: the least work, which is the pairs the causal mask and a
+    sliding layer's window keep, averaged over a chunk of `chunk_frames`
+    (0 = tf_context), and for a routed-expert layer the router and the
+    pairs an even routing sends to the experts held here.
     """
     U, UF = F.MAX_UNITS, F.UNIT_FEATURES
     D, M, H = cfg.unit_embed_dim, cfg.mlp_hidden, cfg.lstm_hidden
@@ -44,11 +55,20 @@ def policy_forward_flops_per_frame(cfg: PolicyConfig) -> float:
 
     # temporal core
     if cfg.arch == "transformer":
-        Dh = H  # qkv/out are HxH each; MLP is Hx4H up + 4HxH down
-        ctx = cfg.tf_context
-        per_layer = 2.0 * (4 * H * Dh) + 2.0 * (2 * H * 4 * H)
-        per_layer += 2.0 * 2 * ctx * H  # scores QK^T + attn·V vs the chunk context
-        fl += cfg.tf_layers * per_layer
+        from dotaclient_tpu.models.transformer_policy import head_shape, layer_kinds
+
+        N, G, Dh = head_shape(cfg)
+        frames = chunk_frames or cfg.tf_context
+        proj = 2.0 * H * (N + 2 * G) * Dh + 2.0 * N * Dh * H  # qkv, out
+        if cfg.moe_experts:
+            held = cfg.moe_experts_held or cfg.moe_experts
+            pairs_here = cfg.moe_top_k * held / cfg.moe_experts
+            ff = 2.0 * H * cfg.moe_experts + pairs_here * 3 * 2.0 * H * cfg.moe_hidden
+        else:
+            ff = 2.0 * (2 * H * 4 * H)  # MLP: Hx4H up + 4HxH down
+        for kind in layer_kinds(cfg):
+            pairs = attended_pairs(frames, cfg.tf_window if kind == "sliding" else 0)
+            fl += proj + ff + pairs * 4.0 * N * Dh / frames  # QK^T + attn·V of the kept pairs
     else:
         fl += 2.0 * H * 4 * H  # x-projection (input is the trunk's H)
         fl += 2.0 * H * 4 * H  # recurrence hidden projection
@@ -83,7 +103,7 @@ def train_step_flops(cfg: LearnerConfig) -> float:
     the (3R+1) trip-count structure is compiler-verified after all.
     """
     frames = cfg.batch_size * (cfg.seq_len + 1)
-    fwd = frames * policy_forward_flops_per_frame(cfg.policy)
+    fwd = frames * policy_forward_flops_per_frame(cfg.policy, cfg.seq_len + 1)
     R, M = cfg.ppo.epochs, cfg.ppo.minibatches
     if R * M == 1:
         return 3.0 * fwd

@@ -151,7 +151,7 @@ def ppo_loss(
         cfg.gamma,
         cfg.gae_lambda,
     )
-    return _surrogate(
+    loss, metrics = _surrogate(
         out,
         batch.actions,
         batch.behavior_logp,
@@ -164,6 +164,8 @@ def ppo_loss(
         aux_coef,
         staleness=batch.behavior_staleness,
     )
+    metrics.update(out.stats or {})  # the forward pass's own counters
+    return loss, metrics
 
 
 class ReuseBatch(NamedTuple):

@@ -182,6 +182,7 @@ def attend(
     sp_axis: str = "",
     sp_mode: str = "ring",
     kv_block: int = 0,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Dispatch: sequence-parallel attention when a mesh with an `sp`
     axis is supplied (learner long-context mode) — `sp_mode` picks the
@@ -189,13 +190,16 @@ def attend(
     all-to-all head re-sharding). Otherwise local attention: blockwise
     flash formulation when `kv_block` is set and the key axis exceeds
     it (long single-device chunks), dense single-block else (actor
-    stepping, short chunks, tests)."""
+    stepping, short chunks, tests). `window`: a sliding layer's (local
+    paths only)."""
     if mesh is not None and sp_axis and sp_axis in mesh.axis_names:
+        if window:
+            raise ValueError("a sliding layer's window is not carried over the sp axis yet")
         if sp_mode == "ulysses":
             return ulysses_causal_attention(q, k, v, q_pos, k_pos, mesh, sp_axis, kv_block)
         if sp_mode != "ring":
             raise ValueError(f"unknown sp_mode {sp_mode!r} (ring|ulysses)")
         return ring_causal_attention(q, k, v, q_pos, k_pos, mesh, sp_axis)
     if kv_block and k.shape[-3] > kv_block:
-        return A.blockwise_causal_attention(q, k, v, q_pos, k_pos, kv_block)
-    return A.causal_attention(q, k, v, q_pos, k_pos)
+        return A.blockwise_causal_attention(q, k, v, q_pos, k_pos, kv_block, window)
+    return A.causal_attention(q, k, v, q_pos, k_pos, window)

@@ -296,3 +296,135 @@ def test_ulysses_blockwise_matches_dense():
     blk = RA.ulysses_causal_attention(q, k, v, pos, pos, mesh, kv_block=8)
     dense = RA.ulysses_causal_attention(q, k, v, pos, pos, mesh)
     np.testing.assert_allclose(blk, dense, rtol=1e-5, atol=1e-6)
+
+
+def _dense_masked(q, k, v, window=0):
+    """The written equations under a dense mask, float64: frame t at
+    position t, key j kept for query i where 0 <= i - j (< window), query
+    head n reading key/value head n // (N // G)."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    B, T, N, Dh = q.shape
+    G = k.shape[2]
+    d = np.arange(T)[:, None] - np.arange(T)[None, :]
+    keep = (d >= 0) & ((d < window) if window else True)
+    out = np.zeros_like(q)
+    for b in range(B):
+        for n in range(N):
+            s = np.where(keep, q[b, :, n] @ k[b, :, n // (N // G)].T / np.sqrt(Dh), -np.inf)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[b, :, n] = (p / p.sum(-1, keepdims=True)) @ v[b, :, n // (N // G)]
+    return out
+
+
+class TestBlockedWindowedGroupedAttention:
+    """Both layer kinds of the blocked attention against the dense mask:
+    grouped heads, a ragged last block, and the blocks it never computes."""
+
+    @pytest.mark.parametrize("window", [0, 5, 8, 11], ids=lambda w: f"window{w}")
+    @pytest.mark.parametrize("T,block", [(32, 8), (21, 8), (9, 4)])
+    def test_matches_the_dense_mask(self, T, block, window):
+        B, N, G, Dh = 2, 4, 2, 8
+        q = jnp.asarray(_rand((B, T, N, Dh), 80 + T))
+        k, v = (jnp.asarray(_rand((B, T, G, Dh), s + T)) for s in (81, 82))
+        pos = _positions(B, T)
+        want = _dense_masked(q, k, v, window)
+        np.testing.assert_allclose(A.causal_attention(q, k, v, pos, pos, window), want, rtol=1e-5, atol=1e-6)
+        got = A.blockwise_causal_attention(q, k, v, pos, pos, block, window)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        via = RA.attend(q, k, v, pos, pos, kv_block=block, window=window)
+        np.testing.assert_allclose(via, want, rtol=1e-5, atol=1e-6)
+
+    def test_grouped_heads_read_their_group(self):
+        """Grouped K/V give what the same K/V repeated per query head give."""
+        B, T, N, G, Dh = 1, 10, 6, 2, 4
+        q = jnp.asarray(_rand((B, T, N, Dh), 90))
+        k, v = (jnp.asarray(_rand((B, T, G, Dh), s)) for s in (91, 92))
+        pos = _positions(B, T)
+        rep = lambda x: jnp.repeat(x, N // G, axis=2)
+        np.testing.assert_allclose(A.causal_attention(q, k, v, pos, pos),
+                                   A.causal_attention(q, rep(k), rep(v), pos, pos), rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("T,block,window", [(32, 8, 0), (32, 8, 5), (21, 8, 9), (4096, 256, 1024)])
+    def test_a_block_is_skipped_only_where_it_holds_no_pair(self, T, block, window):
+        nb = -(-T // block)
+        d = np.arange(T)[:, None] - np.arange(T)[None, :]
+        keep = (d >= 0) & ((d < window) if window else True)
+        computed = 0
+        for i in range(nb):
+            lo, hi = A.block_key_range(i, block, T, window)
+            rows = slice(i * block, min((i + 1) * block, T))
+            assert lo % block == 0 and hi == rows.stop
+            assert not keep[rows, :lo].any() and not keep[rows, hi:].any()  # what is skipped holds nothing
+            for j in range(lo // block, -(-hi // block)):  # what is computed holds a pair
+                assert keep[rows, j * block:(j + 1) * block].any()
+            computed += hi - lo
+        if (T, window) == (4096, 1024):  # 1,280 keys a query block once the window is full
+            assert computed == sum(min((i + 1) * 256, 1280) for i in range(16))
+
+    def test_skipped_blocks_change_nothing(self):
+        """Keys outside a query block's range may hold anything."""
+        B, T, N, G, Dh, block, window = 1, 24, 2, 1, 4, 4, 6
+        q = jnp.asarray(_rand((B, T, N, Dh), 93))
+        k, v = (_rand((B, T, G, Dh), s) for s in (94, 95))
+        pos = _positions(B, T)
+        base = A.blockwise_causal_attention(q, jnp.asarray(k), jnp.asarray(v), pos, pos, block, window)
+        last = slice(20, 24)  # the last query block meets keys [12, 24)
+        assert A.block_key_range(5, block, T, window) == (12, 24)
+        k2, v2 = k.copy(), v.copy()
+        k2[:, :12], v2[:, :12] = 1e6, np.nan
+        pert = A.blockwise_causal_attention(q, jnp.asarray(k2), jnp.asarray(v2), pos, pos, block, window)
+        np.testing.assert_array_equal(np.asarray(pert)[:, last], np.asarray(base)[:, last])
+
+    @pytest.mark.slow  # the blocked VJP compiles a program per query block
+    def test_gradients_match_the_dense_mask(self):
+        B, T, N, G, Dh = 1, 20, 4, 2, 4
+        q = jnp.asarray(_rand((B, T, N, Dh), 96))
+        k, v = (jnp.asarray(_rand((B, T, G, Dh), s)) for s in (97, 98))
+        pos = _positions(B, T)
+        cot = jnp.asarray(_rand((B, T, N, Dh), 99))
+        for window in (0, 7):
+            g_blk = jax.grad(lambda q, k, v: jnp.sum(
+                A.blockwise_causal_attention(q, k, v, pos, pos, 8, window) * cot), argnums=(0, 1, 2))(q, k, v)
+            g_dense = jax.grad(lambda q, k, v: jnp.sum(
+                A.causal_attention(q, k, v, pos, pos, window) * cot), argnums=(0, 1, 2))(q, k, v)
+            for gb, gd in zip(g_blk, g_dense):
+                np.testing.assert_allclose(gb, gd, rtol=1e-4, atol=1e-5)
+
+    def test_a_window_is_not_carried_over_the_sp_axis(self):
+        mesh = mesh_lib.make_mesh("sp=8")
+        q = jnp.zeros((1, 16, 2, 4))
+        pos = _positions(1, 16)
+        with pytest.raises(ValueError, match="window"):
+            RA.attend(q, q, q, pos, pos, mesh=mesh, sp_axis="sp", window=4)
+
+
+class TestRopeTable:
+    def test_the_default_table_is_the_old_one(self):
+        want = 10000.0 ** (-jnp.arange(16, dtype=jnp.float32) / 16)
+        inv, scale = A.rope_table(32)
+        np.testing.assert_array_equal(inv, np.asarray(want))
+        assert scale == 1.0
+        x = jnp.asarray(_rand((1, 6, 2, 32), 70))
+        pos = _positions(1, 6)
+        np.testing.assert_array_equal(A.rope(x, pos), A.rope(x, pos, table=(inv, 1.0)))
+
+    def test_yarn_against_the_formula(self):
+        """Head width 128, theta 500,000, factor 16, original context
+        8,192, beta 32 and 1: the published full-attention section."""
+        dim, theta, factor, orig = 128, 500000.0, 16.0, 8192
+        inv, scale = A.rope_table(dim, theta, factor, orig, 32.0, 1.0)
+        assert scale == pytest.approx(1.2772588722239782, rel=1e-12)
+        base = theta ** (-np.arange(0, dim, 2) / dim)
+        # the index at which a rotation makes r turns within the original context
+        at = lambda r: dim * np.log(orig / (r * 2 * np.pi)) / (2 * np.log(theta))
+        low, high = np.floor(at(32.0)), np.ceil(at(1.0))
+        assert (low, high) == (18, 35)
+        ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+        np.testing.assert_allclose(inv, base / factor * ramp + base * (1 - ramp), rtol=1e-6)
+        np.testing.assert_allclose(inv[:19], base[:19], rtol=1e-6)  # fast rotations keep their frequency
+        np.testing.assert_allclose(inv[35:], base[35:] / 16, rtol=1e-6)  # slow ones are interpolated
+        assert (np.diff(inv) < 0).all()
+        # rope with the table: cos and sin carry the factor, so a vector's norm does
+        x = jnp.asarray(_rand((1, 5, 1, dim), 71))
+        out = A.rope(x, _positions(1, 5), table=(inv, scale))
+        np.testing.assert_allclose(np.linalg.norm(out, axis=-1), scale * np.linalg.norm(x, axis=-1), rtol=1e-5)

@@ -159,3 +159,32 @@ def test_transformer_train_step_compiles(topo):
     on one device."""
     cfg = LearnerConfig(seq_len=127, policy=PolicyConfig(arch="transformer", tf_context=128))
     assert _fits(_compile_train_step(cfg, topo.devices[:1]))
+
+
+@pytest.mark.parametrize("impl", ["megablox", "ragged_dot"])
+def test_expert_layer_compiles_at_published_widths(topo, impl):
+    """The routed-expert layer of the benchmark's transformer cell, forward
+    and backward at its real size (16,384 frames of 2,304, 16 of 64 experts
+    of 896 held, 8 a frame), with either grouped product: a kernel on the
+    chip (nine of them, forward and backward), no row scattered, and the
+    dropless buffer fits."""
+    from dotaclient_tpu.ops import moe
+
+    one = SingleDeviceSharding(topo.devices[0])
+    frames, D, E, held, I, K = 16384, 2304, 64, 16, 896, 8
+
+    def loss(x, wr, wg, wu, wd):
+        y, sizes = moe.expert_layer(x.astype(jnp.bfloat16), moe.route(moe.router_scores(x, wr), K),
+                                    wg, wu, wd, 0, impl)
+        return jnp.sum(y * y), sizes
+
+    shapes = [(frames, D), (D, E), (held, D, I), (held, D, I), (held, I, D)]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one) for s in shapes]
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 9
+    # no row of frames or of pairs is scattered (the kernel's own tile table, a few hundred integers, is)
+    assert not [ln for ln in text.splitlines() if " scatter(" in ln and ("16384" in ln or "131072" in ln)]
+    assert ("ragged-dot" in text) == (impl == "ragged_dot")
+    assert _fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3

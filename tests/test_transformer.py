@@ -330,3 +330,109 @@ def test_blockwise_local_attention_train_step_parity():
     m_dense = _run_one_step(_tf_learner_cfg("dp=8", ""))
     for k in m_dense:
         assert m_blk[k] == pytest.approx(m_dense[k], rel=1e-5, abs=1e-7), k
+
+
+# A published block's shape at a toy's widths: grouped key/value heads of a
+# width of their own, three sliding layers to one full with YaRN, RMSNorm,
+# no biases, a final norm, a routed-expert layer holding a share.
+TF_MOE = PolicyConfig(
+    arch="transformer", unit_embed_dim=16, lstm_hidden=32, mlp_hidden=16, dtype="float32",
+    tf_layers=4, tf_heads=4, tf_kv_heads=2, tf_head_dim=8, tf_context=16,
+    tf_layer_kinds="sliding,sliding,sliding,full", tf_window=5, tf_rope_theta=500000.0,
+    tf_yarn_factor=16.0, tf_yarn_original_context=8, tf_norm="rmsnorm", tf_bias=False,
+    tf_final_norm=True, moe_standardize_router=True, moe_experts=8, moe_experts_held=4,
+    moe_first_expert=2, moe_top_k=3,
+    moe_hidden=12, tf_attn_block=4,
+)
+
+
+class TestPublishedBlockShape:
+    @pytest.fixture(scope="class")
+    def net_and_params(self):
+        return P.PolicyNet(TF_MOE), P.init_params(TF_MOE, jax.random.PRNGKey(0))
+
+    def test_the_parameter_tree(self, net_and_params):
+        tf = net_and_params[1]["params"]["core"]["tf"]
+        assert set(tf) == {"block0", "block1", "block2", "block3", "ln_f"}
+        shapes = jax.tree.map(lambda x: x.shape, tf["block0"])
+        assert shapes == {
+            "ln1": {"scale": (32,)}, "qkv": {"kernel": (32, (4 + 2 * 2) * 8)},
+            "attn_out": {"kernel": (32, 32)}, "ln2": {"scale": (32,)},
+            "moe": {"router": (32, 8), "w_gate": (32, 4, 12), "w_up": (32, 4, 12),
+                    "w_down": (12, 4, 32)}}
+        # seeded expert matrices carry the scale of their fan-in, the first axis
+        assert float(jnp.std(tf["block0"]["moe"]["w_gate"])) == pytest.approx(32 ** -0.5, rel=0.2)
+        assert not np.asarray(tf["ln_f"]["scale"]).any()  # g - 1
+
+    def test_step_mode_equals_unroll(self, net_and_params):
+        """Through the one KVCache, [B, L, C, key/value heads, head width]:
+        a sliding layer masks by position, the rotary table is its kind's."""
+        net, params = net_and_params
+        B, T = 2, 14
+        obs = _obs(np.random.RandomState(3), B, T)
+        _, unrolled = net.apply(params, P.initial_state(TF_MOE, (B,)), obs, unroll=True)
+        state = P.initial_state(TF_MOE, (B,))
+        assert state.k.shape == (B, 4, 16, 2, 8)
+        values, logps = [], []
+        for t in range(T):
+            state, out = net.apply(params, state, jax.tree.map(lambda x: x[:, t], obs))
+            values.append(out.value)
+            logps.append(out.dist.type_logp)
+            assert out.stats is None  # the counters are the learner's
+        np.testing.assert_allclose(np.stack(values, 1), unrolled.value, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(np.stack(logps, 1), unrolled.dist.type_logp, rtol=1e-4, atol=1e-5)
+        assert unrolled.stats["moe_local_pairs"] <= B * T * 3 * 4
+        assert unrolled.stats["moe_load_max_over_mean"] >= 1.0
+
+    def test_blocked_remat_and_dense_unrolls_agree(self, net_and_params):
+        import dataclasses
+
+        net, params = net_and_params
+        obs = _obs(np.random.RandomState(4), 2, 14)
+        state = P.initial_state(TF_MOE, (2,))
+        _, want = net.apply(params, state, obs, unroll=True)
+        for change in (dict(tf_attn_block=0), dict(tf_remat=True), dict(tf_attn_block=8)):
+            _, got = P.PolicyNet(dataclasses.replace(TF_MOE, **change)).apply(
+                params, state, obs, unroll=True)
+            np.testing.assert_allclose(got.value, want.value, rtol=1e-5, atol=1e-6)
+
+    def test_a_sliding_layer_forgets_what_left_its_window(self, net_and_params):
+        """With every layer sliding, frame t does not see frame t - window;
+        with the published period's full layer it does."""
+        import dataclasses
+
+        obs = _obs(np.random.RandomState(5), 1, 12)
+        moved = jax.tree.map(np.copy, obs)
+        moved.hero_feats[:, 0] += 1.0
+        for kinds, sees in (("sliding", False), ("sliding,sliding,sliding,full", True)):
+            cfg = dataclasses.replace(TF_MOE, tf_layers=1 if not sees else 4, tf_layer_kinds=kinds,
+                                      moe_experts=0)
+            net, params = P.PolicyNet(cfg), P.init_params(cfg, jax.random.PRNGKey(1))
+            state = P.initial_state(cfg, (1,))
+            a = net.apply(params, state, obs, unroll=True)[1].value
+            b = net.apply(params, state, moved, unroll=True)[1].value
+            far = np.abs(np.asarray(a - b))[0, 5:]  # frames 5.. are more than 4 behind frame 0
+            assert (far > 0).any() == sees and np.abs(np.asarray(a - b))[0, 0] > 0
+
+    def test_bad_shapes_are_refused(self):
+        import dataclasses
+
+        for change, match in ((dict(tf_layer_kinds="sliding,local"), "unknown kind"),
+                              (dict(tf_window=0), "tf_window"),
+                              (dict(tf_kv_heads=3), "tf_kv_heads"),
+                              (dict(moe_first_expert=6), "moe"),
+                              (dict(tf_norm="batchnorm"), "tf_norm")):
+            cfg = dataclasses.replace(TF_MOE, **change)
+            with pytest.raises(ValueError, match=match):
+                P.init_params(cfg, jax.random.PRNGKey(0))
+
+    def test_the_step_reports_the_routing_counters(self):
+        cfg = LearnerConfig(batch_size=4, seq_len=7, policy=TF_MOE, mesh_shape="dp=1")
+        mesh = mesh_lib.make_mesh("dp=1", devices=jax.devices()[:1])
+        step, state_sh, batch_sh = build_train_step(cfg, mesh)
+        state = jax.device_put(init_train_state(cfg, jax.random.PRNGKey(0)), state_sh)
+        _, metrics = step(state, jax.device_put(make_train_batch(cfg, 0), batch_sh))
+        frames = 4 * 8
+        assert 0 < float(metrics["moe_local_pairs"]) <= frames * 3 * 4
+        assert float(metrics["moe_load_max_over_mean"]) >= 1.0
+        assert np.isfinite(float(metrics["loss"]))
