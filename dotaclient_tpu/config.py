@@ -44,13 +44,17 @@ class PolicyConfig:
     # "ulysses" (all-to-all head re-sharding; needs tf_heads divisible
     # by the sp axis). Same math either way — ops/ring_attention.py.
     tf_sp_mode: str = "ring"
-    # Key-block size for the blockwise (flash-formulation) LOCAL
-    # attention in the learner unroll: caps peak intermediates at
-    # [N, T, block] instead of [N, T, T] for long single-device chunks.
-    # 0 = dense. Engages only when the key axis exceeds the block.
-    # Applies to local attention AND to the ulysses SP path (whose
-    # per-head-group attention sees the full time axis); the ring is
-    # blockwise by construction and ignores it.
+    # Blocked (flash-formulation) LOCAL attention in the learner unroll:
+    # > 0 turns it on wherever the key axis exceeds this size, 0 = dense.
+    # It is the query and key block of the plain path (peak
+    # intermediates [N, block, keys of a block's range] instead of
+    # [N, T, T]), which runs on the CPU, in the ulysses SP path (whose
+    # per-head-group attention sees the full time axis) and on shapes the
+    # kernel refuses. On a TPU, with head widths of a multiple of 128 and
+    # T a multiple of 128, the blocked case goes through the fused Pallas
+    # kernel instead, which chooses its own tiles (ops/attention.py
+    # fused_tiles; ops/ring_attention.py fused_applies has the rule).
+    # The ring is blockwise by construction and ignores this.
     tf_attn_block: int = 0
     # Rematerialize transformer blocks in the learner unroll
     # (jax.checkpoint): activations are recomputed in the backward

@@ -201,6 +201,7 @@ class Block(nn.Module):
     kind: str = "full"
     sp_mesh: Optional[Mesh] = None
     platform: str = ""  # of the devices the surrounding program runs on, where known
+    fused: bool = False  # the unroll's attention goes through the fused kernel (RA.fused_applies)
 
     @nn.compact
     def __call__(
@@ -236,8 +237,11 @@ class Block(nn.Module):
             qkv = dense((N + 2 * G) * Dh, "qkv")(h.astype(dt))
             q, k, v = jnp.split(qkv, [N * Dh, (N + G) * Dh], axis=-1)
             # RoPE at this token's absolute position; cached K were rotated
-            # at write time, so angles are consistent across modes.
-            q = A.rope(q.reshape(q.shape[:-1] + (N, Dh)), positions, table=table)
+            # at write time, so angles are consistent across modes. The
+            # fused kernel wants the scores' 1/sqrt(Dh) in q: it goes into
+            # q's table, where the rotation is still float32.
+            q_table = (table[0], table[1] * Dh**-0.5) if self.fused else table
+            q = A.rope(q.reshape(q.shape[:-1] + (N, Dh)), positions, table=q_table)
             k = A.rope(k.reshape(k.shape[:-1] + (G, Dh)), positions, table=table)
             v = v.reshape(v.shape[:-1] + (G, Dh))
 
@@ -246,7 +250,7 @@ class Block(nn.Module):
                 attn = RA.attend(
                     q, k, v, positions, positions,
                     mesh=self.sp_mesh, sp_axis=cfg.tf_sp_axis, sp_mode=cfg.tf_sp_mode,
-                    kv_block=cfg.tf_attn_block, window=window,
+                    kv_block=cfg.tf_attn_block, window=window, fused=self.fused,
                 )
             else:
                 k_cache, v_cache, cache_pos, onehot, _ = cache
@@ -273,12 +277,13 @@ class Block(nn.Module):
             return x + h.astype(jnp.float32), new_cache and new_cache + (cache[4],), None
 
 
-def _moe_stats(sizes) -> Optional[dict]:
+def _moe_stats(sizes) -> dict:
     """The step's routing counters from each layer's pairs per held
     expert: the most loaded held expert over the mean, of the worst
-    layer, and the pairs computed here over all layers."""
+    layer, and the pairs computed here over all layers. Empty without a
+    routed-expert layer."""
     if sizes[0] is None:
-        return None
+        return {}
     per_layer = jnp.stack(sizes).astype(jnp.float32)  # [L, held]
     mean = jnp.maximum(jnp.mean(per_layer, axis=-1), 1e-9)
     return {
@@ -292,7 +297,8 @@ class TransformerCore(nn.Module):
 
     Unroll: x [B, T, D] → [B, T, D], carry passed through untouched
     (chunk-local context). Step: x [B, D] → [B, D], carry is a KVCache.
-    Third result: the routing counters of a routed-expert core, or None.
+    Third result: the unroll's counters (how many layers' attention took
+    the fused kernel, and a routed-expert core's routing), or None.
     """
 
     cfg: PolicyConfig
@@ -310,15 +316,24 @@ class TransformerCore(nn.Module):
             B, T = x.shape[0], x.shape[1]
             positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
             h = x.astype(jnp.float32)
+            N, G, Dh = head_shape(cfg)
+            fused = RA.fused_applies(
+                platform or jax.default_backend(), (B, T, N, Dh), (B, T, G, Dh), cfg.tf_attn_block,
+                mesh=self.sp_mesh, sp_axis=cfg.tf_sp_axis,
+            )
             # cfg.tf_remat: recompute each block's activations in the
             # backward instead of storing them (jax.checkpoint) —
             # O(T·D) residuals per block instead of every intermediate.
-            block_cls = nn.remat(Block) if cfg.tf_remat else Block
+            # The fused kernel's output and log-sum-exp are kept (their
+            # name is the policy's), so its forward pass runs once a step.
+            keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS) if fused else None
+            block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
             sizes = []
             for i, kind in enumerate(kinds):
-                h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, name=f"block{i}")(h, positions)
+                h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, fused, name=f"block{i}")(h, positions)
                 sizes.append(n)
-            return carry, final(h), _moe_stats(sizes)
+            stats = {"attn_fused_layers": jnp.float32(len(kinds) if fused else 0), **_moe_stats(sizes)}
+            return carry, final(h), stats
 
         assert isinstance(carry, KVCache), "transformer step mode needs a KVCache carry"
         C = carry.pos.shape[1]

@@ -33,7 +33,7 @@ Design, TPU-first:
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -235,10 +235,15 @@ def blockwise_causal_attention(
     `jax.checkpoint`: what is kept for the backward pass is q, k and v,
     and the [N, kv_block, keys] scores exist for one query block at a
     time, forward and backward. The shapes are static and a ragged last
-    block is a shorter slice. The compiler-friendly formulation (static
-    slices, no kernel) is deliberate: a hand-written Pallas kernel is the
-    step to take only if a profile shows the fusion falling short
-    (ops/lstm.py precedent: measure on silicon first).
+    block is a shorter slice. This is the plain formulation (static
+    slices, no kernel): what runs on the CPU, on shapes the kernel below
+    refuses and in every test, and the reference `fused_causal_attention`
+    is tested against. On a TPU its float32 scores go to memory and are
+    evaluated three times before the backward pass proper (forward, the
+    block's rematerialisation, this function's own checkpoint): measured
+    at 15-19% of the layer's roofline (PERF.md, PR 30), which is why the
+    learner's unroll takes the kernel there (ops/ring_attention.py
+    `fused_applies`).
     """
     T = q.shape[-3]
     if k.shape[-3] != T:
@@ -251,3 +256,86 @@ def blockwise_causal_attention(
         out.append(one_block(q[..., rows, :, :], k[..., keys, :, :], v[..., keys, :, :],
                              q_pos[..., rows], k_pos[..., keys], window))
     return jnp.concatenate(out, axis=-3)
+
+
+# Name under which the fused kernel's residuals (its output and the
+# log-sum-exp of its scores) go into a surrounding `jax.checkpoint`: a
+# policy that saves this name recomputes everything of a block but the
+# attention forward (models/transformer_policy.py TransformerCore).
+FUSED_RESIDUALS = "attn_fused_residuals"
+
+
+def fused_tiles(T: int, window: int = 0) -> Tuple[int, int]:
+    """(tile of the query and key axes, keys computed at a time inside a
+    key tile) of the fused kernel for a chunk of T frames, (0, 0) where it
+    has none. The largest of 1,024, 512, 256, 128 that divides T and, in
+    a windowed layer, is no larger than the window (a tile far wider than
+    the window computes mostly masked pairs), 512 keys at a time. Measured
+    on a TPU v5e at 4 rows of T 4,096, 32 heads on 4 of 128 (PERF.md,
+    PR 33): forward and backward 14.5 ms at window 1,024 and 18.3 ms
+    causal, against 15.5 and 21.1 with tiles of 512, 29.8 and 43.8 with
+    tiles of 256, and 14.9 and 18.7 with all 1,024 keys at a time."""
+    for tile in (1024, 512, 256, 128):
+        if T % tile == 0 and (not window or tile <= max(window, 128)):
+            return tile, min(tile, 512)
+    return 0, 0
+
+
+def fused_takes(T: int, N: int, G: int, Dh: int) -> bool:
+    """Whether `fused_causal_attention` takes a chunk of T frames with N
+    query heads on G key/value heads of width Dh: the head width fills
+    the lanes (a multiple of 128), T divides by a tile, N by G."""
+    return Dh % 128 == 0 and N % G == 0 and fused_tiles(T)[0] > 0
+
+
+def fused_causal_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    window: int = 0,
+    interpret: bool = False,
+    q_scaled: bool = False,
+    tiles: Optional[Tuple[int, int]] = None,
+) -> jnp.ndarray:
+    """`causal_attention` for a chunk whose frame t is query and key t
+    (q [B, T, N, Dh], k/v [B, T, G, Dh] -> [B, T, N, Dh] in q.dtype), by
+    the Pallas TPU kernel that ships with JAX (splash attention), forward
+    and backward: the scores of a tile are made, normalised and used in
+    fast memory and never written out. The mask is static (causal, and
+    with `window` the last `window` keys), a tile that holds no unmasked
+    pair is never computed, and query head n reads key/value head
+    n // (N // G) where it lies (K/V are not repeated). q, k, v go to the
+    MXU in their own type; scores, running maximum, normaliser and
+    accumulators are float32. The kernel's output and log-sum-exp carry
+    the checkpoint name `FUSED_RESIDUALS`.
+
+    `q_scaled`: q already holds the 1/sqrt(Dh) (the caller folded it in
+    where q was still float32, `rope`'s table); otherwise it is applied
+    here, which rounds a bfloat16 q a second time. `interpret`: run the
+    kernel in Pallas' interpreter (the CPU's tests). `tiles`: in
+    place of `fused_tiles`' choice (the tests', to cut a small chunk into
+    several tiles).
+    """
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as splash
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as masks
+
+    T, N, Dh = q.shape[-3:]
+    if k.shape[-3] != T or not fused_takes(T, N, k.shape[-2], Dh):
+        raise ValueError(f"the fused kernel does not take q {q.shape} with k {k.shape}")
+    tile, compute = tiles or fused_tiles(T, window)
+    mask = masks.LocalMask((T, T), (window - 1, 0), 0) if window else masks.CausalMask((T, T))
+    kernel = splash.make_splash_mha_single_device(
+        masks.MultiHeadMask([mask] * N),
+        # one backward kernel gives dq with dk and dv: the scores are recomputed once, not twice
+        block_sizes=splash.BlockSizes(
+            block_q=tile, block_kv=tile, block_kv_compute=compute,
+            block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=compute,
+            use_fused_bwd_kernel=True,
+        ),
+        residual_checkpoint_name=FUSED_RESIDUALS,
+        interpret=interpret,
+    )
+    if not q_scaled:
+        q = (q.astype(jnp.float32) * Dh**-0.5).astype(q.dtype)
+    head_major = lambda x: jnp.swapaxes(x, -3, -2)  # [B, T, heads, Dh] <-> [B, heads, T, Dh]
+    return head_major(jax.vmap(kernel)(head_major(q), head_major(k), head_major(v)))

@@ -172,6 +172,40 @@ def ulysses_causal_attention(
     return mapped(q, k, v, q_pos, k_pos)
 
 
+def fused_applies(
+    platform: str,
+    q_shape,
+    k_shape,
+    kv_block: int,
+    mesh: Optional[Mesh] = None,
+    sp_axis: str = "",
+) -> bool:
+    """Whether local attention over q [B, T, N, Dh] and k [B, Tk, G, Dh]
+    goes through the fused kernel (`A.fused_causal_attention`) where it
+    would go through the plain blocks: blocked attention is on and the
+    key axis longer than its block, the program runs on a TPU
+    (`platform`: of the mesh's devices where a mesh is known, else the
+    default backend), queries and keys are one chunk (the actor's step
+    over its cache stays dense), the time axis is not sharded, and the
+    shapes are ones the kernel takes (`A.fused_takes`). On a mesh of
+    several devices only `dp` may be larger than 1 and has to divide the
+    rows: the kernel cannot be partitioned automatically and is mapped
+    over `dp`. One function computed two ways; the choice follows what
+    the program can observe, and no option names it."""
+    B, T, N, Dh = q_shape
+    Tk, G = k_shape[-3:-1]
+    axes = dict(mesh.shape) if mesh is not None else {}
+    return (
+        platform == "tpu"
+        and bool(kv_block)
+        and T == Tk > kv_block
+        and A.fused_takes(T, N, G, Dh)
+        and not (sp_axis and sp_axis in axes)  # `attend` sends such a mesh to the ring
+        and all(n == 1 for a, n in axes.items() if a != "dp")
+        and B % axes.get("dp", 1) == 0
+    )
+
+
 def attend(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -183,6 +217,7 @@ def attend(
     sp_mode: str = "ring",
     kv_block: int = 0,
     window: int = 0,
+    fused: bool = False,
 ) -> jnp.ndarray:
     """Dispatch: sequence-parallel attention when a mesh with an `sp`
     axis is supplied (learner long-context mode) — `sp_mode` picks the
@@ -191,7 +226,10 @@ def attend(
     flash formulation when `kv_block` is set and the key axis exceeds
     it (long single-device chunks), dense single-block else (actor
     stepping, short chunks, tests). `window`: a sliding layer's (local
-    paths only)."""
+    paths only). `fused`: the caller found `fused_applies` true for
+    these shapes and has folded the 1/sqrt(Dh) into q; the blocked case
+    then goes through the fused kernel, mapped over `dp` on a mesh of
+    several devices."""
     if mesh is not None and sp_axis and sp_axis in mesh.axis_names:
         if window:
             raise ValueError("a sliding layer's window is not carried over the sp axis yet")
@@ -200,6 +238,13 @@ def attend(
         if sp_mode != "ring":
             raise ValueError(f"unknown sp_mode {sp_mode!r} (ring|ulysses)")
         return ring_causal_attention(q, k, v, q_pos, k_pos, mesh, sp_axis)
+    if fused:
+        kernel = functools.partial(A.fused_causal_attention, window=window, q_scaled=True)
+        if mesh is not None and mesh.size > 1:
+            # check_vma off: pallas_call declares no varying-axes rule (ops/lstm.py does the same)
+            kernel = shard_map(kernel, mesh=mesh, in_specs=(P("dp"),) * 3, out_specs=P("dp"),
+                               check_vma=False)
+        return kernel(q, k, v)
     if kv_block and k.shape[-3] > kv_block:
         return A.blockwise_causal_attention(q, k, v, q_pos, k_pos, kv_block, window)
     return A.causal_attention(q, k, v, q_pos, k_pos, window)

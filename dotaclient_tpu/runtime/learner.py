@@ -1758,7 +1758,7 @@ def main(argv=None):
         stats = learner.staging.stats()
         _log.info(
             "learner done: version=%d env_steps=%d wire_frames=%d weights_published=%d "
-            "compile_cache=%s hits=%d misses=%d",
+            "compile_cache=%s hits=%d misses=%d attn_fused_layers=%d",
             learner.version,
             learner.env_steps_done,
             stats["wire_frames_obs_f32"] + stats["wire_frames_obs_bf16"],
@@ -1766,6 +1766,9 @@ def main(argv=None):
             cache.dir,
             cache.hits,
             cache.misses,
+            # layers of the compiled unroll whose attention took the fused
+            # kernel, as the last metrics window had it (transformer family)
+            learner.metrics.latest().get("attn_fused_layers", 0),
         )
 
 

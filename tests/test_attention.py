@@ -428,3 +428,145 @@ class TestRopeTable:
         x = jnp.asarray(_rand((1, 5, 1, dim), 71))
         out = A.rope(x, _positions(1, 5), table=(inv, scale))
         np.testing.assert_allclose(np.linalg.norm(out, axis=-1), scale * np.linalg.norm(x, axis=-1), rtol=1e-5)
+
+
+def pallas_kernels(jaxpr) -> list:
+    """The names of every Pallas kernel call in a jaxpr, sub-programs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(str(eqn.params.get("name") or eqn.params["name_and_src_info"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(pallas_kernels(sub))
+    return names
+
+
+class TestFusedKernel:
+    """`fused_causal_attention` (the bundled splash kernel, in Pallas'
+    interpreter here) against the dense masked softmax: a chunk of four
+    tiles, grouped heads, a window that is a multiple of the tile and one
+    that is not."""
+
+    B, T, N, G, Dh = 2, 512, 8, 2, 128
+    TILES = (128, 128)
+
+    def _inputs(self, seed):
+        B, T, N, G, Dh = self.B, self.T, self.N, self.G, self.Dh
+        q = jnp.asarray(_rand((B, T, N, Dh), seed))
+        k, v = (jnp.asarray(_rand((B, T, G, Dh), seed + s)) for s in (1, 2))
+        return q, k, v, jnp.asarray(_rand((B, T, N, Dh), seed + 3)), _positions(B, T)
+
+    # float32: both sides compute the same thing; bfloat16 inputs: the kernel's backward pass rounds the
+    # probabilities and the scores' cotangent to bfloat16 as operands, the plain one keeps them float32
+    @pytest.mark.parametrize("dtype,out_tol,grad_tol", [("float32", 2e-5, 1e-4), ("bfloat16", 2e-2, 5e-2)])
+    @pytest.mark.parametrize("window", [0, 256, 200], ids=lambda w: f"window{w}")
+    def test_output_and_gradients_match_causal_attention(self, window, dtype, out_tol, grad_tol):
+        q32, k, v, cot, pos = self._inputs(100 + window)
+        dt = jnp.dtype(dtype)
+        k, v = k.astype(dt), v.astype(dt)
+
+        def plain(q32, k, v):
+            out = A.causal_attention(q32.astype(dt), k, v, pos, pos, window)
+            return jnp.sum(out.astype(jnp.float32) * cot), out
+
+        def fused(q32, k, v):
+            # as the block does: the 1/sqrt(Dh) goes in while q is float32
+            out = A.fused_causal_attention((q32 * self.Dh**-0.5).astype(dt), k, v, window, interpret=True,
+                                           q_scaled=True, tiles=self.TILES)
+            return jnp.sum(out.astype(jnp.float32) * cot), out
+
+        (_, want), g_want = jax.value_and_grad(plain, (0, 1, 2), has_aux=True)(q32, k, v)
+        (_, got), g_got = jax.value_and_grad(fused, (0, 1, 2), has_aux=True)(q32, k, v)
+        assert got.dtype == dt and got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=out_tol)
+        for a, b in zip(g_got, g_want):
+            assert a.dtype == b.dtype
+            scale = float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+            np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), atol=grad_tol * scale)
+
+    def test_the_scale_is_applied_here_unless_q_holds_it(self):
+        q, k, v, _, pos = self._inputs(7)
+        q, k, v = q[:1, :256], k[:1, :256], v[:1, :256]
+        want = A.causal_attention(q, k, v, pos[:1, :256], pos[:1, :256], 100)
+        got = A.fused_causal_attention(q, k, v, 100, interpret=True, tiles=self.TILES)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+    def test_the_code_chooses_the_tiles(self):
+        assert A.fused_tiles(4096) == (1024, 512) and A.fused_tiles(4096, 1024) == (1024, 512)
+        assert A.fused_tiles(1536) == (512, 512) and A.fused_tiles(384) == (128, 128)
+        assert A.fused_tiles(4096, 200) == (128, 128)  # no tile far wider than the window
+        assert A.fused_tiles(4095) == (0, 0) and not A.fused_takes(4095, 32, 4, 128)
+        assert A.fused_takes(4096, 32, 4, 128) and not A.fused_takes(4096, 32, 4, 64)
+        assert not A.fused_takes(4096, 32, 5, 128)
+        with pytest.raises(ValueError, match="fused kernel does not take"):
+            A.fused_causal_attention(jnp.zeros((1, 256, 2, 64)), jnp.zeros((1, 256, 1, 64)),
+                                     jnp.zeros((1, 256, 1, 64)), interpret=True)
+
+    @pytest.mark.parametrize("keep", [False, True], ids=["remat_all", "residuals_kept"])
+    def test_the_forward_kernel_runs_once_where_its_residuals_are_kept(self, keep):
+        """Under a checkpoint the forward kernel is in the gradient's
+        program twice (the forward pass and its rematerialisation); with a
+        policy that keeps the kernel's named residuals, once."""
+        q, k, v, cot, _ = self._inputs(11)
+        q, k, v, cot = q[:1, :256], k[:1, :256], v[:1, :256], cot[:1, :256]
+
+        def layer(q, k, v):
+            out = A.fused_causal_attention(jnp.tanh(q), k, v, 0, interpret=True, tiles=self.TILES)
+            return jnp.sum(out * cot)
+
+        policy = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS) if keep else None
+        grad = jax.grad(jax.checkpoint(layer, policy=policy), (0, 1, 2))
+        names = pallas_kernels(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+        assert sum("fwd" in n for n in names) == (1 if keep else 2), names
+        assert sum("dkv" in n for n in names) == 1 and len(names) == (2 if keep else 3), names
+        for a, b in zip(grad(q, k, v), jax.grad(layer, (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+# The transformer cell's shapes: 4 rows of 4,096 frames, 32 query heads on 4 of 128, blocks of 256.
+_CELL_Q, _CELL_K = (4, 4096, 32, 128), (4, 4096, 4, 128)
+
+
+@pytest.mark.parametrize("platform,q,k,kv_block,mesh,takes", [
+    ("tpu", _CELL_Q, _CELL_K, 256, "", True),
+    ("tpu", _CELL_Q, _CELL_K, 256, "dp=4", True),  # mapped over dp
+    ("cpu", _CELL_Q, _CELL_K, 256, "", False),
+    ("gpu", _CELL_Q, _CELL_K, 256, "", False),
+    ("tpu", (4, 1, 32, 128), _CELL_K, 256, "", False),  # the actor's step over its cache
+    ("tpu", (4, 4096, 32, 64), (4, 4096, 4, 64), 256, "", False),  # narrow heads
+    ("tpu", (4, 4095, 32, 128), (4, 4095, 4, 128), 256, "", False),  # a ragged chunk
+    ("tpu", _CELL_Q, _CELL_K, 0, "", False),  # blocked attention is off
+    ("tpu", (4, 256, 32, 128), (4, 256, 4, 128), 256, "", False),  # one block: the dense path
+    ("tpu", _CELL_Q, _CELL_K, 256, "dp=1,sp=2", False),  # the time axis is sharded
+    ("tpu", _CELL_Q, _CELL_K, 256, "dp=2,tp=2", False),  # heads may be sharded: not partitioned
+    ("tpu", (6, 4096, 32, 128), (6, 4096, 4, 128), 256, "dp=4", False),  # rows do not divide
+], ids=["cell", "cell_dp4", "cpu", "gpu", "step_with_cache", "head_width_64", "ragged_T", "block_off",
+        "one_block", "sp_mesh", "tp_mesh", "rows_not_by_dp"])
+def test_where_the_fused_kernel_is_taken(platform, q, k, kv_block, mesh, takes):
+    n = int(np.prod([int(a.split("=")[1]) for a in mesh.split(",")])) if mesh else 0
+    mesh = mesh_lib.make_mesh(mesh, devices=jax.devices()[:n]) if mesh else None
+    got = RA.fused_applies(platform, q, k, kv_block, mesh=mesh,
+                           sp_axis="sp" if mesh is not None and "sp" in mesh.axis_names else "")
+    assert got is takes
+
+
+def test_the_fused_kernel_mapped_over_dp_matches_one_device(monkeypatch):
+    """On a mesh of several devices the kernel is shard_mapped over the
+    rows: output and gradients as on one device."""
+    mesh = mesh_lib.make_mesh("dp=4", devices=jax.devices()[:4])
+    B, T, N, G, Dh = 4, 256, 2, 1, 128
+    q = jnp.asarray(_rand((B, T, N, Dh), 120))
+    k, v = (jnp.asarray(_rand((B, T, G, Dh), s)) for s in (121, 122))
+    pos = _positions(B, T)
+    kernel = A.fused_causal_attention
+    monkeypatch.setattr(A, "fused_causal_attention", lambda q, k, v, window=0, q_scaled=False: kernel(
+        q, k, v, window, interpret=True, q_scaled=q_scaled, tiles=(128, 128)))
+    f = lambda mesh: jax.jit(jax.value_and_grad(lambda q, k, v: jnp.sum(
+        RA.attend(q, k, v, pos, pos, mesh=mesh, kv_block=64, window=100, fused=True) ** 2), (0, 1, 2)))
+    want, g_want = f(None)(q, k, v)
+    got, g_got = f(mesh)(q, k, v)
+    plain = jnp.sum(A.causal_attention(q * Dh**0.5, k, v, pos, pos, 100) ** 2)  # fused=True: q holds the scale
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    np.testing.assert_allclose(got, plain, rtol=1e-4)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)

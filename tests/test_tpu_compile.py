@@ -12,6 +12,7 @@ for the train steps, argument shardings for the rest).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -188,3 +189,35 @@ def test_expert_layer_compiles_at_published_widths(topo, impl):
     assert ("ragged-dot" in text) == (impl == "ragged_dot")
     assert _fits(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_fused_attention_compiles_at_the_cells_shapes(topo, n_devices):
+    """The fused attention kernel as the learner's unroll calls it, forward
+    and backward at the transformer cell's shapes (4 rows of 4,096 frames,
+    32 query heads on 4 of 128, window 1,024), on one described chip and
+    mapped over dp=4: two kernels (the forward and the one backward
+    kernel), each with the layer's scope in its `op_name`, no row gathered
+    across chips, and the whole fits."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from dotaclient_tpu.ops import ring_attention as RA
+
+    mesh = mesh_lib.make_mesh("dp=-1", devices=topo.devices[:n_devices])
+    B, T, N, G, Dh = 4, 4096, 32, 4, 128
+    assert RA.fused_applies("tpu", (B, T, N, Dh), (B, T, G, Dh), 256, mesh=mesh)
+
+    def loss(q, k, v):
+        with jax.named_scope("attn_window"):
+            out = RA.attend(q, k, v, None, None, mesh=mesh, kv_block=256, window=1024, fused=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    args = [jax.ShapeDtypeStruct((B, T, h, Dh), jnp.bfloat16, sharding=rows) for h in (N, G, G)]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
+    assert len(kernels) >= 2 and all("attn_window" in n for n in kernels), kernels
+    assert "all-gather" not in text
+    assert _fits(compiled)

@@ -436,3 +436,72 @@ class TestPublishedBlockShape:
         assert 0 < float(metrics["moe_local_pairs"]) <= frames * 3 * 4
         assert float(metrics["moe_load_max_over_mean"]) >= 1.0
         assert np.isfinite(float(metrics["loss"]))
+
+
+# The published block's shape with heads the fused kernel takes (a width of
+# 128): on a TPU this unroll goes through the kernel, and on the CPU only the
+# platform keeps it on the plain blocks.
+TF_FUSABLE = PolicyConfig(
+    arch="transformer", unit_embed_dim=16, lstm_hidden=32, mlp_hidden=16, dtype="float32",
+    tf_layers=2, tf_heads=2, tf_kv_heads=1, tf_head_dim=128, tf_context=256,
+    tf_layer_kinds="sliding,full", tf_window=100, tf_rope_theta=500000.0,
+    tf_yarn_factor=16.0, tf_yarn_original_context=128, tf_norm="rmsnorm", tf_bias=False,
+    tf_final_norm=True, moe_standardize_router=True, moe_experts=4, moe_experts_held=2,
+    moe_top_k=2, moe_hidden=12, tf_attn_block=64, tf_remat=True,
+)
+
+
+class TestFusedAttentionInTheUnroll:
+    B, T = 1, 256
+
+    def _value_and_grad(self, params, obs):
+        net = P.PolicyNet(TF_FUSABLE)
+        state = P.initial_state(TF_FUSABLE, (self.B,))
+
+        def loss(params):
+            _, out = net.apply(params, state, obs, unroll=True)
+            return jnp.sum(out.value ** 2) + jnp.sum(out.dist.type_logp[..., 0]), out.stats
+
+        return jax.value_and_grad(loss, has_aux=True), loss
+
+    def test_the_cpu_stays_on_the_plain_blocks(self):
+        """A CPU trace of the cell's kind of policy: no layer takes the
+        kernel, and there is no Pallas call in the gradient's program."""
+        from tests.test_attention import pallas_kernels
+
+        params = P.init_params(TF_FUSABLE, jax.random.PRNGKey(0))
+        obs = _obs(np.random.RandomState(6), self.B, self.T)
+        vg, _ = self._value_and_grad(params, obs)
+        assert pallas_kernels(jax.make_jaxpr(vg)(params).jaxpr) == []
+        (_, stats), _ = vg(params)
+        assert float(stats["attn_fused_layers"]) == 0.0 and "moe_local_pairs" in stats
+
+    def test_with_the_kernel_the_unroll_computes_the_same(self, monkeypatch):
+        """The rule answered yes (as on a TPU) and the kernel run in Pallas'
+        interpreter: value, counters and gradients as on the plain blocks,
+        every layer counted, and under tf_remat one forward kernel a layer
+        in the gradient's program."""
+        from dotaclient_tpu.ops import attention as A
+        from dotaclient_tpu.ops import ring_attention as RA
+        from tests.test_attention import pallas_kernels
+
+        params = P.init_params(TF_FUSABLE, jax.random.PRNGKey(0))
+        obs = _obs(np.random.RandomState(6), self.B, self.T)
+        vg, _ = self._value_and_grad(params, obs)
+        (want, want_stats), g_want = vg(params)
+
+        kernel = A.fused_causal_attention
+        monkeypatch.setattr(RA, "fused_applies", lambda *a, **k: True)
+        monkeypatch.setattr(A, "fused_causal_attention", lambda q, k, v, window=0, q_scaled=False: kernel(
+            q, k, v, window, interpret=True, q_scaled=q_scaled, tiles=(128, 128)))
+        vg, _ = self._value_and_grad(params, obs)
+        names = pallas_kernels(jax.make_jaxpr(vg)(params).jaxpr)
+        assert sum("fwd" in n for n in names) == 2 and sum("dkv" in n for n in names) == 2, names
+        (got, stats), g_got = vg(params)
+        assert float(stats["attn_fused_layers"]) == 2.0
+        assert float(stats["moe_local_pairs"]) == float(want_stats["moe_local_pairs"])
+        np.testing.assert_allclose(got, want, rtol=1e-4)
+        flat_want, flat_got = jax.tree.leaves(g_want), jax.tree.leaves(g_got)
+        assert len(flat_want) == len(flat_got)
+        for a, b in zip(flat_got, flat_want):
+            np.testing.assert_allclose(a, b, rtol=2e-3, atol=1e-4 * float(jnp.max(jnp.abs(b)) + 1e-6))
