@@ -131,17 +131,12 @@ def main() -> None:
     cfg.staging.pack_workers = pack_workers
     mesh = mesh_lib.make_mesh(cfg.mesh_shape)
     # The production flagship path, exactly what the Learner runs with
-    # default config: fused SINGLE-buffer H2D (one [B, row_bytes] u8
-    # put per batch) + host-side bf16 obs cast. fused_single_h2d=false
-    # falls back to the 4-buffer group layout.
-    from dotaclient_tpu.parallel.train_step import (
-        build_fused_train_step,
-        build_single_train_step,
-    )
+    # default config: fused single-buffer H2D (one [B, row_bytes] u8
+    # put per batch) + host-side bf16 obs cast.
+    from dotaclient_tpu.parallel.train_step import build_single_train_step
     from dotaclient_tpu.runtime.staging import cast_obs_to_compute_dtype
 
-    build = build_single_train_step if cfg.fused_single_h2d else build_fused_train_step
-    train_step, state_sh, io = build(cfg, mesh)
+    train_step, state_sh, io = build_single_train_step(cfg, mesh)
     state = jax.device_put(init_train_state(cfg, jax.random.PRNGKey(0)), state_sh)
 
     # ---- device-only rate (context): pre-packed batch, no host pipeline.
@@ -149,7 +144,7 @@ def main() -> None:
     # the ONE executable production runs (and the e2e section below hits
     # the already-compiled program instead of compiling a second one).
     host_batch = cast_obs_to_compute_dtype(cfg, jax.tree.map(np.asarray, make_train_batch(cfg, 0)))
-    batch = jax.device_put(io.pack_transfer(host_batch), io.transfer_shardings())
+    batch = jax.device_put(io.pack_transfer(host_batch), io.sharding)
     state, metrics = train_step(state, batch)
     jax.block_until_ready(metrics["loss"])
     t0 = time.perf_counter()
@@ -244,20 +239,13 @@ def main() -> None:
         raw = np.frombuffer(b"".join(_payloads), np.uint8).reshape(
             len(_payloads), io.row_bytes
         )
-        if isinstance(payload, dict):
-            for key, buf in payload.items():
-                u8 = buf.view(np.uint8)
-                off = io.seg_off[key]
-                u8[: len(_payloads)] = raw[:, off : off + u8.shape[1]]
-        else:
-            payload[: len(_payloads)] = raw
+        payload[: len(_payloads)] = raw
 
     host_pack_cpu_s = _time_arm(_classic_pack)
     host_concat_s = _time_arm(_concat_land)
 
     # ---- end-to-end rate: producers → broker → staging → device, with
-    # the learner's PIPELINED loop (--learner.prefetch, the production
-    # default): the SAME PrefetchLane the Learner runs stages batch N+1
+    # the learner's loop: the SAME PrefetchLane the Learner runs stages batch N+1
     # — staging pop, device_put dispatch, transfer retire, lease release
     # — on its own thread while step N executes, INCLUDING the per-step
     # weight publish exactly as Learner.run does it at the default
@@ -291,7 +279,7 @@ def main() -> None:
         steps = int(np.sum(b.mask))
         lease = staging.last_batch_lease
         t1 = time.perf_counter()
-        dev = jax.device_put(payload, io.transfer_shardings())
+        dev = jax.device_put(payload, io.sharding)
         if lease is not None:
             # ring mode: the slot may be repacked the moment it is
             # released — wait for the transfer to retire first
@@ -313,7 +301,7 @@ def main() -> None:
     # inflates pipeline_overlap_ratio (item 1's fetch is genuinely
     # exposed — the device has nothing to run yet — and reads as take).
     t0 = time.perf_counter()
-    lane = PrefetchLane(fetch, depth=1, limit=n_iters).start()
+    lane = PrefetchLane(fetch, limit=n_iters).start()
     for i in range(n_iters):
         tb = time.perf_counter()
         item = lane.get(timeout=150.0)  # the lane's own fetch bounds at 120s
@@ -342,62 +330,6 @@ def main() -> None:
     )
     device_s_per_iter = cfg.batch_size * cfg.seq_len / device_rate
     device_idle_s_per_iter = max(dt / n_iters - device_s_per_iter, 0.0)
-
-    # --- optional: full e2e with the ALTERNATE transfer layout (opt-in
-    # via env because it costs a second full XLA compile). With the
-    # single-buffer mode now
-    # the production default headline, this arm measures the 4-buffer
-    # GROUP layout (the pre-ISSUE-15 default) — the rollback
-    # comparison. Best-effort: failure degrades to an error field,
-    # never touches the primary (already measured) rate.
-    e2e_alt = e2e_alt_err = None
-    alt_layout = "groups_4_buffers" if cfg.fused_single_h2d else "single_buffer"
-    if os.environ.get("DOTACLIENT_TPU_BENCH_SINGLE") == "1":
-        stop_s = s_staging = None
-        try:
-            scfg = LearnerConfig(batch_size=256, seq_len=16, mesh_shape="dp=-1",
-                                 fused_single_h2d=not cfg.fused_single_h2d)
-            alt_build = (
-                build_single_train_step if scfg.fused_single_h2d else build_fused_train_step
-            )
-            alt_step, s_state_sh, s_io = alt_build(scfg, mesh)
-            s_state = jax.device_put(
-                init_train_state(scfg, jax.random.PRNGKey(0)), s_state_sh
-            )
-            stop_s = _start_producers(scfg, "bench_alt")
-            s_staging = StagingBuffer(
-                scfg, connect("mem://bench_alt"), version_fn=lambda: 0, fused_io=s_io
-            ).start()
-
-            def fetch_alt():
-                b, payload = s_staging.get_batch_groups(timeout=120.0)
-                if b is None:
-                    raise RuntimeError("alt-layout staging starved (timeout)")
-                steps = int(np.sum(b.mask))
-                return jax.device_put(payload, s_io.transfer_shardings()), steps
-
-            warm_s, _ = fetch_alt()
-            s_state, s_metrics = alt_step(s_state, warm_s)
-            jax.block_until_ready(s_metrics["loss"])
-            nxt_s, nxt_steps_s = fetch_alt()
-            steps_done = 0
-            t0 = time.perf_counter()
-            for _ in range(n_iters):
-                dev_s, n_s = nxt_s, nxt_steps_s
-                s_state, s_metrics = alt_step(s_state, dev_s)
-                steps_done += n_s
-                nxt_s, nxt_steps_s = fetch_alt()
-            jax.block_until_ready(s_metrics["loss"])
-            e2e_alt = steps_done / (time.perf_counter() - t0)
-        except Exception as e:
-            e2e_alt_err = f"{type(e).__name__}: {e}"[:300]
-        finally:
-            # Leaked producers/consumer would burn host cores for the
-            # rest of main() and skew the transfer A/B measured next.
-            if stop_s is not None:
-                stop_s.set()
-            if s_staging is not None:
-                s_staging.stop()
 
     # --- per-stage pipeline trace breakdown (dotaclient_tpu/obs/): a
     # short run of the SAME pipeline with trace-stamped (DTR2) frames,
@@ -438,11 +370,11 @@ def main() -> None:
         for t in t_threads:
             t.start()
         for _ in range(6):
-            b, groups = t_staging.get_batch_groups(timeout=120.0)
+            b, payload = t_staging.get_batch_groups(timeout=120.0)
             if b is None:
                 raise RuntimeError("traced staging starved (timeout)")
             trace = t_staging.last_batch_trace
-            dev = jax.device_put(groups, io.shardings)
+            dev = jax.device_put(payload, io.sharding)
             if trace is not None:
                 tracer.hop_batch("h2d", trace)
             state, metrics = train_step(state, dev)
@@ -483,7 +415,7 @@ def main() -> None:
             groups_p = io.pack_transfer(host_batch)
             t1p = time.perf_counter()
             ph.add("pack", t1p - t0p)
-            dev_p = jax.device_put(groups_p, io.transfer_shardings())
+            dev_p = jax.device_put(groups_p, io.sharding)
             jax.block_until_ready(dev_p)
             t2p = time.perf_counter()
             ph.add("h2d", t2p - t1p)
@@ -508,13 +440,12 @@ def main() -> None:
         compute_section = {"error": f"{type(e).__name__}: {e}"[:200]}
 
     # --- transfer-layout A/B (informational, best-effort): the same
-    # batch bytes H2D as 17 pytree leaves vs 4 dtype groups vs ONE
-    # concatenated byte buffer (decide-with-data: ROADMAP S2 keeps the
-    # winner and deletes the rest).
+    # batch H2D as 17 pytree leaves (the tree path sp and replay take)
+    # vs the ONE [B, row_bytes] u8 buffer.
     transfer_ab = None
     try:
-        host_groups = io.pack(host_batch)  # the host batch from the device-only section
-        sh = io.shardings[next(iter(host_groups))]
+        one_buf = io.pack_transfer(host_batch)  # the host batch from the device-only section
+        sh = io.sharding
 
         def _time_put(payload, shardings, reps=8):
             jax.block_until_ready(jax.device_put(payload, shardings))  # warm
@@ -527,23 +458,10 @@ def main() -> None:
             "tree_17_leaves_ms": round(
                 _time_put(host_batch, jax.tree.map(lambda _: sh, host_batch)) * 1e3, 3
             ),
-            "groups_4_buffers_ms": round(_time_put(host_groups, io.shardings) * 1e3, 3),
-            "note": "blocked device_put of the same batch bytes",
+            "single_buffer_ms": round(_time_put(one_buf, sh) * 1e3, 3),
+            "bytes": int(one_buf.nbytes),
+            "note": "blocked device_put of the same batch, dp-sharded rows",
         }
-        if n_dev == 1:
-            # Replicated 1-D put only compares fairly on one chip — on a
-            # dp>1 mesh it would ship n_dev x the bytes of the sharded
-            # legs and falsely conclude 4->1 is a loss. A multi-chip
-            # variant would row-split the buffer first.
-            one_buf = np.concatenate(
-                [np.ascontiguousarray(g).view(np.uint8).reshape(-1) for g in host_groups.values()]
-            )
-            transfer_ab["bytes"] = int(one_buf.nbytes)
-            transfer_ab["single_buffer_ms"] = round(
-                _time_put(one_buf, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
-                * 1e3,
-                3,
-            )
     except Exception:
         pass
 
@@ -644,7 +562,7 @@ def main() -> None:
         "host_pack_cpu_s_per_batch": round(host_pack_cpu_s, 6),
         "host_concat_s_per_batch": round(host_concat_s, 6),
         "e2e_over_device_only": round(e2e_rate / device_rate, 3),
-        # Overlapped-loop scoreboard (--learner.prefetch, ISSUE 15):
+        # Prefetch-lane scoreboard:
         # share of prefetch-lane work hidden behind the device step, the
         # lane's per-iteration busy time, the loop's exposed take-wait,
         # and device idle bounded from the measured device-only rate.
@@ -653,8 +571,6 @@ def main() -> None:
             "prefetch_s_per_iter": round(lane_work_s / n_iters, 5),
             "take_wait_s_per_iter": round(t_take / n_iters, 5),
             "device_idle_s_per_iter": round(device_idle_s_per_iter, 5),
-            "prefetch_depth": 1,
-            "transfer_layout": "single_buffer" if cfg.fused_single_h2d else "groups_4_buffers",
         },
         # Utilization accounting (SURVEY §6): analytic matmul FLOPs/step
         # (ops/flops.py, fwd+bwd), achieved FLOP/s at the e2e rate, and
@@ -687,11 +603,6 @@ def main() -> None:
         # from the post-headline compute section (obs/compute.py)
         "compute_breakdown": compute_section,
     }
-    if e2e_alt is not None:
-        out["e2e_alt_layout_steps_per_sec"] = round(e2e_alt, 1)
-        out["e2e_alt_layout"] = alt_layout
-    if e2e_alt_err is not None:
-        out["e2e_alt_layout_error"] = e2e_alt_err
     print(json.dumps(out))
 
 

@@ -432,7 +432,7 @@ def multichip_phase(
             init_train_state(cfg, jax.random.PRNGKey(cfg.seed)), state_shardings
         )
         payloads = [
-            jax.device_put(io.pack_transfer(b), io.transfer_shardings()) for b in host_batches
+            jax.device_put(io.pack_transfer(b), io.sharding) for b in host_batches
         ]
         t0 = time.perf_counter()
         compiled = step.lower(state, payloads[0]).compile()

@@ -1281,8 +1281,8 @@ class ShardEpochModel(_Model):
 
 
 class PrefetchModel(_Model):
-    """The overlapped learner pipeline lifecycle (runtime/learner.py
-    PrefetchLane + _fetch_next, --learner.prefetch):
+    """The learner loop's prefetch-lane lifecycle (runtime/learner.py
+    PrefetchLane + _fetch_next):
 
         ready --lane-take--> fetch-locals --put-dispatch--> in-flight
               --retire--> retired (lease released) --enqueue--> slot

@@ -242,33 +242,6 @@ class StagingConfig:
 
 
 @dataclass
-class LearnerPipelineConfig:
-    """Overlapped learner step loop (runtime/learner.py PrefetchLane):
-    a dedicated prefetch thread runs the whole host side of batch N+1 —
-    staging pop, pack-pool pack, device_put dispatch, lease retire —
-    WHILE the device executes train step N, so the host wall disappears
-    behind the device step (ROADMAP item 1; OPPO 2509.25762 pipeline
-    overlap, PAPERS.md). Batch ORDER is unchanged (the lane is the same
-    single staging consumer, FIFO), so the pipelined loop's params are
-    BITWISE identical to the serial loop over the same frame schedule —
-    tests/test_pipeline.py proves it. The PR-7 SIGTERM-drain contract
-    survives: an in-flight prefetched batch is trained out (never
-    dropped) and staging.drained() gains the prefetch-lane station."""
-
-    # Master switch. True (default) = the pipelined loop. False restores
-    # the serial fetch-after-step loop byte-for-byte (no lane thread, no
-    # pipeline_* scalars — the rollback path, MIGRATION item 15).
-    prefetch: bool = True
-    # Batches the lane may hold fetched-ahead (the handoff queue bound).
-    # 1 = classic double buffering: batch N+1 fully staged while step N
-    # runs. Sizing rule (README "Pipelined learner"): every queued batch
-    # ages one extra learner version before training, so keep
-    # prefetch_depth well under ppo.max_staleness (default 4) — depth 1
-    # is right unless a single fetch is slower than a device step.
-    prefetch_depth: int = 1
-
-
-@dataclass
 class WireConfig:
     """Experience-wire quantization (transport/serialize.py DTR3).
     Producer-side only — consumers (staging, the native packer) accept
@@ -582,16 +555,11 @@ class ObsConfig:
     # that own their signal handling.
     install_handlers: bool = True
     # Learner step-phase decomposition (obs/compute.py StepPhaseTimer):
-    # fetch/pack/h2d/device_step/host wall time per iteration, logged as
-    # compute_phase_* scalars. Under the pipelined loop
-    # (--learner.prefetch, the default) the timer runs in OVERLAP mode:
-    # fetch/pack/h2d are recorded on the prefetch lane (fenced there —
-    # the lane's own time, hidden behind the device step), the loop lane
-    # reports take-wait/residual/host, phases still tile the wall, and
-    # the pipeline_* scalars carry the overlap accounting — no per-step
-    # device fence, no overlap forfeited. Only the SERIAL loop
-    # (--learner.prefetch false) still pays the per-step
-    # block_until_ready fence for causal attribution.
+    # logged as compute_phase_* and pipeline_* scalars. The prefetch
+    # lane records its own fetch/pack/h2d (fenced there: the lane's own
+    # time, hidden behind the device step), the loop thread records
+    # take-wait/residual/host, and the phases tile the wall. The loop
+    # itself is never fenced per step.
     step_phases: bool = True
     # Where POST /profile?seconds=N captures land (jax.profiler.trace
     # TensorBoard dirs). "" = dump_dir (or cwd).
@@ -605,7 +573,14 @@ class ObsConfig:
 
 @dataclass
 class LearnerConfig:
-    """Learner binary (reference: optimizer.py CLI)."""
+    """Learner binary (reference: optimizer.py CLI).
+
+    The feed is two boxes, lane -> loop (runtime/learner.py): a prefetch
+    thread stages batch N+1 while the device runs step N. A batch
+    crosses to the device as ONE [B, row_bytes] u8 buffer
+    (parallel/fused_io.py); the Learner takes the per-leaf tree instead
+    where that layout cannot apply (a sequence-parallel mesh, the replay
+    reservoir), from the mesh and the config, with no option."""
 
     batch_size: int = 256  # sequences per train step (global, across dp shards)
     seq_len: int = 16  # rollout chunk length = LSTM truncation window
@@ -663,27 +638,11 @@ class LearnerConfig:
     native_packer: bool = True
     # Parallel host feed (--staging.pack_workers / --staging.transfer_depth).
     staging: StagingConfig = field(default_factory=StagingConfig)
-    # Overlapped step loop (--learner.prefetch / --learner.prefetch_depth):
-    # the field is named `learner` so the flags spell --learner.* on the
-    # learner binary — the pipeline knobs of the loop itself, as opposed
-    # to the staging/transport layers above.
-    learner: LearnerPipelineConfig = field(default_factory=LearnerPipelineConfig)
     # Stage obs floats in the policy compute dtype (bf16) on the host:
     # numerically identical (the policy's first op is the same cast) and
     # halves the dominant host→device transfer (runtime/staging.py
     # cast_obs_to_compute_dtype). Off = ship f32 and cast on device.
     stage_obs_compute_dtype: bool = True
-    # Move each batch to the device as 4 dtype-grouped buffers instead of
-    # 17 pytree leaves (parallel/fused_io.py), to pay the per-transfer
-    # overhead 4 times, not 17. Auto-falls back to the per-leaf tree
-    # path in sequence-parallel mode and with the replay reservoir.
-    fused_h2d: bool = True
-    # With fused_h2d: collapse the 4 dtype-grouped buffers further into
-    # ONE [B, row_bytes] u8 buffer per batch (free in-jit bitcasts
-    # unpack it). Default ON. No chip record times the three layouts
-    # against each other (ROADMAP S2 does); set false to fall back to
-    # the 4-buffer layout.
-    fused_single_h2d: bool = True
     # JAX backend of this process (runtime/device.py init_devices): ""
     # = JAX's default — JAX_PLATFORMS if set, else the best backend
     # present, which on a host without a chip is the CPU; the first log

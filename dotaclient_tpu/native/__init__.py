@@ -467,7 +467,7 @@ def pack_frames(
 
     `out`: a pre-allocated, pre-zeroed TrainBatch to fill instead of
     allocating one. Leaves may be row-strided views (the fused-H2D
-    group-buffer layout, FusedBatchIO.alloc_views) as long as each row's
+    transfer buffer, FusedBatchIO.alloc_transfer) as long as each row's
     data is contiguous — per-leaf row strides are passed to C. The
     caller owns initialization (zeros + NOOP-legal action-mask padding,
     exactly zeros_train_batch's contract).

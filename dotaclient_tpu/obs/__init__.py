@@ -115,20 +115,16 @@ class ObsRuntime:
     # ----------------------------------------------------------- compute
 
     def attach_compute(
-        self, flops_per_step: float, peak_flops: Optional[float], overlap: bool = False
+        self, flops_per_step: float, peak_flops: Optional[float]
     ) -> ComputeObserver:
         """Build the learner's compute bundle (obs/compute.py): phase
         timer (when cfg.step_phases), recompile sentinel factory, MFU
-        accounting — all sharing this runtime's flight recorder.
-        `overlap` puts the phase timer in the pipelined loop's per-lane
-        accounting mode (--learner.prefetch: no per-step fence, lane
-        sums + pipeline_* scalars)."""
+        accounting — all sharing this runtime's flight recorder."""
         self.compute = ComputeObserver(
             flops_per_step,
             peak_flops,
             recorder=self.recorder,
             step_phases=self.cfg.step_phases,
-            overlap=overlap,
         )
         return self.compute
 

@@ -10,14 +10,11 @@ causes:
 - StepPhaseTimer   every learner iteration split into
                    fetch / pack / h2d / device_step / host wall time.
                    Exists only under --obs.enabled + --obs.step_phases;
-                   the disabled path constructs nothing. In the SERIAL
-                   loop it fences per step (block_until_ready) for
-                   causal attribution; under the pipelined loop
-                   (--learner.prefetch) it runs in OVERLAP mode — the
-                   prefetch lane records its own fetch/pack/h2d, the
-                   loop lane reports the exposed wait/residual/host,
-                   and the pipeline_* family carries the overlap
-                   accounting with no per-step fence.
+                   the disabled path constructs nothing. The prefetch
+                   lane records its own fetch/pack/h2d, the loop thread
+                   reports the exposed wait/residual/host, and the
+                   pipeline_* family carries the overlap accounting
+                   with no per-step fence on the loop.
 - RecompileSentinel wraps the jitted train step, hashes the abstract
                    avals + treedef of every call, counts signatures
                    beyond the first as recompiles, records compile wall
@@ -54,73 +51,56 @@ _log = logging.getLogger(__name__)
 class StepPhaseTimer:
     """Per-iteration wall-time decomposition of the learner loop.
 
-    Phases (the loop's stations, in order):
-      fetch        host wait for a packed batch off staging
-      pack         io.pack fallback when staging didn't pre-pack (≈0 on
-                   the production fused path — pack runs on the staging
-                   thread and is charged to fetch's queue wait)
-      h2d          host→device transfer, FENCED (block_until_ready on
-                   the device batch) so it is the real transfer time,
-                   not the dispatch time
-      device_step  train-step dispatch + device execution, FENCED on the
-                   step's metrics
-      host         publish dispatch / checkpoint / metrics-window work
+    The host side of batch N+1 runs on the prefetch lane WHILE the
+    device executes step N, so fencing the loop per step would destroy
+    exactly what it measures. The accounting is two lanes:
 
-    Single-writer contract: only the learner loop thread calls add() and
-    step(); window_scalars() is called from that same thread at each
-    metrics window. The scrape thread reads the RESULT via
-    MetricsLogger.latest(), never this object.
-
-    The warm-up fetch and empty-wait retries record fetch time with no
-    closing step(), so a STARVED window's fetch mean can exceed its wall
-    mean — starvation is exactly when that should read loud. In a fed
-    window the phases tile the wall (the acceptance property).
-
-    OVERLAP mode (``overlap=True`` — the pipelined loop,
-    ``--learner.prefetch``): the host side of batch N+1 runs on a
-    dedicated prefetch lane WHILE the device executes step N, so
-    fencing the loop per step would destroy exactly what it measures.
-    Instead the accounting splits into two lanes:
-
-    - the LOOP lane keeps the single-writer add()/step() contract, but
-      ``fetch`` now means the loop's wait for a prefetched batch (the
-      exposed, un-hidden host time — the device-idle upper bound),
-      ``pack``/``h2d`` stay 0 there, ``device_step`` is the UNFENCED
-      residual (the in-flight device window from the loop's clock), and
-      ``host`` is publish/checkpoint work as before — phases still tile
-      the wall, by construction rather than by fencing;
-    - the PREFETCH lane records its own fetch/pack/h2d wall via
-      add_overlap() — called from the lane thread, so those sums live
-      under a lock (``overlap_s`` accounting) — and window_scalars()
-      reports them as the ``pipeline_*`` family: per-lane means,
-      ``pipeline_prefetch_s`` (lane busy per step),
-      ``pipeline_device_idle_s`` (the exposed loop wait), and
-      ``pipeline_overlap_ratio`` (share of lane work hidden behind the
-      device step).
+    - the LOOP lane (add()/step(), single writer: the learner loop
+      thread, which also calls window_scalars() at each metrics window;
+      the scrape thread reads the RESULT via MetricsLogger.latest(),
+      never this object):
+        fetch        the loop's wait for a prefetched batch (the
+                     exposed, un-hidden host time — the device-idle
+                     upper bound)
+        pack, h2d    0 here (the lane pays them)
+        device_step  the UNFENCED residual (the in-flight device window
+                     from the loop's clock)
+        host         publish dispatch / checkpoint work
+      The phases tile the wall by construction. Empty-wait retries
+      record fetch time with no closing step(), so a STARVED window's
+      fetch mean can exceed its wall mean — starvation is exactly when
+      that should read loud.
+    - the PREFETCH lane records its own fetch / pack / h2d wall via
+      add_lane() (h2d FENCED there, so it is the real transfer time,
+      not the dispatch time) — called from the lane thread, so those
+      sums live under a lock — and window_scalars() reports them as the
+      ``pipeline_*`` family: per-phase means, ``pipeline_prefetch_s``
+      (lane busy per step), ``pipeline_device_idle_s`` (the exposed loop
+      wait), and ``pipeline_overlap_ratio`` (share of lane work hidden
+      behind the device step).
     """
 
     PHASES = ("fetch", "pack", "h2d", "device_step", "host")
     LANE_PHASES = ("fetch", "pack", "h2d")
 
-    def __init__(self, overlap: bool = False):
-        self.overlap = overlap
+    def __init__(self):
         self._sums: Dict[str, float] = dict.fromkeys(self.PHASES, 0.0)
         self._wall = 0.0
         self._steps = 0
-        # Prefetch-lane sums (overlap mode only): written by the lane
-        # thread, read by the loop thread at window close — the one
-        # cross-thread surface, so it gets its own lock (a handful of
-        # acquisitions per step against a multi-ms step).
+        # Prefetch-lane sums: written by the lane thread, read by the
+        # loop thread at window close — the one cross-thread surface, so
+        # it gets its own lock (a handful of acquisitions per step
+        # against a multi-ms step).
         self._lane_lock = threading.Lock()
         self._lane_sums: Dict[str, float] = dict.fromkeys(self.LANE_PHASES, 0.0)
 
     def add(self, phase: str, seconds: float) -> None:
         self._sums[phase] += max(float(seconds), 0.0)
 
-    def add_overlap(self, phase: str, seconds: float) -> None:
-        """Prefetch-lane attribution (overlap mode): fetch/pack/h2d time
-        the lane paid for a batch, hidden behind the device step. Called
-        from the lane thread — the only writer of these sums."""
+    def add_lane(self, phase: str, seconds: float) -> None:
+        """Prefetch-lane attribution: fetch/pack/h2d time the lane paid
+        for a batch, hidden behind the device step. Called from the lane
+        thread — the only writer of these sums."""
         with self._lane_lock:
             self._lane_sums[phase] += max(float(seconds), 0.0)
 
@@ -130,30 +110,29 @@ class StepPhaseTimer:
         self._steps += 1
 
     def window_scalars(self, reset: bool = True) -> Dict[str, float]:
-        """Mean seconds per step for each phase over the window, the
-        mean iteration wall, and the fetch fraction (the watchdog's
-        starvation signal). Overlap mode adds the pipeline_* lane
-        scalars. Resets the window by default (the learner logs once
-        per metrics window, like its win_* accumulators)."""
+        """Mean seconds per step for each loop phase over the window,
+        the mean iteration wall, the fetch fraction (the watchdog's
+        starvation signal) and the pipeline_* lane scalars. Resets the
+        window by default (the learner logs once per metrics window,
+        like its win_* accumulators)."""
         n = max(self._steps, 1)
         out = {f"compute_phase_{p}_s": self._sums[p] / n for p in self.PHASES}
         out["compute_phase_wall_s"] = self._wall / n
         if self._wall > 0:
             out["compute_phase_fetch_frac"] = self._sums["fetch"] / self._wall
-        if self.overlap:
-            with self._lane_lock:
-                lane = dict(self._lane_sums)
-                if reset:
-                    self._lane_sums = dict.fromkeys(self.LANE_PHASES, 0.0)
-            lane_total = sum(lane.values())
-            exposed = self._sums["fetch"]  # loop wait for a prefetched batch
-            for p in self.LANE_PHASES:
-                out[f"pipeline_prefetch_{p}_s"] = lane[p] / n
-            out["pipeline_prefetch_s"] = lane_total / n
-            out["pipeline_device_idle_s"] = exposed / n
-            out["pipeline_overlap_ratio"] = (
-                max(0.0, min(1.0, 1.0 - exposed / lane_total)) if lane_total > 0 else 1.0
-            )
+        with self._lane_lock:
+            lane = dict(self._lane_sums)
+            if reset:
+                self._lane_sums = dict.fromkeys(self.LANE_PHASES, 0.0)
+        lane_total = sum(lane.values())
+        exposed = self._sums["fetch"]  # loop wait for a prefetched batch
+        for p in self.LANE_PHASES:
+            out[f"pipeline_prefetch_{p}_s"] = lane[p] / n
+        out["pipeline_prefetch_s"] = lane_total / n
+        out["pipeline_device_idle_s"] = exposed / n
+        out["pipeline_overlap_ratio"] = (
+            max(0.0, min(1.0, 1.0 - exposed / lane_total)) if lane_total > 0 else 1.0
+        )
         if reset:
             self._sums = dict.fromkeys(self.PHASES, 0.0)
             self._wall = 0.0
@@ -379,8 +358,8 @@ class ProfileCapture:
 
 
 class ComputeObserver:
-    """One learner's compute-observability bundle: phase timer (optional,
-    it costs the overlap), recompile sentinel, MFU accounting. Built by
+    """One learner's compute-observability bundle: phase timer (optional:
+    it fences the lane's puts), recompile sentinel, MFU accounting. Built by
     ObsRuntime.attach_compute(); everything funnels into window_scalars()
     on the learner's metrics cadence."""
 
@@ -390,9 +369,8 @@ class ComputeObserver:
         peak_flops: Optional[float],
         recorder=None,
         step_phases: bool = True,
-        overlap: bool = False,
     ):
-        self.timer = StepPhaseTimer(overlap=overlap) if step_phases else None
+        self.timer = StepPhaseTimer() if step_phases else None
         self.mfu = MfuAccountant(flops_per_step, peak_flops)
         self.sentinel: Optional[RecompileSentinel] = None
         self._recorder = recorder

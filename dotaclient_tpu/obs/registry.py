@@ -317,8 +317,8 @@ PREFIXES: Dict[str, str] = {
     # actor_batch_occupancy. Exported by vector actors AND the
     # inference service (same batcher, same distribution semantics).
     "actor_tick_rows_": "rows-per-fired-tick occupancy histogram (runtime/actor.py InferenceBatcher)",
-    # overlapped learner pipeline (--learner.prefetch, runtime/learner.py
-    # PrefetchLane + obs/compute.py StepPhaseTimer overlap mode):
+    # learner loop's lane accounting (runtime/learner.py PrefetchLane +
+    # obs/compute.py StepPhaseTimer):
     # pipeline_prefetch_s (prefetch-lane busy seconds per step:
     # fetch+pack+h2d, hidden behind the device step),
     # pipeline_prefetch_fetch_s / _pack_s / _h2d_s (the lane's own phase
@@ -326,9 +326,8 @@ PREFIXES: Dict[str, str] = {
     # pipeline_device_idle_s (the loop's exposed wait for a prefetched
     # batch — the device-idle-per-step upper bound),
     # pipeline_overlap_ratio (share of lane work hidden behind the
-    # device step; 1.0 = the host fully disappeared). Emitted only in
-    # pipelined mode — serial runs (--learner.prefetch false) emit
-    # nothing new. A family: the lane split can grow phases.
+    # device step; 1.0 = the host fully disappeared). A family: the
+    # lane split can grow phases.
     "pipeline_": "overlapped learner pipeline lane accounting (runtime/learner.py)",
     # parallel host feed scoreboard (runtime/staging.py _PackPool +
     # parallel/fused_io.py TransferRing, emitted by the learner loop

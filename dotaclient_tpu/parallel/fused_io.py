@@ -1,28 +1,27 @@
-"""Fused host→device batch transfer: 17 pytree leaves → 4 buffers.
+"""Fused host→device batch transfer: 17 pytree leaves → 1 buffer.
 
 Motivation (BENCH_TPU_20260730T0510.json, the one chip record of the
-learner loop: `split.device_put_s` was 11.97 ms of a 13.25 ms
-iteration on the 17-leaf tree path): the e2e bottleneck was the batch
-device_put, and a put pays a per-transfer overhead on top of its
-bytes. How the layouts compare on a directly attached chip is
-unmeasured (ROADMAP S2). The
-TPU mandate is "minimize host↔device transfers"; this module makes the
-transfer count 4 (one per dtype: f32 / bf16 / int32 / bool-as-uint8)
-regardless of how many leaves the batch grows.
+per-leaf loop: `split.device_put_s` was 11.97 ms of a 13.25 ms
+iteration on the 17-leaf tree path): a put pays a per-transfer overhead
+on top of its bytes. The TPU mandate is "minimize host↔device
+transfers"; this module makes the transfer count 1 regardless of how
+many leaves the batch grows.
 
 Mechanics:
 - Every TrainBatch leaf is batch-leading, so each flattens to
-  [B, cols] and a dtype group concatenates along axis 1 into one
-  [B, group_cols] buffer. That keeps the leading axis intact, so the
-  group buffers shard over dp EXACTLY like the tree did — this is not a
-  dp=1 special case.
-- Packing (host, one memcpy per leaf) runs on the learner's fetch path,
-  which already overlaps the in-flight device step; unpacking (slice +
-  reshape per leaf) runs INSIDE the jit train step, where XLA fuses it
-  into the first consumers for free.
+  [B, cols]; leaves of one dtype (f32 / bf16 / int32 / bool-as-uint8)
+  sit side by side in a segment, and a row is the byte-concatenation of
+  its segments in a fixed order, each padded to 4 bytes (RowLayout).
+  The batch crosses H2D as ONE [B, row_bytes] u8 array. The leading
+  axis stays intact, so the buffer shards over dp EXACTLY like the tree
+  did — this is not a dp=1 special case.
+- Packing runs on the staging thread, straight into leaf VIEWS of the
+  transfer buffer (alloc_transfer); unpacking (segment slice, a free
+  bitcast, slice + reshape per leaf) runs INSIDE the jit train step,
+  where XLA fuses it into the first consumers.
 - Sequence-parallel mode is the one exclusion: sp shards the obs TIME
-  axis, which column-flattening would destroy. The learner falls back
-  to the per-leaf tree path when sp is active (parallel/train_step.py).
+  axis, which column-flattening would destroy. The learner takes the
+  per-leaf tree path when sp is active (parallel/train_step.py).
 """
 
 from __future__ import annotations
@@ -36,24 +35,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Stable group keys. Bool packs as uint8 (XLA preds are byte-wide on the
-# wire anyway); everything else transfers in its native dtype.
-_GROUP_OF = {
-    np.dtype(np.float32): "f32",
-    np.dtype(np.int32): "i32",
-    np.dtype(np.bool_): "u8",
-    np.dtype(np.uint8): "u8",
-}
-
-
-def _group_key(dtype) -> str:
-    dtype = np.dtype(dtype)
-    if dtype in _GROUP_OF:
-        return _GROUP_OF[dtype]
-    # ml_dtypes.bfloat16 has no stable np.dtype singleton; match by name.
-    if dtype.name == "bfloat16":
-        return "bf16"
-    raise TypeError(f"fused_io: unsupported batch leaf dtype {dtype}")
+# Stable segment keys, by dtype NAME (ml_dtypes.bfloat16 has no stable
+# np.dtype singleton). Bool packs as uint8 (XLA preds are byte-wide on
+# the wire anyway); everything else transfers in its native dtype.
+_GROUP_OF = {"float32": "f32", "int32": "i32", "bool": "u8", "uint8": "u8", "bfloat16": "bf16"}
 
 
 _GROUP_DTYPES = {"f32": np.float32, "i32": np.int32, "u8": np.uint8, "bf16": "bfloat16"}
@@ -84,7 +69,9 @@ class RowLayout:
         self.slots: Dict[str, List[_LeafSlot]] = {}
         cols: Dict[str, int] = {}
         for i, (shape, dtype) in enumerate(specs):
-            key = _group_key(dtype)
+            key = _GROUP_OF.get(np.dtype(dtype).name)
+            if key is None:
+                raise TypeError(f"fused_io: unsupported batch leaf dtype {np.dtype(dtype)}")
             n = int(np.prod(shape[1:], dtype=np.int64)) if len(shape) > 1 else 1
             self.slots.setdefault(key, []).append(
                 _LeafSlot(i, tuple(shape), dtype, cols.get(key, 0), n)
@@ -119,7 +106,7 @@ class RowLayout:
 
     def views_into(self, buf: np.ndarray, rows: int) -> List[np.ndarray]:
         """Leaf views (flat order) into a [rows, row_bytes] u8 buffer —
-        the alloc_views_single body, layout-only. Bool leaves come back
+        FusedBatchIO.alloc_transfer's layout-only core. Bool leaves come back
         as bool views; every view is asserted to share memory with buf
         (a silent copy would disconnect the batch from the transfer
         bytes and ship zeros)."""
@@ -148,9 +135,10 @@ class RowLayout:
 
 
 class FusedBatchIO:
-    """Pack/unpack between a TrainBatch pytree and dtype-grouped
-    [B, cols] buffers. Built once per (config, mesh) from a template
-    batch; the layout is static, so the jit unpack is pure slicing."""
+    """Pack/unpack between a TrainBatch pytree and the one
+    [B, row_bytes] u8 transfer buffer of RowLayout. Built once per
+    (config, mesh) from a template batch; the layout is static, so the
+    jit unpack is pure slicing and bitcasts."""
 
     def __init__(self, template, mesh: Mesh):
         leaves, self.treedef = jax.tree.flatten(template)
@@ -163,116 +151,34 @@ class FusedBatchIO:
         # from the same template specs, so layout_crc pins parity).
         self.layout = RowLayout([(tuple(l.shape), l.dtype) for l in leaves])
         self.slots = self.layout.slots
-        cols = self.layout.group_cols
-        self.group_cols = cols
-        # pack() accepts exactly this many rows; defaults to the template
-        # (global) batch. Multihost learners set it to their per-process
-        # share so a mis-sized batch still fails AT THE PACK BOUNDARY
-        # with a named count, not downstream as an opaque jit/assembly
-        # shape error.
-        self.local_rows = B
-        dp = "dp" if "dp" in mesh.axis_names else None
-        self.shardings = {k: NamedSharding(mesh, P(dp, None)) for k in cols}
-        # --- single-buffer layout (opt-in transfer mode): each batch row
-        # is the byte-concatenation of its dtype-group segments in a
-        # fixed order, every segment padded to 4 bytes so each start is
-        # aligned for its dtype. The whole batch then crosses H2D as ONE
-        # [B, row_bytes] u8 array (one per-transfer overhead, not
-        # four); rows stay intact so dp sharding is identical to the
-        # group mode.
+        self.group_cols = self.layout.group_cols
         self.seg_off = self.layout.seg_off
         self.row_bytes = self.layout.row_bytes
-        self.single_sharding = NamedSharding(mesh, P(dp, None))
-        # When True (set by build_single_train_step), alloc_transfer /
-        # pack_transfer / transfer_shardings produce the one-buffer
-        # layout; the staging buffer and learner dispatch through those
-        # so they never need to know which mode the step was built for.
-        self.single_mode = False
-
-    # -------------------------------------------------- mode-dispatch API
-
-    def alloc_transfer(self):
-        """(payload, batch-of-views) in whichever layout the train step
-        was built for — groups dict (default) or single u8 buffer."""
-        return self.alloc_views_single() if self.single_mode else self.alloc_views()
-
-    def pack_transfer(self, batch):
-        """batch → transfer payload (dense-staging fallback path)."""
-        if not self.single_mode:
-            return self.pack(batch)
-        # Same pack-boundary validation contract as pack(): a mis-sized
-        # or structurally different batch must fail HERE with a named
-        # error, not silently truncate the leaf zip or broadcast one row
-        # across the buffer. BatchLayoutError marks it as a persistent
-        # config mismatch — staging crashes its consumer loudly instead
-        # of logging dropped_bad forever (ops/batch.py).
-        from dotaclient_tpu.ops.batch import BatchLayoutError
-
-        leaves, treedef = jax.tree.flatten(batch)
-        if treedef != self.treedef:
-            raise BatchLayoutError(
-                f"single pack: batch structure {treedef} != template {self.treedef}"
-            )
-        rows = np.asarray(leaves[0]).shape[0]
-        if rows != self.local_rows:
-            raise BatchLayoutError(
-                f"single pack: got {rows} rows, expected {self.local_rows} "
-                f"(template batch {self.batch}; multihost learners set "
-                f"local_rows to their per-process share)"
-            )
-        buf, views = self.alloc_views_single()
-        for v, ref in zip(jax.tree.leaves(views), leaves):
-            v[...] = ref
-        return buf
-
-    def transfer_shardings(self):
-        return self.single_sharding if self.single_mode else self.shardings
+        # pack_transfer() accepts exactly this many rows; defaults to the
+        # template (global) batch. Multihost learners set it to their
+        # per-process share so a mis-sized batch still fails AT THE PACK
+        # BOUNDARY with a named count, not downstream as an opaque
+        # jit/assembly shape error.
+        self.local_rows = B
+        # Rows stay intact in the buffer, so it shards over dp as the
+        # tree's leading axis did.
+        dp = "dp" if "dp" in mesh.axis_names else None
+        self.sharding = NamedSharding(mesh, P(dp, None))
 
     # ----------------------------------------------------------- host side
 
-    def alloc_views(self):
-        """(groups, batch): zeroed group buffers + a TrainBatch whose
-        leaves are row-strided VIEWS into them.
+    def alloc_transfer(self):
+        """(buf, batch): ONE zeroed [rows, row_bytes] u8 transfer buffer +
+        a TrainBatch whose leaves are row-strided VIEWS into it.
 
         The staging packer fills the views (numpy fallback transparently;
-        the C packer via per-leaf row strides), after which `groups` is
-        already the device-transfer layout — pack() and its full-batch
-        memcpy (~0.7 ms at flagship shapes, on the 1-core host's critical
-        path) never run. Initialization contract matches
-        zeros_train_batch: all-zero leaves, NOOP-legal action-mask
-        padding rows."""
-        from dotaclient_tpu.env import featurizer as F
-
-        rows = self.local_rows
-        groups = {
-            key: np.zeros((rows, self.group_cols[key]), dtype=_GROUP_DTYPES[key])
-            for key in self.group_cols
-        }
-        leaves: List[Any] = [None] * sum(len(s) for s in self.slots.values())
-        for key, slots in self.slots.items():
-            buf = groups[key]
-            for s in slots:
-                v = buf[:, s.start : s.start + s.cols].reshape((rows,) + s.shape[1:])
-                if np.dtype(s.dtype) == np.bool_:
-                    v = v.view(np.bool_)
-                # Splitting the trailing axis of a row-strided column
-                # block is always expressible as a view; a silent copy
-                # here would disconnect the batch from the transfer
-                # buffers and ship zeros to the device.
-                if not np.may_share_memory(v, buf):
-                    raise AssertionError("fused_io.alloc_views: leaf view detached")
-                leaves[s.index] = v
-        batch = jax.tree.unflatten(self.treedef, leaves)
-        batch.obs.action_mask[:] = F.zeros_observation().action_mask
-        return groups, batch
-
-    def alloc_views_single(self):
-        """(buf, batch): ONE zeroed [rows, row_bytes] u8 transfer buffer +
-        a TrainBatch of leaf views into it (same contract as alloc_views;
-        the packer — C via row strides, or numpy — fills the views and
-        `buf` ships as a single device_put). Leaf views sit at their
-        group segment's byte offset; within a row every leaf block is
-        contiguous, so only the row-to-row stride differs from dense."""
+        the C packer via per-leaf row strides), after which `buf` is
+        already the device-transfer layout and ships as a single
+        device_put — no regroup copy runs. Leaf views sit at their
+        segment's byte offset; within a row every leaf block is
+        contiguous, so only the row-to-row stride differs from dense.
+        Initialization contract matches zeros_train_batch: all-zero
+        leaves, NOOP-legal action-mask padding rows."""
         from dotaclient_tpu.env import featurizer as F
 
         rows = self.local_rows
@@ -282,16 +188,25 @@ class FusedBatchIO:
         batch.obs.action_mask[:] = F.zeros_observation().action_mask
         return buf, batch
 
-    def pack(self, batch) -> Dict[str, np.ndarray]:
-        """TrainBatch (numpy leaves) → {group: [rows, cols] contiguous}.
-        One memcpy per leaf; runs on the learner fetch path, overlapped
-        with the in-flight device step. Rows come from the INPUT, not the
-        template: in multihost mode each process packs its LOCAL share
-        (global_batch / process_count rows) and the learner stitches the
-        shares into the global array (runtime/learner.py _fetch_next)."""
+    def pack_transfer(self, batch):
+        """Dense batch → transfer buffer: the fallback the learner's
+        fetch takes when staging hands over a batch it did not pack into
+        views. Rows come from `local_rows`, not the template: in
+        multihost mode each process packs its LOCAL share and the learner
+        stitches the shares into the global array (_fetch_next).
+
+        A mis-sized or structurally different batch must fail HERE with
+        a named error, not silently truncate the leaf zip or broadcast
+        one row across the buffer. BatchLayoutError marks it as a
+        persistent config mismatch — staging crashes its consumer loudly
+        instead of logging dropped_bad forever (ops/batch.py)."""
         from dotaclient_tpu.ops.batch import BatchLayoutError
 
-        leaves = jax.tree.leaves(batch)
+        leaves, treedef = jax.tree.flatten(batch)
+        if treedef != self.treedef:
+            raise BatchLayoutError(
+                f"fused pack: batch structure {treedef} != template {self.treedef}"
+            )
         rows = np.asarray(leaves[0]).shape[0]
         if rows != self.local_rows:
             raise BatchLayoutError(
@@ -299,47 +214,26 @@ class FusedBatchIO:
                 f"(template batch {self.batch}; multihost learners set "
                 f"local_rows to their per-process share)"
             )
-        out = {}
-        for key, slots in self.slots.items():
-            buf = np.empty((rows, self.group_cols[key]), dtype=_GROUP_DTYPES[key])
-            for s in slots:
-                leaf = np.asarray(leaves[s.index])
-                buf[:, s.start : s.start + s.cols] = leaf.reshape(rows, -1).astype(
-                    buf.dtype, copy=False
-                )
-            out[key] = buf
-        return out
+        buf, views = self.alloc_transfer()
+        for v, ref in zip(jax.tree.leaves(views), leaves):
+            v[...] = ref
+        return buf
+
+    def make_ring(self, depth: int) -> "TransferRing":
+        """A ring of `depth` preallocated transfer buffers. See
+        TransferRing."""
+        return TransferRing(self, depth)
 
     # --------------------------------------------------------- device side
 
-    def unpack(self, groups: Dict[str, jnp.ndarray]):  # graftlint: jit-region
-        """{group: [B, cols]} → TrainBatch, inside jit. Slices + reshapes
-        only — XLA fuses them into the first consumers."""
-        leaves: List[Any] = [None] * sum(len(s) for s in self.slots.values())
-        for key, slots in self.slots.items():
-            buf = groups[key]
-            for s in slots:
-                x = jax.lax.slice_in_dim(buf, s.start, s.start + s.cols, axis=1)
-                x = x.reshape(s.shape)
-                if np.dtype(s.dtype) == np.bool_:
-                    x = x != 0
-                leaves[s.index] = x
-        return jax.tree.unflatten(self.treedef, leaves)
-
-    # ------------------------------------------------------- transfer ring
-
-    def make_ring(self, depth: int) -> "TransferRing":
-        """A ring of `depth` preallocated transfer-buffer sets in this
-        io's current mode (groups or single). See TransferRing."""
-        return TransferRing(self, depth)
-
     def unpack_single(self, buf: jnp.ndarray):  # graftlint: jit-region
-        """[B, row_bytes] u8 → TrainBatch, inside jit: slice each group's
-        byte segment, bitcast u8[..., k] to the group dtype, then the
-        same per-leaf slicing as unpack. Bitcasts are free on device
-        (layout reinterpretation; both sides little-endian)."""
+        """[B, row_bytes] u8 → TrainBatch, inside jit: slice each
+        segment's bytes, bitcast u8[..., k] to the segment dtype, then
+        slice + reshape per leaf — XLA fuses them into the first
+        consumers. Bitcasts are free on device (layout reinterpretation;
+        both sides little-endian)."""
         B = buf.shape[0]
-        leaves: List[Any] = [None] * sum(len(s) for s in self.slots.values())
+        leaves: List[Any] = [None] * self.layout.n_leaves
         for key, slots in self.slots.items():
             gdt = np.dtype(_GROUP_DTYPES[key])
             k = gdt.itemsize
@@ -362,7 +256,7 @@ class RingSlot:
     """One preallocated transfer-buffer set with explicit ownership.
 
     Lifecycle (TransferRing docstring): acquire() hands the slot to the
-    packer freshly RE-ZEROED to the alloc_views contract (all-zero
+    packer freshly RE-ZEROED to the alloc_transfer contract (all-zero
     leaves + NOOP-legal action-mask padding — a reused buffer must not
     leak the previous batch into this batch's padding); release() hands
     it back to the free queue. release() is idempotent — a double
@@ -374,23 +268,17 @@ class RingSlot:
     def __init__(self, ring: "TransferRing", index: int, payload, batch):
         self._ring = ring
         self.index = index
-        self.payload = payload  # groups dict, or the single u8 buffer
+        self.payload = payload  # the [rows, row_bytes] u8 transfer buffer
         self.batch = batch  # TrainBatch of leaf VIEWS into payload
         self._held = False
 
     def _reset(self) -> None:
-        """Zero the backing buffer(s) and restore the NOOP action-mask
+        """Zero the backing buffer and restore the NOOP action-mask
         padding — exactly zeros_train_batch's initialization contract,
         so a reused slot packs bitwise like a fresh allocation."""
         from dotaclient_tpu.env import featurizer as F
 
-        bufs = (
-            self.payload.values()
-            if isinstance(self.payload, dict)
-            else (self.payload,)
-        )
-        for arr in bufs:
-            arr[...] = 0
+        self.payload[...] = 0
         self.batch.obs.action_mask[:] = F.zeros_observation().action_mask
 
     def release(self) -> None:
@@ -406,7 +294,7 @@ class RingSlot:
 
 
 class TransferRing:
-    """Ring of preallocated transfer-buffer sets with explicit ownership
+    """Ring of preallocated transfer buffers with explicit ownership
     handoff: free → packing (acquire) → ready/in-transfer (staging ready
     queue → learner fetch → device_put) → free (release).
 

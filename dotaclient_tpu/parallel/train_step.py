@@ -344,23 +344,36 @@ def build_train_step(cfg: LearnerConfig, mesh):
     return train_step, state_shardings, batch_shardings
 
 
-def _build_fused(cfg: LearnerConfig, mesh, single: bool):
-    """Shared body of the two fused-transfer builders: validated core,
-    staging-matching template, one FusedBatchIO, one jit — only the
-    transfer layout (groups dict vs single u8 buffer) differs."""
+def build_single_train_step(cfg: LearnerConfig, mesh):
+    """Returns (fused_step, state_shardings, io: FusedBatchIO).
+
+    Same compiled math as build_train_step, but the batch crosses the
+    host→device boundary as ONE [B, row_bytes] u8 buffer instead of 17
+    pytree leaves, so the per-transfer overhead is paid once
+    (parallel/fused_io.py). Callers move a host TrainBatch with
+    `jax.device_put(io.pack_transfer(batch), io.sharding)` (staging packs
+    straight into `io.alloc_transfer()` views instead) and call
+    `fused_step(state, buf)`; the unpack (byte-segment slices + free
+    bitcasts) runs inside the jit and fuses into the first consumers.
+
+    Refused in sequence-parallel mode (column-flattening would destroy
+    the sp time-axis sharding) and with the replay reservoir (the per-row
+    behavior_staleness stamp is not part of the row layout): the Learner
+    takes build_train_step there, and a caller that gets the condition
+    wrong is told so here.
+    """
     step_fn, state_shardings, use_sp, _ = _build_core(cfg, mesh)
     if use_sp:
         raise ValueError(
-            f"{'single-buffer' if single else 'fused'} H2D transfer is "
-            f"incompatible with sequence parallelism (tf_sp_axis set); "
-            f"use build_train_step"
+            "fused H2D transfer is incompatible with sequence parallelism "
+            "(tf_sp_axis set); use build_train_step"
         )
     if cfg.replay.enabled:
         raise ValueError(
             "fused H2D transfer is incompatible with the replay reservoir: "
             "the per-row behavior_staleness stamp is not part of the "
-            "dtype-grouped transfer layout; use build_train_step (the "
-            "Learner falls back to the tree path automatically)"
+            "transfer layout; use build_train_step (the Learner takes the "
+            "tree path automatically)"
         )
     from dotaclient_tpu.parallel.fused_io import FusedBatchIO
     from dotaclient_tpu.runtime.staging import cast_obs_to_compute_dtype
@@ -371,48 +384,19 @@ def _build_fused(cfg: LearnerConfig, mesh, single: bool):
     # the compute dtype when stage_obs_compute_dtype is on.
     template = cast_obs_to_compute_dtype(cfg, jax.tree.map(np.asarray, _batch_template(cfg)))
     io = FusedBatchIO(template, mesh)
-    io.single_mode = single
-    unpack = io.unpack_single if single else io.unpack
 
     def fused_fn(state: TrainState, payload):
         with jax.named_scope("unpack"):
-            batch = unpack(payload)
+            batch = io.unpack_single(payload)
         return step_fn(state, batch)
 
     step = jax.jit(
         fused_fn,
-        in_shardings=(state_shardings, io.transfer_shardings()),
+        in_shardings=(state_shardings, io.sharding),
         out_shardings=(state_shardings, mesh_lib.replicated(mesh)),
         donate_argnums=(0,),
     )
     return step, state_shardings, io
-
-
-def build_fused_train_step(cfg: LearnerConfig, mesh):
-    """Returns (fused_step, state_shardings, io: FusedBatchIO).
-
-    Same compiled math as build_train_step, but the batch crosses the
-    host→device boundary as FOUR dtype-grouped [B, cols] buffers instead
-    of 17 pytree leaves, so the per-transfer overhead is paid 4 times,
-    not 17 (parallel/fused_io.py). Callers move a host
-    TrainBatch with `jax.device_put(io.pack(batch), io.shardings)` and
-    call `fused_step(state, groups)`; the unpack runs inside the jit and
-    fuses into the first consumers. Refused in sequence-parallel mode
-    (column-flattening would destroy the sp time-axis sharding) — use
-    the tree path there.
-    """
-    return _build_fused(cfg, mesh, single=False)
-
-
-def build_single_train_step(cfg: LearnerConfig, mesh):
-    """Returns (single_step, state_shardings, io: FusedBatchIO) — the
-    fused train step with the batch crossing H2D as ONE [B, row_bytes]
-    u8 buffer (FusedBatchIO.unpack_single: byte-segment slices + free
-    bitcasts inside the jit). Collapses the transfer COUNT from 4 to 1
-    (bench.py's transfer_layout_ab is the standing A/B; no chip record
-    of it exists yet — ROADMAP S2). Same refusal under sequence
-    parallelism as the grouped mode."""
-    return _build_fused(cfg, mesh, single=True)
 
 
 def jit_cache_size(jitted) -> int:

@@ -16,16 +16,22 @@ The python-side `version` counter mirrors state.step without forcing a
 device sync every iteration; it is the version actors stamp on their
 rollouts and the learner's staleness filter reads.
 
-Pipelining (--learner.prefetch, default ON — the ISSUE-15 overlapped
-loop): the loop never blocks on the device except where semantics
-require it —
+The feed is two boxes, lane -> loop, and the loop never blocks on the
+device except where semantics require it:
 - a dedicated PREFETCH LANE thread runs the whole host side of batch
   N+1 — staging pop, pack wait, device_put dispatch, transfer retire,
   ring-lease release — WHILE the device executes train step N, so the
-  loop thread's per-iteration host cost collapses to one queue pop plus
-  the async train-step dispatch (double buffering with a real second
-  lane, not just jax async dispatch; tests/test_pipeline.py holds the
-  bitwise-params parity proof against the serial loop);
+  loop thread's per-iteration host cost is one queue pop plus the async
+  train-step dispatch (double buffering with a real second lane, not
+  just jax async dispatch). The lane is the one staging consumer and
+  pops FIFO, so the loop trains the batches in arrival order
+  (tests/test_pipeline.py steps the same batches by hand and holds the
+  parameters bitwise);
+- a batch crosses to the device as ONE [B, row_bytes] u8 buffer
+  (parallel/fused_io.py), unpacked inside the compiled step. Where that
+  layout cannot apply — a sequence-parallel mesh, the replay reservoir —
+  the Learner takes the per-leaf tree (build_train_step), chosen from
+  the mesh and the config; same compiled math;
 - metrics are device_get only every `metrics_every` steps (each fetch is
   a full device sync);
 - weight publishes dispatch ONE on-device flatten (ParamFlattener) and
@@ -33,10 +39,7 @@ require it —
   the blocking single-transfer host read + serialize + broker I/O with
   latest-wins coalescing. Stream ordering keeps this safe against the
   train step's state donation (flatten is dispatched first, on the loop
-  thread — the lane never touches the state);
-- `--learner.prefetch false` restores the serial fetch-after-step loop
-  byte-for-byte (no lane thread, no pipeline_* scalars — the rollback
-  path, MIGRATION item 15).
+  thread — the lane never touches the state).
 """
 
 from __future__ import annotations
@@ -57,8 +60,10 @@ from dotaclient_tpu.obs.spans import span, timeline
 from dotaclient_tpu.parallel import mesh as mesh_lib
 from dotaclient_tpu.parallel.train_step import (
     TrainState,
+    build_single_train_step,
     build_train_step,
     init_train_state,
+    is_sequence_parallel,
 )
 from dotaclient_tpu.runtime.metrics import MetricsLogger
 from dotaclient_tpu.runtime.staging import StagingBuffer
@@ -335,33 +340,29 @@ class _LaneItem(NamedTuple):
 
 
 class PrefetchLane:
-    """The dedicated prefetch stage of the pipelined learner loop
-    (--learner.prefetch): runs the WHOLE host side of batch N+1 —
-    staging pop, pack wait, device_put dispatch, transfer retire, ring
-    lease release — on its own thread while the loop thread keeps the
-    device busy with step N, handing finished batches over a bounded
-    queue (depth = --learner.prefetch_depth; 1 = classic double
-    buffering).
+    """The prefetch stage of the learner loop: runs the WHOLE host side
+    of batch N+1 — staging pop, pack wait, device_put dispatch, transfer
+    retire, ring lease release — on its own thread while the loop
+    thread keeps the device busy with step N, handing finished batches
+    over a queue of one (classic double buffering).
 
-    Ownership rules carried over from the serial loop, unchanged:
-    - the lane is the ONE staging consumer, popping FIFO — batch order
-      is identical to the serial loop, which is why the pipelined
-      params are BITWISE equal to the serial params over the same
-      frame schedule (tests/test_pipeline.py);
+    Ownership rules:
+    - the lane is the ONE staging consumer, popping FIFO, so the loop
+      trains the batches in arrival order and its parameters are
+      BITWISE those of the same batches stepped by hand
+      (tests/test_pipeline.py);
     - a ring lease is released only after ITS device_put retired
-      (inside Learner._fetch_next — the PR-11 donation-safety rule;
-      the lane moves the release off the loop thread, it never moves
-      it before the retire);
+      (inside Learner._fetch_next — the donation-safety rule; the lane
+      keeps the release off the loop thread, never before the retire);
     - `holding()` makes a popped-but-untrained batch visible to
-      staging.drained() as the prefetch station, so the PR-7 SIGTERM
+      staging.drained() as the prefetch station, so the SIGTERM
       zero-loss contract extends through the lane: a drain trains the
       in-flight prefetched batch out, never drops it.
 
     Budget (`limit` = the run's num_steps): the lane never fetches more
     batches than the loop will train, so a finite phased run
     (train → eval → train, scripts/train_north_star.py) cannot eat and
-    discard a trailing batch — exactly the serial loop's
-    no-trailing-prefetch rule. Empty waits ("idle" items) consume no
+    discard a trailing batch. Empty waits ("idle" items) consume no
     budget. Fetch errors surface on the loop thread via "error" items
     (the staging _check_fatal fast-failure contract survives the lane).
     """
@@ -369,7 +370,6 @@ class PrefetchLane:
     def __init__(
         self,
         fetch_fn,
-        depth: int = 1,
         limit: Optional[int] = None,
         drain: Optional[threading.Event] = None,
         abort: Optional[threading.Event] = None,
@@ -377,7 +377,8 @@ class PrefetchLane:
         stop_event: Optional[threading.Event] = None,
     ):
         self._fetch = fetch_fn  # () -> (batch, env_steps, wait_s, put_s, trace)
-        self._out: "queue.Queue[_LaneItem]" = queue.Queue(maxsize=max(int(depth), 1))
+        # One batch ahead and no more: every queued batch ages a version.
+        self._out: "queue.Queue[_LaneItem]" = queue.Queue(maxsize=1)
         self._limit = limit
         self._drain = drain
         self._abort = abort
@@ -487,41 +488,25 @@ class Learner:
         self.cfg = cfg
         self.broker = broker
         self.mesh = mesh if mesh is not None else mesh_lib.make_mesh(cfg.mesh_shape)
-        # Overlapped step loop (--learner.prefetch, PrefetchLane): ON by
-        # default; False restores the serial fetch-after-step loop
-        # byte-for-byte (no lane thread, no pipeline_* scalars, no
-        # staging probe — the flag-off inertness contract).
-        pipeline_cfg = getattr(cfg, "learner", None)
-        self._prefetch_enabled = bool(
-            pipeline_cfg is not None and pipeline_cfg.prefetch
-        )
-        self._prefetch_depth = (
-            max(int(pipeline_cfg.prefetch_depth), 1) if pipeline_cfg is not None else 1
-        )
-        # The live lane of the CURRENT run() (None between runs and in
-        # serial mode); staging's prefetch drained() station reads it
-        # through _prefetch_holding.
+        # The live lane of the CURRENT run() (None between runs);
+        # staging's prefetch drained() station reads it through
+        # _prefetch_holding.
         self._prefetch_lane: Optional[PrefetchLane] = None
-        # Fused 4-buffer H2D path when enabled and not sequence-parallel
-        # (fused_io.py); per-leaf tree path otherwise. Same compiled math.
-        # The replay reservoir also forces the tree path: the per-row
-        # behavior_staleness stamp is not part of the fused transfer
-        # layout, and replay targets data-starved regimes where the H2D
-        # transfer-count overhead is not the bottleneck anyway.
+        # One fused u8 buffer per batch (fused_io.py), unless the layout
+        # cannot apply: sp shards the obs TIME axis, which the row layout
+        # would destroy, and the replay reservoir's per-row
+        # behavior_staleness stamp is not part of it (replay targets
+        # data-starved regimes where the transfer count is not the
+        # bottleneck anyway). There the batch crosses as its per-leaf
+        # tree. Same compiled math.
         self.fused_io = None
-        from dotaclient_tpu.parallel.train_step import is_sequence_parallel
-
-        if cfg.fused_h2d and not is_sequence_parallel(cfg, self.mesh) and not cfg.replay.enabled:
-            from dotaclient_tpu.parallel.train_step import (
-                build_fused_train_step,
-                build_single_train_step,
-            )
-
-            build = build_single_train_step if cfg.fused_single_h2d else build_fused_train_step
-            self.train_step, self.state_shardings, self.fused_io = build(cfg, self.mesh)
-            self.batch_sharding = None
-        else:
+        self.batch_sharding = None
+        if is_sequence_parallel(cfg, self.mesh) or cfg.replay.enabled:
             self.train_step, self.state_shardings, self.batch_sharding = build_train_step(
+                cfg, self.mesh
+            )
+        else:
+            self.train_step, self.state_shardings, self.fused_io = build_single_train_step(
                 cfg, self.mesh
             )
         self.version = 0
@@ -598,10 +583,6 @@ class Learner:
             staging_cfg.batch_size = cfg.batch_size // self._n_proc
             if self.fused_io is not None:
                 self.fused_io.local_rows = staging_cfg.batch_size
-        # fused mode: staging packs straight into the dtype-grouped
-        # transfer buffers (leaf views), so _fetch_next ships `groups`
-        # without the io.pack regroup copy (~0.7 ms/batch of host memcpy
-        # at flagship shapes — critical-path time on a 1-core host).
         # Observability (dotaclient_tpu/obs/, --obs.*): None when off —
         # every obs touchpoint below is a single `is not None` check, so
         # the disabled hot path is unchanged.
@@ -619,12 +600,10 @@ class Learner:
             tracer=self.obs.tracer if self.obs is not None else None,
             recorder=self.obs.recorder if self.obs is not None else None,
         )
-        if self._prefetch_enabled:
-            # The prefetch station of the zero-loss drain contract: a
-            # batch the lane popped but the loop has not trained is
-            # visible to staging.drained() (PR-7, one station further
-            # downstream). Serial mode attaches nothing.
-            self.staging.attach_prefetch_probe(self._prefetch_holding)
+        # The prefetch station of the zero-loss drain contract: a batch
+        # the lane popped but the loop has not trained is visible to
+        # staging.drained().
+        self.staging.attach_prefetch_probe(self._prefetch_holding)
         self.flattener = ParamFlattener(state.params)
         # Full-state mode: every fanned-out version is persisted as a
         # high-water mark (tiny atomic file, publisher thread) so a
@@ -654,20 +633,16 @@ class Learner:
             # the recompile sentinel (aval-signature hash + compile wall
             # + shape-diff to the flight recorder), MFU accounting gets
             # the analytic FLOPs model against the platform peak table,
-            # and — when cfg.obs.step_phases — the loop runs phase-fenced
-            # (run() below). With obs off, self.train_step stays the raw
-            # jit object: byte-identical hot path, asserted in test_obs.
+            # and — when cfg.obs.step_phases — the phase timer: the lane
+            # records its fetch/pack/h2d (fenced there, hidden behind the
+            # device step), the loop its take-wait/residual/host; no
+            # per-step fence on the loop. With obs off, self.train_step
+            # stays the raw jit object: byte-identical hot path, asserted
+            # in test_obs.
             from dotaclient_tpu.ops.flops import aggregate_peak_flops, train_step_flops
 
             compute = self.obs.attach_compute(
-                train_step_flops(cfg),
-                aggregate_peak_flops(jax.devices()),
-                # Pipelined loop: the phase timer runs in OVERLAP mode —
-                # fetch/pack/h2d recorded on the prefetch lane (fenced
-                # there, hidden behind the device step), loop lane
-                # reports take-wait/residual/host, pipeline_* scalars
-                # carry the overlap accounting. No per-step fence.
-                overlap=self._prefetch_enabled,
+                train_step_flops(cfg), aggregate_peak_flops(jax.devices())
             )
             self.train_step = compute.wrap_train_step(self.train_step)
             # (The liveness watchdog attaches at the END of __init__,
@@ -1077,36 +1052,30 @@ class Learner:
                 self.flattener.flatten_on_device(self.state.params), self.version
             )
 
-    def _fetch_next(self, batch_timeout: float, lane: bool = False, cancel=None):
+    def _fetch_next(self, batch_timeout: float, cancel=None):
         """Pull one batch off staging and device_put it (dp-sharded).
 
-        Serial loop: called AFTER the current step has been dispatched,
-        so the host wait and the transfer overlap the running device
-        step. Pipelined loop (`lane=True`): called on the PrefetchLane
-        thread — the same work, now FULLY off the loop thread, with
-        phase attribution routed to the timer's overlap-lane sums
-        (add_overlap) and the staging wait cancellable at lane teardown.
+        Called on the PrefetchLane thread, so the host wait and the
+        transfer overlap the running device step; phase attribution goes
+        to the timer's lane sums (add_lane) and the staging wait is
+        cancellable at lane teardown.
         Returns (batch_dev, env_steps, wait_s, put_s, trace) or
         (None, 0, w, 0.0, None); `trace` is the batch's obs trace refs
         (staging.last_batch_trace) with the h2d hop already recorded —
         at DISPATCH time, like every hop this loop records (the loop
-        never syncs the device per step). In fused mode the pack
+        never syncs the device per step). On the fused path the pack
         happened on the STAGING thread (straight into the transfer
-        buffers), so wait_s is queue wait; only the dense-staging
-        fallback pays io.pack here (still charged to wait_s, never to
-        put_s — that bucket is the pure H2D transfer).
+        buffer), so wait_s is queue wait; only the dense-staging
+        fallback pays io.pack_transfer here (still charged to wait_s,
+        never to put_s — that bucket is the pure H2D transfer).
         """
         timer = self.obs.compute.timer if self.obs is not None and self.obs.compute else None
-        add = None
-        if timer is not None:
-            # Overlap mode attributes fetch/pack/h2d to the prefetch
-            # lane (its own fenced wall, hidden behind the device step);
-            # the serial timer keeps the loop-lane single-writer path.
-            add = timer.add_overlap if lane else timer.add
+        # The lane's own fenced wall, hidden behind the device step.
+        add = timer.add_lane if timer is not None else None
         step = self._fetch_step
         t0 = time.perf_counter()
         with timeline("lane.wait_batch", step=step):
-            batch, groups = self.staging.get_batch_groups(timeout=batch_timeout, cancel=cancel)
+            batch, buf = self.staging.get_batch_groups(timeout=batch_timeout, cancel=cancel)
         t1 = time.perf_counter()
         if add is not None:
             add("fetch", t1 - t0)
@@ -1114,41 +1083,36 @@ class Learner:
             return None, 0, t1 - t0, 0.0, None
         self._fetch_step = step + 1
         trace = self.staging.last_batch_trace
-        # Ring lease (--staging.pack_workers > 1, fused mode): the batch
+        # Ring lease (--staging.pack_workers > 1, fused path): the batch
         # lives in a TransferRing slot that must go back to the packers
         # once — and only once — its device_put has retired. None on the
         # classic path.
         lease = self.staging.last_batch_lease
         env_steps = int(np.sum(batch.mask))
         if self.fused_io is not None:
-            # Staging packed straight into the transfer buffers (groups
-            # non-None); the io.pack fallback only runs if a caller wired
-            # a dense staging buffer to a fused learner. Host memcpy is
-            # charged to the WAIT bucket, not the put bucket:
+            # Staging packed straight into the transfer buffer (buf
+            # non-None); the pack_transfer fallback only runs if a caller
+            # wired a dense staging buffer to a fused learner. Host memcpy
+            # is charged to the WAIT bucket, not the put bucket:
             # time_device_put_s exists to attribute the H2D transfer
             # specifically (the on-silicon bottleneck).
-            if groups is None:
-                groups = self.fused_io.pack_transfer(batch)
+            if buf is None:
+                buf = self.fused_io.pack_transfer(batch)
             t2 = time.perf_counter()
             if add is not None:
                 add("pack", t2 - t1)
-            shardings = self.fused_io.transfer_shardings()
+            sharding = self.fused_io.sharding
             with timeline("lane.device_put", step=step):
                 if self._n_proc > 1:
                     # Each process contributes its local rows; the result is
-                    # ONE global array per buffer whose dp shards live where
-                    # each host put them — no cross-host data movement.
-                    batch_dev = jax.tree.map(
-                        lambda arr, sh: jax.make_array_from_process_local_data(sh, arr),
-                        groups,
-                        shardings,
-                    )
+                    # ONE global array whose dp shards live where each host
+                    # put them — no cross-host data movement.
+                    batch_dev = jax.make_array_from_process_local_data(sharding, buf)
                 else:
-                    batch_dev = jax.device_put(groups, shardings)
+                    batch_dev = jax.device_put(buf, sharding)
             if add is not None:
                 # Fence: the phase is the real transfer, not its dispatch.
-                # On the prefetch lane the fence blocks only the lane —
-                # attribution costs no overlap there.
+                # It blocks only the lane — attribution costs no overlap.
                 jax.block_until_ready(batch_dev)
                 add("h2d", time.perf_counter() - t2)
             if lease is not None:
@@ -1157,10 +1121,9 @@ class Learner:
                 # released slot is re-zeroed and repacked immediately —
                 # an in-flight transfer would ship the next batch's bytes
                 # (or zeros) to the device. The block waits on the H2D
-                # stream only, and this fetch already overlaps the
-                # in-flight device step, so the wait hides behind compute
-                # (the ParamFlattener stream-ordering argument, applied
-                # on the host side).
+                # stream only, on the lane, so the wait hides behind
+                # compute (the ParamFlattener stream-ordering argument,
+                # applied on the host side).
                 with span("lane.retire", step=step):
                     jax.block_until_ready(batch_dev)
                     lease.release()
@@ -1201,10 +1164,8 @@ class Learner:
         (checked between steps) — for soak/bench drivers with a time
         budget rather than a step budget.
 
-        Loop shape: --learner.prefetch (default ON) runs the pipelined
-        loop — a PrefetchLane thread stages batch N+1 while the device
-        executes step N (_run_pipelined); prefetch=False runs the
-        serial fetch-after-step loop byte-for-byte (_run_serial).
+        Loop shape: a PrefetchLane thread stages batch N+1 while the
+        device executes step N (_loop).
         """
         self.staging.start()
         self.publisher.start()
@@ -1235,14 +1196,9 @@ class Learner:
                     return batch_timeout
                 return max(0.05, min(batch_timeout, deadline - time.monotonic()))
 
-            if self._prefetch_enabled:
-                done_steps = self._run_pipelined(
-                    num_steps, batch_timeout, max_idle, deadline, _bt, metrics_box
-                )
-            else:
-                done_steps = self._run_serial(
-                    num_steps, batch_timeout, max_idle, deadline, _bt, metrics_box
-                )
+            done_steps = self._loop(
+                num_steps, batch_timeout, max_idle, deadline, _bt, metrics_box
+            )
         finally:
             if metrics_box[0] is not None:
                 jax.block_until_ready(metrics_box[0])
@@ -1253,146 +1209,18 @@ class Learner:
             self.metrics.flush()
         return done_steps
 
-    def _run_serial(
+    def _loop(
         self, num_steps, batch_timeout, max_idle, deadline, _bt, metrics_box
     ) -> int:
-        """The serial fetch-after-step loop (--learner.prefetch false) —
-        the pre-pipeline behavior, byte-for-byte (the rollback path;
-        tests/test_pipeline.py pins the flag-off inertness)."""
-        cfg = self.cfg
-        # Step-phase decomposition (obs/compute.py): when the timer
-        # exists the SERIAL loop FENCES the device once per step so each
-        # phase is causally attributable — trading the round-3 prefetch
-        # overlap for legibility. (The pipelined loop instead runs the
-        # timer in overlap mode: attribution moves to the prefetch lane
-        # and no fence is paid — _run_pipelined.) timer=None keeps the
-        # async-dispatch shape untouched.
-        compute = self.obs.compute if self.obs is not None else None
-        timer = compute.timer if compute is not None else None
-        done_steps = 0
-        # per-window accumulators, reset at every metrics log
-        win_wait = win_put = 0.0
-        win_env_steps = 0
-        win_steps = 0
-        t_win = time.perf_counter()
-        metrics = None
-        idle = 0
-        next_batch, next_env_steps, w, p, next_trace = self._fetch_next(_bt())
-        win_wait += w
-        win_put += p
-        while num_steps is None or done_steps < num_steps:
-            if self._abort.is_set():
-                # SIGKILL emulation: return NOW, staged work dies
-                # with the incarnation (chaos controller contract).
-                break
-            if deadline is not None and time.monotonic() >= deadline:
-                break
-            if next_batch is None:
-                if self._drain.is_set():
-                    # Drain: staging intake is quiesced; an empty
-                    # fetch with nothing left to pack means the
-                    # in-flight work is trained out — return so the
-                    # caller can drain_save().
-                    if self.staging.drained():
-                        break
-                    next_batch, next_env_steps, w, p, next_trace = self._fetch_next(_bt())
-                    win_wait += w
-                    win_put += p
-                    continue
-                idle += 1
-                if max_idle is not None and idle >= max_idle:
-                    raise TimeoutError(
-                        f"no batch for {idle} consecutive {batch_timeout:.0f}s waits "
-                        f"— producers dead or stalled"
-                    )
-                if deadline is not None and time.monotonic() >= deadline:
-                    break
-                _log.warning("no batch within %.0fs; waiting", batch_timeout)
-                next_batch, next_env_steps, w, p, next_trace = self._fetch_next(_bt())
-                win_wait += w
-                win_put += p
-                continue
-            idle = 0
-            batch_dev, env_steps, batch_trace = next_batch, next_env_steps, next_trace
-            t_pass = time.perf_counter()
-            # Async dispatch: returns immediately, device runs the step.
-            self.state, metrics = self._dispatch(batch_dev)
-            metrics_box[0] = metrics
-            if timer is not None:
-                # Fence: device_step is dispatch + execution wall. The
-                # prefetch below then runs AFTER the device finished —
-                # the overlap cost the serial step_phases mode documents.
-                jax.block_until_ready(metrics)
-                timer.add("device_step", time.perf_counter() - t_pass)
-            if self.obs is not None and batch_trace is not None:
-                # Terminal hops at DISPATCH (the loop's only routine
-                # sync is the metrics fetch): per-stage apply delta +
-                # the e2e actor→apply scalar that decomposes staleness.
-                self.obs.tracer.hop_batch("apply", batch_trace)
-                self.obs.tracer.e2e(batch_trace)
-            self.version += 1
-            done_steps += 1
-            self.env_steps_done += env_steps
-            win_env_steps += env_steps
-            win_steps += 1
-
-            last = num_steps is not None and done_steps >= num_steps
-            if not last:
-                # Host work below overlaps the in-flight device step.
-                # Skipped on the final step: a trailing prefetch would
-                # eat (and discard) one packed batch per phased-run
-                # call and could stall up to batch_timeout.
-                next_batch, next_env_steps, w, p, next_trace = self._fetch_next(_bt())
-                win_wait += w
-                win_put += p
-            else:
-                next_batch, next_env_steps, next_trace = None, 0, None
-
-            t_host = time.perf_counter()
-            if self.version % cfg.publish_every == 0 and self._primary:
-                # Non-primary processes skip: weights are replicated
-                # and one fanout per version is the contract.
-                self._submit_publish()
-            if self.checkpointer is not None and self.version % cfg.checkpoint_every == 0:
-                with span("loop.checkpoint", version=self.version):
-                    self.checkpoint()
-
-            if timer is not None:
-                # Close the pass BEFORE a possible metrics window so
-                # window_scalars only ever aggregates fully-closed
-                # passes (a half-recorded pass would make the phase
-                # sum drift from the wall). The metrics sync/log below
-                # is the observer's own cost and stays outside the
-                # decomposition by design.
-                t_end = time.perf_counter()
-                timer.add("host", t_end - t_host)
-                timer.step(t_end - t_pass)
-
-            if self.version % cfg.metrics_every == 0 or last:
-                now = time.perf_counter()
-                self._log_window(
-                    metrics, now, t_win, win_steps, win_env_steps, win_wait, win_put
-                )
-                win_wait = win_put = 0.0
-                win_env_steps = win_steps = 0
-                t_win = now
-        return done_steps
-
-    def _run_pipelined(
-        self, num_steps, batch_timeout, max_idle, deadline, _bt, metrics_box
-    ) -> int:
-        """The overlapped loop (--learner.prefetch, default): a
-        PrefetchLane thread runs the whole host side of batch N+1 —
-        staging pop, pack wait, device_put dispatch, retire, ring-lease
-        release — while the device executes step N, so the loop thread's
-        per-iteration host cost is one queue pop + the async train-step
-        dispatch. Batch order is FIFO-identical to the serial loop (the
-        lane is the same single staging consumer), so params are BITWISE
-        equal to a serial run over the same frame schedule
-        (tests/test_pipeline.py). The SIGTERM drain trains out every batch the
-        lane holds (the "exhausted" sentinel lands FIFO-last), and the
-        lane's fetch budget is capped at num_steps so a phased run never
-        eats a trailing batch."""
+        """The loop of one run(): a PrefetchLane thread runs the whole
+        host side of batch N+1 — staging pop, pack wait, device_put
+        dispatch, retire, ring-lease release — while the device executes
+        step N, so the loop thread's per-iteration host cost is one
+        queue pop + the async train-step dispatch. The lane is the single
+        staging consumer, so batches are trained FIFO. The SIGTERM drain
+        trains out every batch the lane holds (the "exhausted" sentinel
+        lands FIFO-last), and the lane's fetch budget is capped at
+        num_steps so a phased run never eats a trailing batch."""
         cfg = self.cfg
         compute = self.obs.compute if self.obs is not None else None
         timer = compute.timer if compute is not None else None
@@ -1402,8 +1230,7 @@ class Learner:
         # driver's next run()).
         cancel = threading.Event()
         lane = PrefetchLane(
-            lambda: self._fetch_next(_bt(), lane=True, cancel=cancel),
-            depth=self._prefetch_depth,
+            lambda: self._fetch_next(_bt(), cancel=cancel),
             limit=num_steps,
             drain=self._drain,
             abort=self._abort,
@@ -1457,8 +1284,7 @@ class Learner:
                     # everything the drain owed is trained out.
                     break
                 if item.kind == "idle":
-                    # Starvation must read LOUD, exactly like the serial
-                    # loop's empty fetches: the wall spent polling for
+                    # Starvation must read LOUD: the wall spent polling for
                     # this (empty) item is exposed loop wait — charge it
                     # to the take accumulator and the timer's fetch
                     # phase (compute_phase_fetch_frac is the watchdog's
@@ -1515,7 +1341,7 @@ class Learner:
                         self.checkpoint()
 
                 if timer is not None:
-                    # Overlap mode: no per-step fence. device_step is
+                    # No per-step fence. device_step is
                     # the UNFENCED residual — the in-flight device
                     # window from the loop's clock — so the loop-lane
                     # phases tile the wall by construction; the causal
@@ -1532,7 +1358,7 @@ class Learner:
                     now = time.perf_counter()
                     t_dispatch += self._log_window(
                         metrics, now, t_win, win_steps, win_env_steps,
-                        win_wait, win_put, win_take=win_take, win_gap=win_gap,
+                        win_wait, win_put, win_take, win_gap,
                     )
                     win_wait = win_put = win_take = win_gap = 0.0
                     win_env_steps = win_steps = 0
@@ -1551,14 +1377,14 @@ class Learner:
         win_env_steps: int,
         win_wait: float,
         win_put: float,
-        win_take: Optional[float] = None,
-        win_gap: Optional[float] = None,
+        win_take: float,
+        win_gap: float,
     ) -> float:
         """One metrics window — the ONLY routine device sync in the loop
-        (jax.device_get of the step metrics). Shared by both loop shapes;
-        `win_take` is the pipelined loop's exposed take-wait accumulator
-        and `win_gap` its longest interval between two dispatches (None =
-        serial split). Returns the seconds the sync blocked."""
+        (jax.device_get of the step metrics). `win_wait`/`win_put` are
+        the lane's fetch wait and device_put, `win_take` the loop's
+        exposed take-wait and `win_gap` its longest interval between two
+        dispatches. Returns the seconds the sync blocked."""
         compute = self.obs.compute if self.obs is not None else None
         t_sync = time.perf_counter()
         with span("loop.sync", step=self.version):
@@ -1573,23 +1399,19 @@ class Learner:
         # loop never syncs per step.
         scalars["time_wait_batch_s"] = win_wait / n
         scalars["time_device_put_s"] = win_put / n
-        if win_take is None:
-            scalars["time_step_s"] = max(dt - win_wait - win_put, 0.0) / n
-        else:
-            # Pipelined loop: wait/put were paid on the prefetch lane,
-            # overlapping the device step — only the take-wait is
-            # exposed loop time, so the residual subtracts just that.
-            # The pipeline_* family carries the overlap accounting
-            # (obs overlap-mode timer refines these with fenced lane
-            # sums when step_phases is on — same keys, logged after).
-            lane_s = win_wait + win_put
-            scalars["time_step_s"] = max(dt - win_take, 0.0) / n
-            scalars["pipeline_prefetch_s"] = lane_s / n
-            scalars["pipeline_device_idle_s"] = win_take / n
-            scalars["pipeline_overlap_ratio"] = (
-                max(0.0, min(1.0, 1.0 - win_take / lane_s)) if lane_s > 0 else 1.0
-            )
-            scalars["loop_dispatch_gap_max_s"] = win_gap
+        # wait/put were paid on the prefetch lane, overlapping the device
+        # step — only the take-wait is exposed loop time, so the residual
+        # subtracts just that. The pipeline_* family carries the overlap
+        # accounting (the phase timer refines these with fenced lane sums
+        # when step_phases is on — same keys, logged after).
+        lane_s = win_wait + win_put
+        scalars["time_step_s"] = max(dt - win_take, 0.0) / n
+        scalars["pipeline_prefetch_s"] = lane_s / n
+        scalars["pipeline_device_idle_s"] = win_take / n
+        scalars["pipeline_overlap_ratio"] = (
+            max(0.0, min(1.0, 1.0 - win_take / lane_s)) if lane_s > 0 else 1.0
+        )
+        scalars["loop_dispatch_gap_max_s"] = win_gap
         scalars["active_actors"] = stats["active_actors"]
         scalars["staleness_dropped"] = stats["dropped_stale"]
         scalars["staging_quarantined"] = stats["quarantined"]
@@ -1661,9 +1483,9 @@ class Learner:
             scalars.update(self.obs.tracer.scalars())
         if compute is not None:
             # compute_* families (obs/compute.py): phase means over this
-            # window (every pass fully closed — the loops close the pass
-            # before logging), cumulative recompile counters, cumulative
-            # MFU; in overlap mode also the fenced pipeline_* lane sums.
+            # window (every pass fully closed — the loop closes the pass
+            # before logging), the fenced pipeline_* lane sums, cumulative
+            # recompile counters, cumulative MFU.
             scalars.update(compute.window_scalars(win_steps, dt))
         self.metrics.log(self.version, scalars)
         _log.info(

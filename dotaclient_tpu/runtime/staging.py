@@ -73,7 +73,7 @@ def fill_rollouts(
 ) -> None:
     """Fill a pre-zeroed TrainBatch (zeros_train_batch contract) with B
     variable-length rollouts, in place. The leaves may be strided views
-    (the fused-H2D group buffers) or dense arrays; numpy assignment
+    (the fused-H2D transfer buffer) or dense arrays; numpy assignment
     handles both, including the f32→bf16 cast when the obs leaves are
     staged in the compute dtype.
 
@@ -344,14 +344,14 @@ class StagingBuffer:
         # get_batch_groups (learner-thread-read; None when untraced).
         self.last_batch_trace = None
         # Fused-H2D mode (parallel/fused_io.FusedBatchIO): the packer
-        # fills leaf VIEWS of the dtype-grouped transfer buffers, so the
-        # learner ships `groups` without a regroup copy. The caller must
+        # fills leaf VIEWS of the one u8 transfer buffer, so the
+        # learner ships that buffer without a regroup copy. The caller must
         # pass the SAME io the train step was built with (layouts must
         # agree) and read via get_batch_groups.
         self._fused_io = fused_io
         # python path: Rollout objects; native path: raw frame bytes
         self._pending: List = []
-        # queue items: (TrainBatch, groups-dict-or-None, traces, lease)
+        # queue items: (TrainBatch, transfer-buffer-or-None, traces, lease)
         self._ready: "queue.Queue" = queue.Queue(maxsize=2)
         self._stop = threading.Event()
         # Parallel host feed (--staging.pack_workers > 1): a dedicated
@@ -390,13 +390,12 @@ class StagingBuffer:
         # the classic path). Single-consumer contract, like
         # last_batch_trace: only the learner loop pops batches.
         self.last_batch_lease = None
-        # Downstream prefetch-lane station (--learner.prefetch): the
-        # pipelined learner's PrefetchLane pops batches off _ready and
-        # holds them (locals or its handoff queue) until the loop trains
-        # them. drained() must see those popped-but-untrained frames or
-        # a SIGTERM drain could declare victory one batch early — the
-        # PR-7 loss class, one station further downstream. None = no
-        # lane (the serial loop, or a non-learner consumer).
+        # Downstream prefetch-lane station: the learner's PrefetchLane
+        # pops batches off _ready and holds them (locals or its handoff
+        # queue) until the loop trains them. drained() must see those
+        # popped-but-untrained frames or a SIGTERM drain could declare
+        # victory one batch early. None = no lane (a non-learner
+        # consumer).
         self._prefetch_probe = None
         # SIGTERM drain: once set, the consumer stops popping the broker
         # but keeps packing already-pending frames into full batches —
@@ -509,7 +508,7 @@ class StagingBuffer:
                 raise ValueError(
                     "replay reservoir and fused H2D staging are mutually "
                     "exclusive: the behavior_staleness stamp is not part of "
-                    "the dtype-grouped transfer layout (the Learner builds "
+                    "the fused transfer layout (the Learner builds "
                     "the tree-path train step when replay.enabled)"
                 )
             if cfg.replay.max_staleness <= cfg.ppo.max_staleness:
@@ -656,7 +655,7 @@ class StagingBuffer:
             t_pack = time.perf_counter()
             try:
                 with span("staging.pack"):
-                    batch, groups, lease = self._pack(items)
+                    batch, payload, lease = self._pack(items)
             except BatchLayoutError:
                 # layout/config mismatch: fails every batch, not this
                 # batch — propagate to the fatal handler in the caller
@@ -692,7 +691,7 @@ class StagingBuffer:
             with span("staging.ready_wait"):
                 while not self._stop.is_set():
                     try:
-                        self._ready.put((batch, groups, traces, lease), timeout=0.2)
+                        self._ready.put((batch, payload, traces, lease), timeout=0.2)
                         break
                     except queue.Full:
                         continue
@@ -882,9 +881,9 @@ class StagingBuffer:
         return items, staleness, traces
 
     def _pack(self, items: List):
-        """(TrainBatch, groups-or-None, lease-or-None). Fused mode packs
-        straight into leaf views of the dtype-grouped transfer buffers
-        (no regroup copy later); dense mode matches the original layout.
+        """(TrainBatch, payload-or-None, lease-or-None). Fused mode packs
+        straight into leaf views of the u8 transfer buffer (no regroup
+        copy later); dense mode matches the original layout.
         Pool mode (pack_workers > 1) row-shards the same copy across the
         worker pool — bitwise identical output for any split — and in
         fused mode targets a TransferRing slot, returned as the lease."""
@@ -899,9 +898,9 @@ class StagingBuffer:
         if self._pool is not None:
             return self._pack_sharded(items, obs_bf16)
         if self._fused_io is not None:
-            # payload: groups dict, or ONE u8 buffer in single mode —
-            # opaque here; the learner ships it with io.transfer_shardings()
-            groups, out = self._fused_io.alloc_transfer()
+            # payload: the ONE u8 transfer buffer the views live in —
+            # opaque here; the learner ships it with io.sharding
+            payload, out = self._fused_io.alloc_transfer()
             if self._lib is not None:
                 from dotaclient_tpu import native
 
@@ -919,7 +918,7 @@ class StagingBuffer:
                 # assignment cast) transparently; no post-cast — it
                 # would detach the leaves from the transfer buffers.
                 fill_rollouts(out, items, self.cfg.seq_len)
-            return out, groups, None
+            return out, payload, None
         if self._lib is not None:
             from dotaclient_tpu import native
 
@@ -1030,8 +1029,7 @@ class StagingBuffer:
         """Assembled-intake landing: every pending item is an
         AssembledRow whose payload already holds the exact RowLayout
         bytes, so "packing" a batch is a ring-slot acquire plus one
-        C-level row concat and one bulk copy per dtype group (single
-        bulk copy in single-buffer mode) — no parse, no per-field
+        C-level row concat and one bulk copy — no parse, no per-field
         scatter, no cast.
         Bitwise identical to the classic pack of the same wire
         frames: the shard ran the SAME row encoder over the SAME bytes
@@ -1054,18 +1052,7 @@ class StagingBuffer:
         raw = np.frombuffer(
             b"".join(row.payload for row in items), np.uint8
         ).reshape(n_rows, self._fused_io.row_bytes)
-        if isinstance(payload, dict):
-            # Grouped transfer layout: one vectorized strided copy per
-            # dtype group — the row layout's segment order/offsets are
-            # the grouped layout's columns, so each group is a column
-            # slice of the stacked rows.
-            seg_off = self._fused_io.seg_off
-            for key, buf in payload.items():
-                u8 = buf.view(np.uint8)
-                off = seg_off[key]
-                u8[:n_rows] = raw[:, off : off + u8.shape[1]]
-        else:
-            payload[:n_rows] = raw
+        payload[:n_rows] = raw
         return slot.batch, payload, slot
 
     def _parse(self, frame: bytes):
@@ -1389,7 +1376,7 @@ class StagingBuffer:
                 # waiting out the full batch timeout would only burn the
                 # drain budget against a queue nothing will ever fill.
                 # UPSTREAM stations only: the caller here IS the consumer
-                # (the prefetch lane in pipelined mode), and its own
+                # (the learner's prefetch lane), and its own
                 # mid-fetch _inflight flag covers this very wait — the
                 # full-station drained() would read False forever and the
                 # fast-exit would never fire, burning the whole
@@ -1427,15 +1414,15 @@ class StagingBuffer:
         return item[0]
 
     def get_batch_groups(self, timeout: Optional[float] = None, cancel=None):
-        """(TrainBatch, groups) — `groups` is the ready-to-ship fused-H2D
-        buffer dict when the buffer was built with fused_io, else None
-        (caller falls back to io.pack). The batch's leaves are views into
-        `groups`.
+        """(TrainBatch, payload) — `payload` is the ready-to-ship u8
+        transfer buffer when staging was built with fused_io, else None
+        (caller falls back to io.pack_transfer). The batch's leaves are
+        views into `payload`.
 
         Classic path (pack_workers=1): every batch allocates fresh
         buffers, so no aliasing hazard. Ring path (pack_workers>1):
-        `groups` is a leased TransferRing slot — the caller must release
-        `self.last_batch_lease` AFTER the device_put of `groups` has
+        `payload` is a leased TransferRing slot — the caller must release
+        `self.last_batch_lease` AFTER the device_put of `payload` has
         retired (jax.block_until_ready), at which point the slot may be
         re-zeroed and repacked; holding leases is the ring's
         backpressure.
@@ -1446,14 +1433,14 @@ class StagingBuffer:
         None). Single-consumer by contract (only the learner loop pops
         batches), so the attribute reads are race-free."""
         try:
-            batch, groups, traces, lease = self._get_ready(timeout, cancel=cancel)
+            batch, payload, traces, lease = self._get_ready(timeout, cancel=cancel)
         except queue.Empty:
             self.last_batch_trace = None
             self.last_batch_lease = None
             return None, None
         self.last_batch_trace = traces
         self.last_batch_lease = lease
-        return batch, groups
+        return batch, payload
 
     # -- checkpoint / drain support --------------------------------------
 
@@ -1534,7 +1521,7 @@ class StagingBuffer:
         self._quiesce.set()
 
     def attach_prefetch_probe(self, probe: Callable[[], bool]) -> None:
-        """Register the pipelined learner's prefetch-lane station
+        """Register the learner's prefetch-lane station
         (runtime/learner.py PrefetchLane.holding): a callable that is
         True while the lane holds popped-but-untrained frames — in its
         thread locals mid-fetch or in its handoff queue. drained()
@@ -1592,7 +1579,7 @@ class StagingBuffer:
         if not self._ready.empty():
             return False
         # The most DOWNSTREAM station: a batch the prefetch lane popped
-        # off _ready but the loop has not trained yet (--learner.prefetch).
+        # off _ready but the loop has not trained yet.
         if include_prefetch:
             probe = self._prefetch_probe
             if probe is not None and probe():

@@ -8,8 +8,8 @@ Sections, at matched seeds (the SAME wire bytes feed every arm):
    classic learner-host pack builds from the same frames, for every
    shard split in {1, 2, 3, 4}, over a mixed DTR1 (f32) + DTR2 (traced
    f32) + DTR3 (bf16) wire batch with partial (L < T, i.e. padded)
-   rows, on BOTH packers (native C and the python fill fallback), with
-   a grouped-transfer AND a single-buffer spot check. Assembled arms
+   rows, on BOTH packers (native C and the python fill fallback).
+   Assembled arms
    run REAL localhost BrokerServer shards behind the REAL FabricBroker
    block fan-in into the REAL StagingBuffer; multi-shard row order is
    fan-in nondeterministic, so arms compare SORTED per-row hashes (row
@@ -17,7 +17,7 @@ Sections, at matched seeds (the SAME wire bytes feed every arm):
 2. host_cost — the perf headline at the flagship 256x16 shape: classic
    host pack (C packer parsing 256 frames into the fused transfer
    views) vs the concat-only landing assembled mode leaves on the
-   learner host (one memcpy per row-group segment of pre-packed rows).
+   learner host (one row concat + one bulk copy of pre-packed rows).
    pack_over_concat_x is the collapse the ISSUE names.
 3. host_memcpy_probe — the independent GIL-released floor: raw libc
    memcpy (ctypes, no repo code) of the same batch bytes, 1/2/4
@@ -108,7 +108,7 @@ def _small_cfg(native_on: bool, assemble: bool) -> LearnerConfig:
     return cfg
 
 
-def _small_io(cfg: LearnerConfig, single: bool):
+def _small_io(cfg: LearnerConfig):
     from dotaclient_tpu.parallel import mesh as mesh_lib
     from dotaclient_tpu.parallel.fused_io import FusedBatchIO
     from dotaclient_tpu.parallel.train_step import _batch_template
@@ -116,9 +116,7 @@ def _small_io(cfg: LearnerConfig, single: bool):
     template = cast_obs_to_compute_dtype(
         cfg, jax.tree.map(np.asarray, _batch_template(cfg))
     )
-    io = FusedBatchIO(template, mesh_lib.make_mesh("dp=-1"))
-    io.single_mode = single
-    return io
+    return FusedBatchIO(template, mesh_lib.make_mesh("dp=-1"))
 
 
 def _mixed_frames():
@@ -149,20 +147,10 @@ def _mixed_frames():
     return frames
 
 
-def _row_hashes(groups) -> list:
+def _row_hashes(payload) -> list:
     """Sorted per-row sha256 over the transfer-buffer bytes — row
     CONTENT is the parity contract; fan-in arrival order is not."""
-    if isinstance(groups, dict):
-        rows = []
-        for r in range(SMALL_B):
-            rows.append(
-                b"".join(
-                    np.ascontiguousarray(groups[k][r]).view(np.uint8).tobytes()
-                    for k in sorted(groups)
-                )
-            )
-    else:
-        rows = [np.ascontiguousarray(groups[r]).tobytes() for r in range(SMALL_B)]
+    rows = [np.ascontiguousarray(payload[r]).tobytes() for r in range(SMALL_B)]
     return sorted(hashlib.sha256(r).hexdigest() for r in rows)
 
 
@@ -170,11 +158,11 @@ def _digest(row_hashes: list) -> str:
     return hashlib.sha256("".join(row_hashes).encode()).hexdigest()[:16]
 
 
-def _classic_hashes(tag: str, frames, native_on: bool, single: bool = False):
+def _classic_hashes(tag: str, frames, native_on: bool):
     """Reference arm: the HEAD learner-host pack of the same wire bytes
     through the real StagingBuffer (mem:// broker)."""
     cfg = _small_cfg(native_on, assemble=False)
-    io = _small_io(cfg, single)
+    io = _small_io(cfg)
     name = f"abip_{tag}"
     mem.reset(name)
     pub = connect(f"mem://{name}")
@@ -185,10 +173,10 @@ def _classic_hashes(tag: str, frames, native_on: bool, single: bool = False):
         sb._lib = None
     sb.start()
     try:
-        batch, groups = sb.get_batch_groups(timeout=60.0)
+        batch, payload = sb.get_batch_groups(timeout=60.0)
         if batch is None:
             raise RuntimeError(f"{tag}: classic staging produced no batch")
-        hashes = _row_hashes(groups)
+        hashes = _row_hashes(payload)
         lease = sb.last_batch_lease
         if lease is not None:
             lease.release()
@@ -197,8 +185,7 @@ def _classic_hashes(tag: str, frames, native_on: bool, single: bool = False):
         sb.stop()
 
 
-def _assembled_hashes(tag: str, frames, n_shards: int, native_on: bool,
-                      single: bool = False):
+def _assembled_hashes(tag: str, frames, n_shards: int, native_on: bool):
     """Assembled arm: n real armed BrokerServer shards pre-pack the same
     wire bytes into DTB1 blocks; FabricBroker block fan-in; the
     assembled StagingBuffer lands rows concat-only into the ring.
@@ -217,18 +204,18 @@ def _assembled_hashes(tag: str, frames, n_shards: int, native_on: bool,
         if n_shards < len(servers):
             fab.restrict_consume_shards(list(range(n_shards)))
         cfg = _small_cfg(native_on, assemble=True)
-        io = _small_io(cfg, single)
+        io = _small_io(cfg)
         sb = StagingBuffer(cfg, fab, version_fn=lambda: 0, fused_io=io)
         sb.start()
         pubs = [connect(eps[i]) for i in range(n_shards)]
         for i, f in enumerate(frames):
             pubs[i % n_shards].publish_experience(f)
-        batch, groups = sb.get_batch_groups(timeout=60.0)
+        batch, payload = sb.get_batch_groups(timeout=60.0)
         if batch is None:
             raise RuntimeError(
                 f"{tag}: assembled staging produced no batch; stats={sb.stats()}"
             )
-        hashes = _row_hashes(groups)
+        hashes = _row_hashes(payload)
         stats = sb.stats()
         lease = sb.last_batch_lease
         if lease is not None:
@@ -272,19 +259,8 @@ def section_parity():
                 a["bitwise_identical"] for a in arms.values()
             ),
         }
-    # single-buffer transfer layout spot check (build_single_train_step
-    # mode: the ring slot is ONE [rows, row_bytes] buffer, the landing
-    # is one memcpy per row instead of per-group segments)
-    ref1 = _classic_hashes("single_ref", list(frames), True, single=True)
-    h1, _ = _assembled_hashes("single_s2", list(frames), 2, True, single=True)
-    out["single_buffer_spot"] = {
-        "shards": 2,
-        "bitwise_identical": h1 == ref1,
-    }
     out["all_identical"] = (
-        out["native"]["bitwise_identical"]
-        and out["python"]["bitwise_identical"]
-        and out["single_buffer_spot"]["bitwise_identical"]
+        out["native"]["bitwise_identical"] and out["python"]["bitwise_identical"]
     )
     return out
 
@@ -304,8 +280,8 @@ def _flagship_io():
 def section_host_cost(reps: int):
     """Flagship-shape learner-host cost: the classic pack (parse 256
     frames + scatter every field into the fused transfer views) vs the
-    concat-only landing of shard-assembled rows (one memcpy per
-    row-group segment). Same frames, same transfer layout; row assembly
+    concat-only landing of shard-assembled rows (one row concat + one
+    bulk copy). Same frames, same transfer layout; row assembly
     itself is the SHARD's cost and is metered there (broker_assemble_cpu
     _s_total), not here — that is the point of the feature."""
     from dotaclient_tpu import native
@@ -335,15 +311,12 @@ def section_host_cost(reps: int):
 
     def _concat_land():
         # The production _pack_assembled landing: one C-level row concat
-        # + one bulk strided copy per dtype group.
+        # + one bulk copy.
         payload, _outb = io.alloc_transfer()
         raw = np.frombuffer(b"".join(payloads), np.uint8).reshape(
             FLAGSHIP_B, io.row_bytes
         )
-        for key, buf in payload.items():
-            u8 = buf.view(np.uint8)
-            off = io.seg_off[key]
-            u8[:FLAGSHIP_B] = raw[:, off : off + u8.shape[1]]
+        payload[:FLAGSHIP_B] = raw
 
     def _timed(fn):
         fn()

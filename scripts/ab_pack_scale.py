@@ -192,7 +192,7 @@ def section_packer_scale(reps: int):
         "bf16_wire": [serialize_rollout(cast_rollout_obs_bf16(r)) for r in rollouts],
     }
     io = _flagship_io()
-    groups, out = io.alloc_views()  # one shared target; L=T frames fill every row
+    _, out = io.alloc_transfer()  # one shared target; L=T frames fill every row
     pools = {w: _PackPool(w, name=f"abps-{w}") for w in WORKER_ARMS if w > 1}
     # Per-arm prebuilt PackPlans — exactly what the staging ring path
     # runs per batch (glue paid once per slot, not per call).
@@ -291,12 +291,10 @@ def _staged_hash(tag: str, frames, workers: int, native_on: bool) -> str:
         sb._lib = None
     sb.start()
     try:
-        batch, groups = sb.get_batch_groups(timeout=60.0)
+        batch, payload = sb.get_batch_groups(timeout=60.0)
         if batch is None:
             raise RuntimeError(f"{tag}: staging produced no batch")
-        h = hashlib.sha256()
-        for k in sorted(groups):
-            h.update(np.ascontiguousarray(groups[k]).view(np.uint8).tobytes())
+        h = hashlib.sha256(np.ascontiguousarray(payload).tobytes())
         lease = sb.last_batch_lease
         if lease is not None:
             lease.release()
@@ -353,12 +351,9 @@ def section_parity():
             cfg, jax.tree.map(np.asarray, _batch_template(cfg))
         )
         io = FusedBatchIO(template, mesh_lib.make_mesh("dp=-1"))
-        groups, views = io.alloc_views()
+        payload, views = io.alloc_transfer()
         native.pack_frames(lib, list(frames), 8, 8, False, obs_bf16=True, out=views)
-        h = hashlib.sha256()
-        for k in sorted(groups):
-            h.update(np.ascontiguousarray(groups[k]).view(np.uint8).tobytes())
-        direct = h.hexdigest()
+        direct = hashlib.sha256(payload.tobytes()).hexdigest()
 
     out = {"direct_single_pack_sha256": direct}
     for packer, native_on in (("native", True), ("python", False)):

@@ -303,12 +303,12 @@ def section_e2e(rollouts, n_iters: int, seed: int):
     import threading
 
     from dotaclient_tpu.parallel import mesh as mesh_lib
-    from dotaclient_tpu.parallel.train_step import build_fused_train_step, init_train_state
+    from dotaclient_tpu.parallel.train_step import build_single_train_step, init_train_state
 
     policy = PolicyConfig(unit_embed_dim=32, lstm_hidden=32, mlp_hidden=32)
     cfg = LearnerConfig(batch_size=64, seq_len=FLAGSHIP_T, policy=policy, seed=seed)
     mesh = mesh_lib.make_mesh("dp=-1")
-    train_step, state_sh, io = build_fused_train_step(cfg, mesh)
+    train_step, state_sh, io = build_single_train_step(cfg, mesh)
     small = make_rollouts(256, FLAGSHIP_T, policy.lstm_hidden, seed=seed + 1)
     arms = {
         "f32_wire": [serialize_rollout(r) for r in small],
@@ -338,10 +338,10 @@ def section_e2e(rollouts, n_iters: int, seed: int):
         state = jax.device_put(init_train_state(cfg, jax.random.PRNGKey(seed)), state_sh)
 
         def fetch():
-            b, groups = sb.get_batch_groups(timeout=120.0)
+            b, payload = sb.get_batch_groups(timeout=120.0)
             if b is None:
                 raise RuntimeError("staging starved")
-            return jax.device_put(groups, io.shardings), int(np.sum(b.mask))
+            return jax.device_put(payload, io.sharding), int(np.sum(b.mask))
 
         try:
             dev, _ = fetch()

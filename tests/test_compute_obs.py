@@ -62,6 +62,11 @@ def test_step_phase_timer_window_means_and_reset():
     # phases tile the wall (the acceptance property, exact at unit level)
     phase_sum = sum(sc[f"compute_phase_{p}_s"] for p in StepPhaseTimer.PHASES)
     assert phase_sum == pytest.approx(0.9)
+    # the lane family rides every window; a lane that recorded nothing
+    # reads zero busy and a full overlap, the loop's fetch as exposed wait
+    assert sc["pipeline_prefetch_s"] == 0.0
+    assert sc["pipeline_device_idle_s"] == pytest.approx(0.3)
+    assert sc["pipeline_overlap_ratio"] == 1.0
     # window reset: an empty next window has zero means, no frac
     sc2 = t.window_scalars()
     assert sc2["compute_phase_fetch_s"] == 0.0

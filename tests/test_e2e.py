@@ -203,8 +203,8 @@ def test_checkpoint_resume_transformer_family(tmp_path):
 
 
 def test_e2e_single_buffer_h2d(env_addr):
-    """The opt-in ONE-u8-buffer H2D mode end-to-end: actors → broker →
-    single-layout staging → bitcast-unpack train step. Three steps with
+    """The ONE-u8-buffer H2D layout end-to-end: actors → broker →
+    staging packing into the transfer buffer → bitcast-unpack train step. Three steps with
     finite losses prove the learner glue (transfer shardings, staged
     payload dispatch, step input) — the layout itself is bitwise-pinned
     in test_fused_io/test_native/test_staging."""
@@ -212,7 +212,7 @@ def test_e2e_single_buffer_h2d(env_addr):
     mem.reset(broker_name)
     lcfg = LearnerConfig(
         batch_size=8, seq_len=8, policy=SMALL, mesh_shape="dp=-1",
-        publish_every=1, fused_single_h2d=True,
+        publish_every=1,
     )
     acfg = ActorConfig(
         env_addr=env_addr, broker_url=f"mem://{broker_name}",
@@ -227,7 +227,7 @@ def test_e2e_single_buffer_h2d(env_addr):
         t.start()
     learner = Learner(lcfg, broker_connect(f"mem://{broker_name}"))
     try:
-        assert learner.fused_io is not None and learner.fused_io.single_mode
+        assert learner.fused_io is not None
         steps = learner.run(num_steps=3, batch_timeout=120.0)
     finally:
         stop.set()

@@ -1,5 +1,6 @@
-"""Fused 4-buffer H2D path: pack/unpack roundtrip, exact metric parity
-with the per-leaf tree path, dp shardability, and the sp exclusion."""
+"""Fused single-buffer H2D path: pack/unpack roundtrip, metric parity
+with the per-leaf tree path, dp shardability, the sp exclusion, and the
+Learner's choice between the fused layout and the tree."""
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +11,7 @@ from dotaclient_tpu.config import LearnerConfig, PolicyConfig
 from dotaclient_tpu.parallel import mesh as mesh_lib
 from dotaclient_tpu.parallel.fused_io import FusedBatchIO
 from dotaclient_tpu.parallel.train_step import (
-    build_fused_train_step,
+    build_single_train_step,
     build_train_step,
     init_train_state,
     make_train_batch,
@@ -29,6 +30,18 @@ def _cfg(aux=False, dtype="float32", **kw):
     )
 
 
+def _sp_cfg():
+    """A transformer learner over a dp x sp mesh: seq_len+1 = 8 frames
+    divide by sp=4."""
+    return LearnerConfig(
+        batch_size=8,
+        seq_len=7,
+        mesh_shape="dp=2,sp=4",
+        policy=PolicyConfig(arch="transformer", tf_sp_axis="sp", tf_context=8,
+                            unit_embed_dim=16, lstm_hidden=16, mlp_hidden=16, tf_heads=4),
+    )
+
+
 def _host_batch(cfg, seed=0):
     return cast_obs_to_compute_dtype(cfg, jax.tree.map(np.asarray, make_train_batch(cfg, seed)))
 
@@ -41,10 +54,13 @@ class TestRoundtrip:
         mesh = mesh_lib.make_mesh("dp=-1")
         batch = _host_batch(cfg)
         io = FusedBatchIO(batch, mesh)
-        groups = io.pack(batch)
-        # bf16-staged configs ship 4 groups; pure-f32 configs ship 3
-        assert set(groups) == ({"f32", "i32", "u8", "bf16"} if dtype == "bfloat16" else {"f32", "i32", "u8"})
-        out = jax.jit(io.unpack)(groups)
+        # the fallback pack _fetch_next takes when staging hands over a
+        # dense batch
+        buf = io.pack_transfer(batch)
+        assert buf.shape == (cfg.batch_size, io.row_bytes) and buf.dtype == np.uint8
+        # bf16-staged configs carry 4 segments; pure-f32 configs 3
+        assert set(io.seg_off) == ({"f32", "i32", "u8", "bf16"} if dtype == "bfloat16" else {"f32", "i32", "u8"})
+        out = jax.jit(io.unpack_single)(buf)
         in_leaves, in_def = jax.tree.flatten(batch)
         out_leaves, out_def = jax.tree.flatten(out)
         assert in_def == out_def
@@ -61,57 +77,30 @@ class TestRoundtrip:
             FusedBatchIO(bad, mesh)
 
 
-class TestFusedTrainStep:
-    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-    def test_metrics_match_tree_path(self, dtype):
-        """The fused step must compute the identical function — same
-        metrics as the per-leaf path on the same batch and init."""
-        cfg = _cfg(aux=True, dtype=dtype)
-        mesh = mesh_lib.make_mesh("dp=2,tp=4")
-        batch = _host_batch(cfg)
-
-        tree_step, state_sh, batch_shardings = build_train_step(cfg, mesh)
-        state = jax.device_put(init_train_state(cfg, jax.random.PRNGKey(0)), state_sh)
-        _, m_tree = tree_step(state, jax.device_put(batch, batch_shardings))
-
-        fused_step, state_sh2, io = build_fused_train_step(cfg, mesh)
-        state2 = jax.device_put(init_train_state(cfg, jax.random.PRNGKey(0)), state_sh2)
-        _, m_fused = fused_step(state2, jax.device_put(io.pack(batch), io.shardings))
-
-        for k in m_tree:
-            assert float(m_fused[k]) == pytest.approx(float(m_tree[k]), rel=1e-5, abs=1e-7), k
-
-    def test_group_buffers_shard_over_dp(self):
-        cfg = _cfg()
-        mesh = mesh_lib.make_mesh("dp=8")
-        fused_step, _, io = build_fused_train_step(cfg, mesh)
-        groups = jax.device_put(io.pack(_host_batch(cfg)), io.shardings)
-        for k, g in groups.items():
-            assert len(g.sharding.device_set) == 8, k
-            # leading (batch) axis split 8 ways
-            shard_shapes = {s.data.shape for s in g.addressable_shards}
-            assert shard_shapes == {(cfg.batch_size // 8, g.shape[1])}, k
-
-    def test_refused_under_sequence_parallelism(self):
-        cfg = _cfg()
-        cfg.policy.arch = "transformer"
-        cfg.policy.tf_sp_axis = "sp"
-        cfg.seq_len = 7
-        mesh = mesh_lib.make_mesh("dp=2,sp=4")
-        with pytest.raises(ValueError, match="sequence parallelism"):
-            build_fused_train_step(cfg, mesh)
-
-    def test_learner_uses_fused_path_by_default(self):
+class TestLearnerLayout:
+    @pytest.mark.parametrize("case", ["default", "sp", "replay"])
+    def test_layout_is_chosen_from_mesh_and_replay(self, case):
+        """No flag: the fused layout unless the mesh is sequence-parallel
+        or the replay reservoir is on, where the per-leaf tree runs."""
+        from dotaclient_tpu.config import ReplayConfig
         from dotaclient_tpu.runtime.learner import Learner
         from dotaclient_tpu.transport import memory as mem
         from dotaclient_tpu.transport.base import connect
 
-        mem.reset("fused_lrn")
-        learner = Learner(_cfg(), connect("mem://fused_lrn"))
-        assert learner.fused_io is not None
-        mem.reset("tree_lrn")
-        learner2 = Learner(_cfg(fused_h2d=False), connect("mem://tree_lrn"))
-        assert learner2.fused_io is None and learner2.batch_sharding is not None
+        cfg = {
+            "default": _cfg,
+            "sp": _sp_cfg,
+            "replay": lambda: _cfg(replay=ReplayConfig(enabled=True)),
+        }[case]()
+        mem.reset(f"layout_{case}")
+        learner = Learner(cfg, connect(f"mem://layout_{case}"))
+        try:
+            if case == "default":
+                assert learner.fused_io is not None and learner.batch_sharding is None
+            else:
+                assert learner.fused_io is None and learner.batch_sharding is not None
+        finally:
+            learner.close()
 
 
 class TestSingleBuffer:
@@ -125,7 +114,7 @@ class TestSingleBuffer:
         mesh = mesh_lib.make_mesh("dp=-1")
         batch = _host_batch(cfg)
         io = FusedBatchIO(batch, mesh)
-        buf, views = io.alloc_views_single()
+        buf, views = io.alloc_transfer()
         assert buf.shape == (cfg.batch_size, io.row_bytes) and buf.dtype == np.uint8
         for v, ref in zip(jax.tree.leaves(views), jax.tree.leaves(batch)):
             v[...] = ref
@@ -152,12 +141,6 @@ class TestSingleBuffer:
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     def test_single_step_metrics_match_tree_path(self, dtype):
         """The single-buffer step computes the identical function."""
-        from dotaclient_tpu.parallel.train_step import (
-            build_single_train_step,
-            build_train_step,
-            init_train_state,
-        )
-
         cfg = _cfg(aux=True, dtype=dtype)
         mesh = mesh_lib.make_mesh("dp=2,tp=4")
         batch = _host_batch(cfg)
@@ -167,10 +150,9 @@ class TestSingleBuffer:
         _, m_tree = tree_step(state0, jax.device_put(batch, batch_sh))
 
         single_step, state_sh2, io = build_single_train_step(cfg, mesh)
-        assert io.single_mode
         state1 = jax.device_put(init_train_state(cfg, jax.random.PRNGKey(0)), state_sh2)
         buf = io.pack_transfer(batch)
-        _, m_single = single_step(state1, jax.device_put(buf, io.single_sharding))
+        _, m_single = single_step(state1, jax.device_put(buf, io.sharding))
         # Input bits are identical (the roundtrip test is bitwise); the
         # residual is bf16 fusion-order noise between two different XLA
         # programs (~5e-5 observed on the CPU backend). A layout bug
@@ -180,17 +162,18 @@ class TestSingleBuffer:
                 np.asarray(m_single[k]), np.asarray(m_tree[k]), rtol=1e-4, atol=1e-5
             ), k
 
-    def test_refused_under_sequence_parallelism(self):
-        from dotaclient_tpu.parallel.train_step import build_single_train_step
-        from dotaclient_tpu.config import PolicyConfig as PC
+    def test_buffer_shards_over_dp(self):
+        cfg = _cfg()
+        mesh = mesh_lib.make_mesh("dp=8")
+        _, _, io = build_single_train_step(cfg, mesh)
+        buf = jax.device_put(io.pack_transfer(_host_batch(cfg)), io.sharding)
+        assert len(buf.sharding.device_set) == 8
+        # leading (batch) axis split 8 ways, rows whole
+        shard_shapes = {s.data.shape for s in buf.addressable_shards}
+        assert shard_shapes == {(cfg.batch_size // 8, io.row_bytes)}
 
-        cfg = LearnerConfig(
-            batch_size=8,
-            seq_len=7,
-            mesh_shape="dp=2,sp=4",
-            policy=PC(arch="transformer", tf_sp_axis="sp", tf_context=8,
-                      unit_embed_dim=16, lstm_hidden=16, mlp_hidden=16, tf_heads=4),
-        )
+    def test_refused_under_sequence_parallelism(self):
+        cfg = _sp_cfg()
         mesh = mesh_lib.make_mesh(cfg.mesh_shape)
-        with pytest.raises(ValueError, match="single-buffer"):
+        with pytest.raises(ValueError, match="sequence parallelism"):
             build_single_train_step(cfg, mesh)

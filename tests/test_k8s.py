@@ -183,31 +183,27 @@ def test_multihost_learner_slice_consistency():
 
 
 def test_learner_manifests_keep_pipelined_loop():
-    """Production learner deploys pin the overlapped loop (ISSUE 15):
-    --learner.prefetch true explicitly (the loop shape
-    must survive a default change, and rollback is exactly this flag —
-    MIGRATION item 15), and --obs.step_phases true WITH it — phase
-    attribution is free under the pipelined loop (obs/compute.py overlap
-    mode fences the prefetch lane, never the loop) and exports the
-    pipeline_* overlap scoreboard. A manifest pairing step_phases true
-    with prefetch false would silently pay a per-step device fence —
-    the pairing is the contract."""
+    """Production learner deploys pin --obs.step_phases true — phase
+    attribution costs the loop nothing (obs/compute.py fences the
+    prefetch lane, never the loop) and exports the pipeline_* overlap
+    scoreboard — and their args parse: the learner's parser refuses a
+    flag it does not know (those that went with the serial loop and the
+    grouped layout among them), so such a pod would crash-loop at
+    boot."""
+    from dotaclient_tpu.config import LearnerConfig, parse_config
+
     for name in ("learner", "learner-multihost"):
         (_, doc), = [
             (f, d) for f, d in DOCS
             if d["metadata"]["name"] == name and d["kind"] != "Service"
         ]
         args = doc["spec"]["template"]["spec"]["containers"][0]["args"]
-        assert "--learner.prefetch" in args, f"{name}: prefetch not pinned"
-        assert args[args.index("--learner.prefetch") + 1] == "true", (
-            f"{name}: production learner must run the overlapped loop"
-        )
         assert "--obs.step_phases" in args, f"{name}: step_phases not pinned"
         assert args[args.index("--obs.step_phases") + 1] == "true", (
-            f"{name}: step_phases is free (overlap mode) under the "
-            "pipelined loop and carries the pipeline_* scoreboard — "
-            "pin it on"
+            f"{name}: step_phases costs the loop nothing and carries the "
+            "pipeline_* scoreboard — pin it on"
         )
+        assert parse_config(LearnerConfig(), args).obs.step_phases is True
 
 
 def test_learner_drain_grace_pairing():

@@ -286,7 +286,7 @@ def test_dtr3_pack_into_f32_batch_upcasts_exactly():
     )
 
 
-def test_dtr3_grouped_pack_bitwise_matches_dense():
+def test_dtr3_strided_pack_bitwise_matches_dense():
     """DTR3 frames through the fused-H2D strided views (row_strides
     path) — the production landing zone — must match the dense pack."""
     import jax
@@ -305,7 +305,7 @@ def test_dtr3_grouped_pack_bitwise_matches_dense():
 
     template = cast_obs_to_compute_dtype(cfg, jax.tree.map(np.asarray, _batch_template(cfg)))
     io = FusedBatchIO(template, mesh_lib.make_mesh("dp=-1"))
-    groups, out = io.alloc_views()
+    _, out = io.alloc_transfer()
     native.pack_frames(lib, frames, seq_len=8, lstm_hidden=8, with_aux=False, obs_bf16=True, out=out)
     leaves_equal(dense, out)
 
@@ -376,11 +376,12 @@ def _template_from(batch):
 
 @pytest.mark.parametrize("aux", [False, True])
 @pytest.mark.parametrize("obs_bf16", [False, True])
-def test_grouped_pack_bitwise_matches_dense(aux, obs_bf16):
-    """dt_pack_batch with row strides (writing into the fused-H2D group
-    buffers through leaf views) must produce BITWISE the batch the dense
-    path does, and the group buffers must equal io.pack(dense) — i.e.
-    eliminating the regroup copy changes no byte of what ships. Frames
+def test_strided_pack_bitwise_matches_dense(aux, obs_bf16):
+    """dt_pack_batch with row strides (writing into the fused-H2D
+    transfer buffer through leaf views: byte-offset strides into one
+    [B, row_bytes] u8 buffer) must produce BITWISE the batch the dense
+    path does, and the buffer must equal io.pack_transfer(dense) — i.e.
+    packing in place changes no byte of what ships. Frames
     salted with NaNs and RNE ties so the bf16 in-copy cast is exercised
     on its hard cases through the strided path too."""
     rollouts = [make_rollout(L=3 + (i % 4), H=8, seed=i, aux=aux, actor_id=i) for i in range(6)]
@@ -391,7 +392,7 @@ def test_grouped_pack_bitwise_matches_dense(aux, obs_bf16):
 
     dense = native.pack_frames(lib, frames, 8, 8, aux, obs_bf16=obs_bf16)
     io = _template_from(dense)
-    groups, out = io.alloc_views()
+    buf, out = io.alloc_transfer()
     native.pack_frames(lib, frames, 8, 8, aux, obs_bf16=obs_bf16, out=out)
     # bitwise: view raw bytes so canonicalized NaNs compare EQUAL (the
     # point of the salt) instead of tripping float NaN != NaN.
@@ -401,28 +402,24 @@ def test_grouped_pack_bitwise_matches_dense(aux, obs_bf16):
         np.testing.assert_array_equal(
             np.ascontiguousarray(a).view(np.uint8), np.ascontiguousarray(b).view(np.uint8)
         )
-    ref_groups = io.pack(dense)
-    assert set(groups) == set(ref_groups)
-    for k in groups:
-        np.testing.assert_array_equal(
-            np.asarray(groups[k]).view(np.uint8), np.asarray(ref_groups[k]).view(np.uint8)
-        )
+    np.testing.assert_array_equal(buf, io.pack_transfer(dense))
 
 
-def test_grouped_pack_rejects_wrong_rows():
+def test_strided_pack_rejects_wrong_rows():
     frames = [serialize_rollout(make_rollout(L=3, H=8, seed=i)) for i in range(4)]
     dense = native.pack_frames(lib, frames, 8, 8, False)
     io = _template_from(dense)
-    groups, out = io.alloc_views()
+    _, out = io.alloc_transfer()
     with pytest.raises(ValueError, match="rows"):
         native.pack_frames(lib, frames[:3], 8, 8, False, out=out)
 
 
 @pytest.mark.parametrize("obs_bf16", [False, True])
-def test_single_buffer_pack_bitwise_matches_dense(obs_bf16):
-    """The C packer writing through SINGLE-buffer leaf views (byte-offset
-    strides into one [B, row_bytes] u8 buffer) must equal the dense pack
-    bitwise, and the buffer must equal pack_transfer of the dense batch."""
+def test_strided_pack_unpacks_on_device_to_dense(obs_bf16):
+    """What the C packer wrote through the transfer buffer's leaf views,
+    unpacked inside a jit as the train step does (segment slices and
+    bitcasts), is bitwise the dense pack: the packer's byte offsets and
+    the device's agree."""
     rollouts = [make_rollout(L=3 + (i % 4), H=8, seed=i, actor_id=i) for i in range(6)]
     for r in rollouts:
         r.obs.global_feats[0, :3] = [np.nan, 1.00390625, -1.00390625]
@@ -430,16 +427,15 @@ def test_single_buffer_pack_bitwise_matches_dense(obs_bf16):
 
     dense = native.pack_frames(lib, frames, 8, 8, False, obs_bf16=obs_bf16)
     io = _template_from(dense)
-    io.single_mode = True
     buf, out = io.alloc_transfer()
     native.pack_frames(lib, frames, 8, 8, False, obs_bf16=obs_bf16, out=out)
     import jax
 
-    for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(out)):
+    on_device = jax.device_get(jax.jit(io.unpack_single)(buf))
+    for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(on_device)):
         np.testing.assert_array_equal(
             np.ascontiguousarray(a).view(np.uint8), np.ascontiguousarray(b).view(np.uint8)
         )
-    np.testing.assert_array_equal(buf, io.pack_transfer(dense))
 
 
 # --- sharded pack (ISSUE 11): row_offset C path + PackPlan -------------
@@ -459,7 +455,7 @@ def test_row_offset_sharded_pack_bitwise_matches_dense():
     dense = native.pack_frames(lib, frames, 8, 8, False, obs_bf16=True)
     io = _template_from(dense)
     for workers in (2, 3):  # 3 over 7 rows = uneven (3/2/2)
-        groups, out = io.alloc_views()
+        _, out = io.alloc_transfer()
         for off, cnt in shard_rows(len(frames), workers):
             native.pack_frames(
                 lib, frames[off : off + cnt], 8, 8, False, obs_bf16=True,
@@ -483,7 +479,7 @@ def test_row_offset_validation():
     frames = [serialize_rollout(make_rollout(L=3, H=8, seed=i)) for i in range(4)]
     dense = native.pack_frames(lib, frames, 8, 8, False)
     io = _template_from(dense)
-    _, out = io.alloc_views()
+    _, out = io.alloc_transfer()
     with pytest.raises(BatchLayoutError):
         native.pack_frames(lib, frames, 8, 8, False, out=out, row_offset=2, total_rows=4)
     with pytest.raises(BatchLayoutError):
@@ -509,8 +505,8 @@ def test_pack_plan_matches_pack_frames_and_reports_absolute_row():
         ]
         frame_sets.append([serialize_rollout(r) for r in rollouts])
     io = _template_from(native.pack_frames(lib, frame_sets[0], 8, 8, False, obs_bf16=True))
-    groups_ref, out_ref = io.alloc_views()
-    groups_plan, out_plan = io.alloc_views()
+    buf_ref, out_ref = io.alloc_transfer()
+    buf_plan, out_plan = io.alloc_transfer()
     plans = [
         native.PackPlan(lib, out_plan, cnt, 8, 8, False, True, off, B)
         for off, cnt in shard_rows(B, 2)
@@ -519,10 +515,7 @@ def test_pack_plan_matches_pack_frames_and_reports_absolute_row():
         native.pack_frames(lib, frames, 8, 8, False, obs_bf16=True, out=out_ref)
         for p in plans:
             p.pack(frames[p.row_offset : p.row_offset + p.n])
-        for k in groups_ref:
-            np.testing.assert_array_equal(
-                groups_ref[k].view(np.uint8), groups_plan[k].view(np.uint8)
-            )
+        np.testing.assert_array_equal(buf_ref, buf_plan)
     # malformed frame in the SECOND shard: error names the absolute row
     bad = list(frame_sets[0])
     bad_row = plans[1].row_offset

@@ -1,13 +1,10 @@
-"""Overlapped learner pipeline (ISSUE 15, --learner.prefetch): the
-PrefetchLane loop's bitwise parity with the serial loop, the PR-7
-zero-loss drain contract through the new prefetch station, the overlap
-phase accounting and the flag-off inertness."""
+"""The learner loop and its prefetch lane: bitwise parity of
+`Learner.run` with the same batches stepped by hand through the per-leaf
+tree step, the zero-loss drain contract through the prefetch station,
+the phase accounting, and the refusal of the flags that went with the
+serial loop and the grouped layout."""
 
 import json
-import os
-import subprocess
-import sys
-import textwrap
 import threading
 import time
 
@@ -27,19 +24,16 @@ from dotaclient_tpu.transport import memory as mem
 from dotaclient_tpu.transport.base import connect
 from dotaclient_tpu.transport.serialize import serialize_rollout
 
-from conftest import clean_subprocess_env
 from test_transport import make_rollout
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 POL = dict(unit_embed_dim=16, lstm_hidden=8, mlp_hidden=16, dtype="float32")
 
 
-def _cfg(name, tmp_path, prefetch=True, obs=False, **kw):
-    cfg = LearnerConfig(
+def _cfg(name, tmp_path, obs=False, dtype="float32", **kw):
+    return LearnerConfig(
         batch_size=8,
         seq_len=4,
-        policy=PolicyConfig(**POL),
+        policy=PolicyConfig(**{**POL, "dtype": dtype}),
         broker_url=f"mem://{name}",
         log_dir=str(tmp_path / name),
         metrics_every=2,
@@ -47,17 +41,18 @@ def _cfg(name, tmp_path, prefetch=True, obs=False, **kw):
         obs=ObsConfig(enabled=obs, install_handlers=False),
         **kw,
     )
-    cfg.learner.prefetch = prefetch
-    return cfg
+
+
+def _frames(n, seed0=0):
+    return [
+        serialize_rollout(make_rollout(L=4, H=8, version=0, seed=seed0 + i, actor_id=i))
+        for i in range(n)
+    ]
 
 
 def _feed(broker, n, seed0=0):
-    for i in range(n):
-        broker.publish_experience(
-            serialize_rollout(
-                make_rollout(L=4, H=8, version=0, seed=seed0 + i, actor_id=i)
-            )
-        )
+    for frame in _frames(n, seed0):
+        broker.publish_experience(frame)
 
 
 def _state_hash(state):
@@ -69,39 +64,51 @@ def _state_hash(state):
     return h.hexdigest()
 
 
-def _run_arm(name, tmp_path, prefetch, steps):
-    from dotaclient_tpu.runtime.learner import Learner
-
-    mem.reset(name)
-    broker = connect(f"mem://{name}")
-    _feed(broker, 8 * steps)
-    learner = Learner(_cfg(name, tmp_path, prefetch=prefetch), connect(f"mem://{name}"))
-    try:
-        done = learner.run(num_steps=steps, batch_timeout=60.0, max_idle=3)
-        assert done == steps
-        return _state_hash(learner.state), learner
-    finally:
-        learner.close()
-
-
 # ------------------------------------------------------- bitwise parity
 
 
-def test_pipelined_bitwise_identical_to_serial(tmp_path):
-    """The tentpole contract: the PrefetchLane is the same single FIFO
-    staging consumer, so batch order is unchanged and K pipelined steps
-    produce BITWISE the serial params + optimizer state over the same
-    frame schedule (the RESUME_SOAK-style lockstep argument;
-    scripts/ab_overlap.py runs the same proof on both transfer
-    layouts)."""
-    h_serial, _ = _run_arm("pf_par_ser", tmp_path, False, 3)
-    h_pipe, learner = _run_arm("pf_par_pipe", tmp_path, True, 3)
-    assert h_serial == h_pipe
-    # lane torn down with the run; the staging probe stays attached and
-    # reads "nothing held"
-    assert learner._prefetch_lane is None
-    assert learner.staging._prefetch_probe is not None
-    assert not learner._prefetch_holding()
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_loop_matches_hand_stepped_tree_path(tmp_path, dtype):
+    """The loop's contract, against a reference that shares none of it:
+    the lane is the single FIFO staging consumer, so N steps of
+    `Learner.run` (prefetch lane, fused u8 buffer, unpack inside the
+    step) leave BITWISE the params + optimizer state of the same frames,
+    batched in arrival order by hand and stepped through
+    `build_train_step` (dense leaves, per-leaf device_put, no loop)."""
+    from dotaclient_tpu.ops.batch import zeros_train_batch
+    from dotaclient_tpu.parallel.train_step import build_train_step, init_train_state
+    from dotaclient_tpu.runtime.learner import Learner
+    from dotaclient_tpu.runtime.staging import cast_obs_to_compute_dtype, fill_rollouts
+    from dotaclient_tpu.transport.serialize import deserialize_rollout
+
+    name, steps = f"pf_par_{dtype}", 3
+    mem.reset(name)
+    cfg = _cfg(name, tmp_path, dtype=dtype)
+    B, T = cfg.batch_size, cfg.seq_len
+    frames = _frames(B * steps)
+    broker = connect(f"mem://{name}")
+    for frame in frames:
+        broker.publish_experience(frame)
+    learner = Learner(cfg, connect(f"mem://{name}"))
+    try:
+        assert learner.fused_io is not None
+        assert learner.run(num_steps=steps, batch_timeout=60.0, max_idle=3) == steps
+        h_loop = _state_hash(learner.state)
+        # lane torn down with the run; the staging probe stays attached
+        # and reads "nothing held"
+        assert learner._prefetch_lane is None
+        assert learner.staging._prefetch_probe is not None
+        assert not learner._prefetch_holding()
+    finally:
+        learner.close()
+
+    step, state_sh, batch_sh = build_train_step(cfg, learner.mesh)
+    state = jax.device_put(init_train_state(cfg, jax.random.PRNGKey(cfg.seed)), state_sh)
+    for i in range(steps):
+        batch = zeros_train_batch(B, T, cfg.policy.lstm_hidden, cfg.policy.aux_heads)
+        fill_rollouts(batch, [deserialize_rollout(f) for f in frames[B * i : B * (i + 1)]], T)
+        state, _ = step(state, jax.device_put(cast_obs_to_compute_dtype(cfg, batch), batch_sh))
+    assert _state_hash(state) == h_loop
 
 
 # ------------------------------------------------- drain through the lane
@@ -121,7 +128,6 @@ def test_sigterm_drain_trains_out_inflight_prefetch(tmp_path):
     cfg = _cfg(
         "pf_drain",
         tmp_path,
-        prefetch=True,
         checkpoint_dir=str(tmp_path / "ck"),
         ckpt=CkptConfig(full_state=True),
     )
@@ -181,120 +187,42 @@ def test_drained_false_while_lane_holds():
     assert sb.drained()
 
 
-# -------------------------------------------------- flag-off inertness
+# ------------------------------------------------------ removed flags
 
 
-def test_prefetch_off_builds_no_lane(tmp_path, monkeypatch):
-    """--learner.prefetch false: the serial loop never constructs a
-    PrefetchLane (monkeypatch-proof), attaches no staging probe, and
-    emits no pipeline_* scalars."""
-    from dotaclient_tpu.runtime import learner as learner_mod
+@pytest.mark.parametrize(
+    "flag",
+    ["--learner.prefetch", "--learner.prefetch_depth", "--fused_h2d", "--fused_single_h2d"],
+)
+def test_removed_flags_are_refused(flag, capsys):
+    """A manifest that still passes a flag of the serial loop or the
+    grouped layout fails at boot, naming the flag — not silently."""
+    from dotaclient_tpu.config import parse_config
 
-    class _Boom:
-        def __init__(self, *a, **kw):
-            raise AssertionError("PrefetchLane constructed with prefetch off")
-
-    monkeypatch.setattr(learner_mod, "PrefetchLane", _Boom)
-    mem.reset("pf_off")
-    broker = connect("mem://pf_off")
-    _feed(broker, 16)
-    learner = learner_mod.Learner(
-        _cfg("pf_off", tmp_path, prefetch=False), connect("mem://pf_off")
-    )
-    try:
-        assert learner.staging._prefetch_probe is None
-        steps = learner.run(num_steps=2, batch_timeout=60.0, max_idle=3)
-    finally:
-        learner.close()
-    assert steps == 2
-    recs = [
-        json.loads(l)
-        for l in (tmp_path / "pf_off" / "metrics.jsonl").read_text().splitlines()
-    ]
-    assert recs
-    assert all(not any(k.startswith("pipeline_") for k in r) for r in recs)
+    with pytest.raises(SystemExit) as e:
+        parse_config(LearnerConfig(), [flag, "1"])
+    assert e.value.code == 2
+    assert f"unrecognized arguments: {flag}" in capsys.readouterr().err
 
 
-@pytest.mark.slow  # full subprocess learner boot
-def test_prefetch_off_subprocess_inertness(tmp_path):
-    """Subprocess proof: a --learner.prefetch false learner runs with no
-    'learner-prefetch' thread ever observed and logs no pipeline_*
-    scalar — the serial rollback path is structurally the pre-ISSUE-15
-    loop."""
-    code = textwrap.dedent(
-        f"""
-        import json, os, sys, threading
-        sys.path.insert(0, {REPO_ROOT!r})
-        sys.path.insert(0, os.path.join({REPO_ROOT!r}, "tests"))
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-        from test_transport import make_rollout
-        from dotaclient_tpu.config import LearnerConfig, PolicyConfig, PPOConfig
-        from dotaclient_tpu.runtime.learner import Learner
-        from dotaclient_tpu.transport.base import connect
-        from dotaclient_tpu.transport.serialize import serialize_rollout
-
-        seen = set()
-        stop = False
-        def sampler():
-            while not stop:
-                seen.update(t.name for t in threading.enumerate())
-        th = threading.Thread(target=sampler, daemon=True)
-        th.start()
-        cfg = LearnerConfig(
-            batch_size=8, seq_len=4,
-            policy=PolicyConfig(unit_embed_dim=16, lstm_hidden=8, mlp_hidden=16,
-                                dtype="float32"),
-            broker_url="mem://pf_sub", log_dir={str(tmp_path / "sub")!r},
-            metrics_every=1, ppo=PPOConfig(max_staleness=1_000_000),
-        )
-        cfg.learner.prefetch = False
-        broker = connect("mem://pf_sub")
-        for i in range(16):
-            broker.publish_experience(serialize_rollout(
-                make_rollout(L=4, H=8, version=0, seed=i, actor_id=i)))
-        learner = Learner(cfg, connect("mem://pf_sub"))
-        try:
-            assert learner.run(num_steps=2, batch_timeout=60.0, max_idle=3) == 2
-        finally:
-            stop = True
-            learner.close()
-        assert "learner-prefetch" not in seen, sorted(seen)
-        recs = [json.loads(l) for l in open(os.path.join({str(tmp_path / "sub")!r},
-                                            "metrics.jsonl"))]
-        assert all(not any(k.startswith("pipeline_") for k in r) for r in recs)
-        print("INERT_OK")
-        """
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True,
-        text=True,
-        timeout=300,
-        env=clean_subprocess_env(),
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "INERT_OK" in proc.stdout
-
-
-# ------------------------------------------------ overlap phase accounting
+# -------------------------------------------------------- phase accounting
 
 
 def test_step_phase_timer_overlap_mode_unit():
-    """StepPhaseTimer(overlap=True): lane sums live apart from the loop
+    """StepPhaseTimer: lane sums live apart from the loop
     sums, phases still tile the wall, and the pipeline_* scalars carry
     the overlap arithmetic (ratio = share of lane work not exposed as
     loop take-wait)."""
     from dotaclient_tpu.obs.compute import StepPhaseTimer
 
-    t = StepPhaseTimer(overlap=True)
+    t = StepPhaseTimer()
     for _ in range(2):
         t.add("fetch", 0.1)  # loop lane: exposed take-wait
         t.add("device_step", 0.8)
         t.add("host", 0.1)
-        t.add_overlap("fetch", 0.3)  # prefetch lane, hidden
-        t.add_overlap("pack", 0.1)
-        t.add_overlap("h2d", 0.1)
+        t.add_lane("fetch", 0.3)  # prefetch lane, hidden
+        t.add_lane("pack", 0.1)
+        t.add_lane("h2d", 0.1)
         t.step(1.0)
     sc = t.window_scalars()
     assert sc["compute_phase_wall_s"] == pytest.approx(1.0)
@@ -312,19 +240,18 @@ def test_step_phase_timer_overlap_mode_unit():
 
 
 def test_pipelined_phases_tile_wall_and_emit_pipeline_family(tmp_path):
-    """The satellite-1 acceptance: under the pipelined loop with
-    step_phases on, compute_phase_* still tiles the wall (overlap mode,
-    no per-step fence) and the pipeline_* lane family is emitted."""
+    """With step_phases on, compute_phase_* tiles the wall (no per-step
+    fence) and the pipeline_* lane family is emitted."""
     from dotaclient_tpu.runtime.learner import Learner
 
     mem.reset("pf_phases")
     broker = connect("mem://pf_phases")
     _feed(broker, 32)
     learner = Learner(
-        _cfg("pf_phases", tmp_path, prefetch=True, obs=True), connect("mem://pf_phases")
+        _cfg("pf_phases", tmp_path, obs=True), connect("mem://pf_phases")
     )
     try:
-        assert learner.obs.compute.timer.overlap  # overlap mode armed
+        assert learner.obs.compute.timer is not None
         steps = learner.run(num_steps=4, batch_timeout=60.0, max_idle=3)
     finally:
         learner.close()
@@ -354,8 +281,8 @@ def test_pipelined_phases_tile_wall_and_emit_pipeline_family(tmp_path):
 
 
 def test_pipeline_family_registered():
-    """Registry pins for the new family: every pipeline_* scalar the
-    pipelined loop emits resolves through the documented prefix."""
+    """Registry pins: every pipeline_* scalar the loop emits resolves
+    through the documented prefix."""
     from dotaclient_tpu.obs import registry
 
     for name in (
@@ -367,33 +294,3 @@ def test_pipeline_family_registered():
         "pipeline_overlap_ratio",
     ):
         assert registry.is_registered(name), name
-
-
-# ------------------------------------------------------ nightly re-run
-
-
-@pytest.mark.nightly  # full A/B re-run: two learners x two layouts + compiles
-@pytest.mark.slow  # nightly-heavy must ALSO be slow (tier-1 -m override)
-def test_overlap_ab_quick_all_green(tmp_path):
-    """Nightly lane: re-run scripts/ab_overlap.py --quick and assert the
-    same invariants hold live (on a capable host the probe re-arms the
-    full 0.98 bar automatically)."""
-    out = tmp_path / "OVERLAP_AB.json"
-    proc = subprocess.run(
-        [
-            sys.executable,
-            os.path.join(REPO_ROOT, "scripts", "ab_overlap.py"),
-            "--quick",
-            "--out",
-            str(out),
-        ],
-        capture_output=True,
-        text=True,
-        timeout=1800,
-        cwd=REPO_ROOT,
-        env=clean_subprocess_env(),
-    )
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    art = json.loads(out.read_text())
-    assert art["verdict"]["all_green"] is True
-    assert art["parity"]["all_identical"] is True

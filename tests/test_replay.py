@@ -286,9 +286,6 @@ def test_fused_pack_row_mismatch_is_layout_error():
 
     small = pack_rollouts([make_rollout(L=3, H=8, seed=i) for i in range(2)], 8, False)
     with pytest.raises(BatchLayoutError):
-        io.pack(small)
-    io.single_mode = True
-    with pytest.raises(BatchLayoutError):
         io.pack_transfer(small)
 
 
@@ -415,13 +412,13 @@ def test_fused_build_refuses_replay():
     import jax
 
     from dotaclient_tpu.parallel import mesh as mesh_lib
-    from dotaclient_tpu.parallel.train_step import build_fused_train_step
+    from dotaclient_tpu.parallel.train_step import build_single_train_step
 
     cfg = LearnerConfig(batch_size=2, seq_len=8, policy=SMALL)
     cfg.replay = replay_cfg()
     mesh = mesh_lib.make_mesh("dp=1", devices=jax.devices()[:1])
     with pytest.raises(ValueError, match="replay"):
-        build_fused_train_step(cfg, mesh)
+        build_single_train_step(cfg, mesh)
 
 
 # ------------------------------------------------------------------- soak

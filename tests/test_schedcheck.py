@@ -164,7 +164,7 @@ def test_prefetch_model_matches_real_lane():
         observed_holding_during_fetch.append(lane_box[0].holding())
         return item, 1, 0.0, 0.0, None
 
-    lane = PrefetchLane(fetch, depth=1, limit=2)
+    lane = PrefetchLane(fetch, limit=2)
     lane_box.append(lane)
     lane.start()
     got = []
@@ -341,7 +341,7 @@ def _stub_ring(depth=2):
     from dotaclient_tpu.parallel.fused_io import TransferRing
 
     def alloc_transfer():
-        payload = {"f32": np.ones((2, 8), np.float32)}
+        payload = np.ones((2, 32), np.uint8)
         batch = SimpleNamespace(
             obs=SimpleNamespace(
                 action_mask=np.zeros((2, 3, F.N_ACTION_TYPES), bool)
@@ -364,13 +364,13 @@ def test_ring_model_matches_real_transfer_ring():
     b = ring.acquire(timeout=1)
     assert a is not None and b is not None and a is not b
     assert ring.acquire(timeout=0.05) is None  # backpressure: all leased
-    assert (a.payload["f32"] == 0).all()  # acquire re-zeroed the buffer
-    a.payload["f32"][:] = 7.0
+    assert (a.payload == 0).all()  # acquire re-zeroed the buffer
+    a.payload[:] = 7
     a.release()
     a.release()  # idempotent: must NOT duplicate the slot
     assert ring.occupancy == 1
     c = ring.acquire(timeout=1)
-    assert c is a and (c.payload["f32"] == 0).all()
+    assert c is a and (c.payload == 0).all()
     assert ring.acquire(timeout=0.05) is None  # no phantom second copy
     b.release()
     c.release()

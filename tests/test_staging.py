@@ -425,11 +425,11 @@ def _bitwise_equal(a, b):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("native_on", [False, True])
-def test_staging_fused_groups_match_dense_path(dtype, native_on):
-    """A fused staging buffer (packs into group-buffer views, native OR
-    python fallback) must emit bitwise the batch a dense buffer emits
-    through pack+cast, and its groups must equal io.pack of that dense
-    batch — the regroup-copy elimination ships identical bytes. Salted
+def test_staging_fused_payload_matches_dense_path(dtype, native_on):
+    """A fused staging buffer (packs into transfer-buffer views, native
+    OR python fallback) must emit bitwise the batch a dense buffer emits
+    through pack+cast, and its payload must equal io.pack_transfer of
+    that dense batch — packing in place ships identical bytes. Salted
     with NaN/RNE-tie obs so the fallback's assignment-cast is pinned to
     astype on the hard cases."""
     cfg = LearnerConfig(
@@ -456,35 +456,31 @@ def test_staging_fused_groups_match_dense_path(dtype, native_on):
         for f in frames:
             pub_a.publish_experience(f)
             pub_b.publish_experience(f)
-        batch_f, groups = fused.get_batch_groups(timeout=30.0)
-        # dense buffers answer get_batch_groups too, with groups=None —
+        batch_f, buf = fused.get_batch_groups(timeout=30.0)
+        # dense buffers answer get_batch_groups too, with payload None —
         # read the dense batch THROUGH that API so the tuple contract is
         # actually pinned (not just the empty-queue timeout path).
-        batch_d, groups_d = dense.get_batch_groups(timeout=30.0)
-        assert groups_d is None
-        assert groups is not None and batch_f is not None and batch_d is not None
+        batch_d, buf_d = dense.get_batch_groups(timeout=30.0)
+        assert buf_d is None
+        assert batch_f is not None and batch_d is not None
+        assert isinstance(buf, np.ndarray) and buf.dtype == np.uint8
+        assert buf.shape == (cfg.batch_size, io.row_bytes)
         _bitwise_equal(batch_f, batch_d)
-        ref = io.pack(batch_d)
-        assert set(groups) == set(ref)
-        for k in groups:
-            np.testing.assert_array_equal(
-                groups[k].view(np.uint8), np.asarray(ref[k]).view(np.uint8)
-            )
-        # the batch leaves genuinely alias the group buffers (no copy)
-        assert any(
-            np.may_share_memory(leaf, buf)
-            for buf in groups.values()
-            for leaf in [np.asarray(batch_f.mask)]
-        )
+        np.testing.assert_array_equal(buf, io.pack_transfer(batch_d))
+        # the batch leaves genuinely alias the transfer buffer (no copy)
+        assert np.may_share_memory(np.asarray(batch_f.mask), buf)
         # empty queue: the timeout path returns (None, None)
         assert dense.get_batch_groups(timeout=0.1) == (None, None)
     finally:
         fused.stop(), dense.stop()
 
 
-def test_staging_fused_single_buffer_matches_dense():
-    """Single-buffer staging (one u8 transfer payload) emits bitwise the
-    dense batch, and the payload equals pack_transfer of that batch."""
+def test_staging_fused_payload_unpacks_on_device_to_dense():
+    """The u8 payload a fused staging buffer hands over, unpacked inside
+    a jit as the train step does, is bitwise the batch a dense buffer
+    emits: host packer and device unpack agree on every byte offset."""
+    import jax
+
     cfg = LearnerConfig(
         batch_size=4,
         seq_len=8,
@@ -496,7 +492,6 @@ def test_staging_fused_single_buffer_matches_dense():
     frames = [serialize_rollout(r) for r in rollouts]
 
     io = _fused_io_for(cfg)
-    io.single_mode = True
     mem.reset("fsb_a"), mem.reset("fsb_b")
     fused = StagingBuffer(cfg, connect("mem://fsb_a"), fused_io=io).start()
     dense = StagingBuffer(cfg, connect("mem://fsb_b")).start()
@@ -507,10 +502,8 @@ def test_staging_fused_single_buffer_matches_dense():
             pub_b.publish_experience(f)
         batch_f, buf = fused.get_batch_groups(timeout=30.0)
         batch_d = dense.get_batch(timeout=30.0)
-        assert isinstance(buf, np.ndarray) and buf.dtype == np.uint8
-        assert buf.shape == (cfg.batch_size, io.row_bytes)
-        _bitwise_equal(batch_f, batch_d)
-        np.testing.assert_array_equal(buf, io.pack_transfer(batch_d))
+        assert batch_f is not None
+        _bitwise_equal(jax.device_get(jax.jit(io.unpack_single)(buf)), batch_d)
     finally:
         fused.stop(), dense.stop()
 
@@ -553,7 +546,7 @@ def _mixed_wire_frames(n, with_traced=True):
 
 def _drain_batches(cfg, frames, fused, n_batches):
     """Run one staging buffer to completion; returns materialized batch
-    copies (+ the groups payload bytes per batch when fused)."""
+    copies (+ a copy of the u8 transfer payload per batch when fused)."""
     import copy as _copy
 
     import jax
@@ -571,15 +564,11 @@ def _drain_batches(cfg, frames, fused, n_batches):
     batches, payloads = [], []
     try:
         for _ in range(n_batches):
-            b, groups = sb.get_batch_groups(timeout=30)
+            b, payload = sb.get_batch_groups(timeout=30)
             assert b is not None
             batches.append(jax.tree.map(lambda a: np.array(a), b))
-            if groups is not None:
-                payloads.append(
-                    {k: np.array(v) for k, v in groups.items()}
-                    if isinstance(groups, dict)
-                    else np.array(groups)
-                )
+            if payload is not None:
+                payloads.append(np.array(payload))
             lease = sb.last_batch_lease
             if lease is not None:
                 lease.release()
@@ -605,10 +594,9 @@ def test_pack_workers_sharded_fused_bitwise_parity(native_on, workers):
     )
     for a, b in zip(base_b, got_b):
         _bitwise_equal(a, b)
+    assert len(base_p) == len(got_p) == 3
     for pa, pb in zip(base_p, got_p):
-        assert set(pa) == set(pb)
-        for k in pa:
-            np.testing.assert_array_equal(pa[k].view(np.uint8), pb[k].view(np.uint8))
+        np.testing.assert_array_equal(pa, pb)
     # scoreboard meters exist only in pool mode
     assert stats["pack_workers"] == workers
     assert stats["pack_ring_depth"] == 2.0
@@ -647,9 +635,9 @@ def test_transfer_ring_lease_backpressure_and_reuse():
         held = []
         ids = []
         for _ in range(2):
-            b, groups = sb.get_batch_groups(timeout=30)
+            b, payload = sb.get_batch_groups(timeout=30)
             assert b is not None
-            ids.append(id(next(iter(groups.values()))))
+            ids.append(id(payload))
             held.append(sb.last_batch_lease)
             assert held[-1] is not None
         # both slots leased: no third batch can form
@@ -657,10 +645,10 @@ def test_transfer_ring_lease_backpressure_and_reuse():
         assert b3 is None
         held[0].release()
         held[0].release()  # idempotent: a double release must not fork the slot
-        b3, groups3 = sb.get_batch_groups(timeout=30)
+        b3, payload3 = sb.get_batch_groups(timeout=30)
         assert b3 is not None
-        # the freed slot's buffers are REUSED, not reallocated
-        assert id(next(iter(groups3.values()))) == ids[0]
+        # the freed slot's buffer is REUSED, not reallocated
+        assert id(payload3) == ids[0]
         lease3 = sb.last_batch_lease
         assert lease3 is not None
         lease3.release()

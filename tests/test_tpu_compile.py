@@ -106,7 +106,7 @@ def _compile_train_step(cfg: LearnerConfig, devices):
     state = jax.eval_shape(lambda: init_train_state(cfg, jax.random.PRNGKey(0)))
     payload, _ = io.alloc_transfer()
     return step.lower(
-        _on(state_shardings, state), _on(io.transfer_shardings(), payload)
+        _on(state_shardings, state), _on(io.sharding, payload)
     ).compile()
 
 
