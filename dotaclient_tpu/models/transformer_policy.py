@@ -151,8 +151,9 @@ class ExpertLayer(nn.Module):
     def __call__(self, h: jnp.ndarray, seen=None):
         """h [B, T, D] f32, normed. `seen` (step mode, T == 1): the
         sums `moe.standardize` carries over the frames before this one
-        [B, 2, E], and their count [B]. Returns ([B, T, D] f32,
-        pairs per held expert, the sums with this frame's or None)."""
+        [B, 2, E], and their count [B]. Returns ([B, T, D] f32, (pairs
+        per held expert, passes over the layer's buffer), the sums with
+        this frame's or None)."""
         cfg = self.cfg
         D, E, I = h.shape[-1], cfg.moe_experts, cfg.moe_hidden
         held = cfg.moe_experts_held or E
@@ -181,12 +182,12 @@ class ExpertLayer(nn.Module):
         if impl == "auto":
             impl = "megablox" if (self.platform or jax.default_backend()) == "tpu" else "ragged_dot"
         x = h.reshape(-1, D)
-        by_expert = lambda w: jnp.swapaxes(w.astype(dt), 0, 1)
-        y, sizes = moe.expert_layer(
+        y, *counts = moe.expert_layer(
             x.astype(dt), moe.route(scores.reshape(-1, E), cfg.moe_top_k),
-            by_expert(w_gate), by_expert(w_up), by_expert(w_down), cfg.moe_first_expert, impl,
+            moe.by_expert(w_gate, dt), moe.by_expert(w_up, dt), moe.by_expert(w_down, dt),
+            cfg.moe_first_expert, impl, moe.buffer_rows(x.shape[0] * cfg.moe_top_k, held, E),
         )
-        return y.reshape(h.shape), sizes, sums
+        return y.reshape(h.shape), tuple(counts), sums
 
 
 class Block(nn.Module):
@@ -217,8 +218,8 @@ class Block(nn.Module):
         the router's running sums [B,2,E]):
         T==1 stepping — the block writes its fresh K/V into the cache at
         write_onehot and attends over the merged cache; a sliding layer
-        masks by position. Returns (x_out, new cache or None, pairs per
-        held expert or None)."""
+        masks by position. Returns (x_out, new cache or None, the
+        routed-expert layer's counts or None)."""
         cfg = self.cfg
         D = cfg.lstm_hidden
         N, G, Dh = head_shape(cfg)
@@ -267,8 +268,8 @@ class Block(nn.Module):
         if cfg.moe_experts:
             with jax.named_scope("moe"):
                 seen = None if cache is None else (cache[4], positions[:, 0])
-                y, sizes, rsum = ExpertLayer(cfg, self.platform, name="moe")(_norm(cfg, "ln2")(x), seen)
-            return x + y, new_cache and new_cache + (rsum,), sizes
+                y, counts, rsum = ExpertLayer(cfg, self.platform, name="moe")(_norm(cfg, "ln2")(x), seen)
+            return x + y, new_cache and new_cache + (rsum,), counts
         with jax.named_scope("mlp"):
             h = _norm(cfg, "ln2")(x)
             h = dense(4 * D, "mlp_up")(h.astype(dt))
@@ -277,18 +278,22 @@ class Block(nn.Module):
             return x + h.astype(jnp.float32), new_cache and new_cache + (cache[4],), None
 
 
-def _moe_stats(sizes) -> dict:
-    """The step's routing counters from each layer's pairs per held
-    expert: the most loaded held expert over the mean, of the worst
-    layer, and the pairs computed here over all layers. Empty without a
-    routed-expert layer."""
-    if sizes[0] is None:
+def _moe_stats(counts) -> dict:
+    """The step's routing counters from each layer's (pairs per held
+    expert, passes over its buffer): the most loaded held expert over the
+    mean, of the worst layer, the pairs computed here over all layers,
+    and the passes over the buffers of `moe.buffer_rows` rows, all layers
+    (one a layer where its held pairs fit). Empty without a routed-expert
+    layer."""
+    if counts[0] is None:
         return {}
+    sizes, passes = zip(*counts)
     per_layer = jnp.stack(sizes).astype(jnp.float32)  # [L, held]
     mean = jnp.maximum(jnp.mean(per_layer, axis=-1), 1e-9)
     return {
         "moe_load_max_over_mean": jnp.max(jnp.max(per_layer, axis=-1) / mean),
         "moe_local_pairs": jnp.sum(per_layer),
+        "moe_passes": jnp.sum(jnp.stack(passes)),
     }
 
 
@@ -328,11 +333,11 @@ class TransformerCore(nn.Module):
             # name is the policy's), so its forward pass runs once a step.
             keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS) if fused else None
             block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
-            sizes = []
+            counts = []
             for i, kind in enumerate(kinds):
                 h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, fused, name=f"block{i}")(h, positions)
-                sizes.append(n)
-            stats = {"attn_fused_layers": jnp.float32(len(kinds) if fused else 0), **_moe_stats(sizes)}
+                counts.append(n)
+            stats = {"attn_fused_layers": jnp.float32(len(kinds) if fused else 0), **_moe_stats(counts)}
             return carry, final(h), stats
 
         assert isinstance(carry, KVCache), "transformer step mode needs a KVCache carry"

@@ -1580,7 +1580,7 @@ def main(argv=None):
         stats = learner.staging.stats()
         _log.info(
             "learner done: version=%d env_steps=%d wire_frames=%d weights_published=%d "
-            "compile_cache=%s hits=%d misses=%d attn_fused_layers=%d",
+            "compile_cache=%s hits=%d misses=%d attn_fused_layers=%d moe_passes=%d",
             learner.version,
             learner.env_steps_done,
             stats["wire_frames_obs_f32"] + stats["wire_frames_obs_bf16"],
@@ -1591,6 +1591,9 @@ def main(argv=None):
             # layers of the compiled unroll whose attention took the fused
             # kernel, as the last metrics window had it (transformer family)
             learner.metrics.latest().get("attn_fused_layers", 0),
+            # passes of the routed-expert layers over their buffers (ops/moe.py
+            # buffer_rows; one a layer where the held pairs fit), same window
+            learner.metrics.latest().get("moe_passes", 0),
         )
 
 

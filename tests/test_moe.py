@@ -1,7 +1,8 @@
 """The routed-expert layer (ops/moe.py): the router against its written
-equations, a share's partial sum against a loop over its experts, the
-shares of an expert-parallel group adding up to the whole layer, and no
-pair dropped however skewed the routing."""
+equations, a share's partial sum against a loop over its experts, in a
+buffer of every pair and in smaller ones that take one pass or several,
+the shares of an expert-parallel group adding up to the whole layer, and
+no pair dropped however skewed the routing."""
 
 import jax
 import jax.numpy as jnp
@@ -32,9 +33,9 @@ def _written(x, wr, wg, wu, wd, lo, hi, top_k=K):
     return y
 
 
-def _program(x, wr, wg, wu, wd, lo, hi, top_k=K):
+def _program(x, wr, wg, wu, wd, lo, hi, top_k=K, impl="ragged_dot", rows=None):
     routing = moe.route(moe.router_scores(x, wr), top_k)
-    return moe.expert_layer(x, routing, wg[lo:hi], wu[lo:hi], wd[lo:hi], lo)
+    return moe.expert_layer(x, routing, wg[lo:hi], wu[lo:hi], wd[lo:hi], lo, impl, rows)
 
 
 def test_router_against_its_written_equations():
@@ -53,19 +54,62 @@ def test_router_against_its_written_equations():
     assert (half.experts == again.experts).all() and half.weights.dtype == jnp.float32
 
 
-@pytest.mark.parametrize("lo,hi", [(0, 8), (2, 4), (6, 8)])
-def test_a_share_against_the_loop_over_its_experts(lo, hi):
-    args = _weights(1)
-    got, sizes = _program(*args, lo, hi)
+def _against_the_written_layer(args, lo, hi, **how):
+    """The program's forward and its gradients of x, the router and the
+    three matrices against the written layer's; returns the program's
+    counts and gradients."""
+    got, *counts = jax.jit(lambda *a: _program(*a, lo, hi, **how))(*args)
     np.testing.assert_allclose(got, _written(*args, lo, hi), rtol=1e-5, atol=1e-5)
-    chosen = np.asarray(moe.route(moe.router_scores(args[0], args[1]), K).experts)
-    assert sizes.tolist() == [(chosen == e).sum() for e in range(lo, hi)]
-    g_got = jax.grad(lambda *a: jnp.sum(jnp.sin(_program(*a, lo, hi)[0])), argnums=(0, 1, 2, 3, 4))(*args)
+    g_got = jax.grad(lambda *a: jnp.sum(jnp.sin(_program(*a, lo, hi, **how)[0])), argnums=(0, 1, 2, 3, 4))(*args)
     g_want = jax.grad(lambda *a: jnp.sum(jnp.sin(_written(*a, lo, hi))), argnums=(0, 1, 2, 3, 4))(*args)
     for a, b in zip(g_got, g_want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    return counts, g_got
+
+
+@pytest.mark.parametrize("lo,hi", [(0, 8), (2, 4), (6, 8)])
+def test_a_share_against_the_loop_over_its_experts(lo, hi):
+    args = _weights(1)
+    (sizes, _), g_got = _against_the_written_layer(args, lo, hi)
+    chosen = np.asarray(moe.route(moe.router_scores(args[0], args[1]), K).experts)
+    assert sizes.tolist() == [(chosen == e).sum() for e in range(lo, hi)]
     # experts outside the share get no gradient from it
     assert not np.asarray(g_got[2][:lo]).any() and not np.asarray(g_got[2][hi:]).any()
+
+
+# Experts 2 and 3 hold 32 of the 111 pairs (seed 1). Rows of the buffer: every pair (one pass, neither
+# choice nor loop compiled); above the held pairs (the bare pass); exactly the held pairs; under them (two
+# turns of the loop); windows that cut through both groups (five turns); and the kernel over two windows.
+@pytest.mark.parametrize("rows,impl,passes", [
+    (None, "ragged_dot", 1), (F * K, "ragged_dot", 1), (64, "ragged_dot", 1), (32, "ragged_dot", 1),
+    (16, "ragged_dot", 2), (7, "ragged_dot", 5), (16, "megablox_interpret", 2),
+])
+def test_a_buffer_of_fewer_rows_against_the_loop_over_its_experts(rows, impl, passes):
+    """Forward and the gradients of x, the router and the three matrices
+    are the written layer's whatever the buffer's rows: where the held
+    pairs overflow it the passes go on over the next windows."""
+    args = _weights(1)
+    (sizes, took), _ = _against_the_written_layer(args, 2, 4, impl=impl, rows=rows)
+    assert int(sizes.sum()) == 32 and float(took) == passes
+    # the smaller buffer holds a loop on the device, the buffer of every pair does not
+    text = str(jax.make_jaxpr(lambda *a: _program(*a, 2, 4, rows=rows)[0])(*args))
+    assert ("while" in text) == (rows is not None and rows < F * K)
+
+
+def test_the_buffers_rows_follow_the_shapes():
+    """The share of the pairs an even routing sends here and a quarter
+    more, in whole row tiles: every pair for the uncut layer, for the
+    actor's step over a batch and for the small shapes of these tests;
+    40,960 of 131,072 in the benchmark's cell."""
+    assert moe.buffer_rows(16384 * 8, 16, 64) == 40960
+    assert moe.buffer_rows(16384 * 8, 64, 64) == 16384 * 8  # every expert held
+    assert moe.buffer_rows(16 * 8, 16, 64) == 16 * 8  # step mode: the frames are the batch
+    assert moe.buffer_rows(F * K, 2, E) == F * K
+    assert moe.buffer_rows(2048, 2, 8) == 1024 and moe.buffer_rows(2048, 1, 8) == 512
+    for pairs in (512, 1000, 4096, 30000, 131072):
+        for held, experts in ((1, 8), (3, 8), (16, 64), (48, 64)):
+            rows = moe.buffer_rows(pairs, held, experts)
+            assert rows == pairs or (rows % 512 == 0 and pairs * held / experts * moe.HEADROOM <= rows < pairs)
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer():
@@ -76,21 +120,23 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert sum(int(p[1].sum()) for p in parts) == F * K  # every pair computed once, by one share
 
 
-def test_dropless_under_skew():
-    """Every frame to one expert: the buffer holds frames x top_k rows, so
-    a share that gets every pair computes every pair."""
+@pytest.mark.parametrize("rows", [None, 8])
+def test_dropless_under_skew(rows):
+    """Every frame to one expert: a share that gets every pair computes
+    every pair, in the buffer of frames x top_k rows at once and in a
+    small one by as many passes as it takes."""
     x, _, wg, wu, wd = _weights(3)
     wr = jnp.zeros((D, E)).at[:, 5].set(0.0)
     x = jnp.abs(x)
     wr = wr.at[:, 5].set(4.0)  # expert 5 first for every frame, by a wide margin
-    got, sizes = _program(x, wr, wg, wu, wd, 4, 6, top_k=1)
-    assert sizes.tolist() == [0, F]
+    got, sizes, passes = _program(x, wr, wg, wu, wd, 4, 6, top_k=1, rows=rows)
+    assert sizes.tolist() == [0, F] and float(passes) == (5 if rows else 1)
     np.testing.assert_allclose(got, (jax.nn.silu(x @ wg[5]) * (x @ wu[5])) @ wd[5], rtol=1e-5, atol=1e-5)
-    none, sizes = _program(x, wr, wg, wu, wd, 0, 4, top_k=1)
-    assert sizes.tolist() == [0, 0, 0, 0] and not np.asarray(none).any()
+    none, sizes, passes = _program(x, wr, wg, wu, wd, 0, 4, top_k=1, rows=rows)
+    assert sizes.tolist() == [0, 0, 0, 0] and not np.asarray(none).any() and float(passes) == (0 if rows else 1)
     # all top_k choices of every frame held here: the buffer is full
-    full, sizes = _program(*_weights(3), 0, E, top_k=E)
-    assert int(sizes.sum()) == F * E
+    full, sizes, passes = _program(*_weights(3), 0, E, top_k=E, rows=rows)
+    assert int(sizes.sum()) == F * E and float(passes) == (F * E // rows if rows else 1)
     np.testing.assert_allclose(full, _written(*_weights(3), 0, E, top_k=E), rtol=1e-5, atol=1e-5)
 
 
@@ -110,8 +156,8 @@ def test_the_kernel_and_ragged_dot_give_the_same_layer():
     args = _weights(4)
     x, wr, wg, wu, wd = args
     routing = moe.route(moe.router_scores(x, wr), K)
-    want, sizes = moe.expert_layer(x, routing, wg[1:5], wu[1:5], wd[1:5], 1, "ragged_dot")
-    got, sizes_k = moe.expert_layer(x, routing, wg[1:5], wu[1:5], wd[1:5], 1, "megablox_interpret")
+    want, sizes, _ = moe.expert_layer(x, routing, wg[1:5], wu[1:5], wd[1:5], 1, "ragged_dot")
+    got, sizes_k, _ = moe.expert_layer(x, routing, wg[1:5], wu[1:5], wd[1:5], 1, "megablox_interpret")
     assert sizes.tolist() == sizes_k.tolist()
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     grads = [jax.grad(lambda x, wg, wd: jnp.sum(jnp.sin(

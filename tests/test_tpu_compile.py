@@ -166,29 +166,67 @@ def test_transformer_train_step_compiles(topo):
 def test_expert_layer_compiles_at_published_widths(topo, impl):
     """The routed-expert layer of the benchmark's transformer cell, forward
     and backward at its real size (16,384 frames of 2,304, 16 of 64 experts
-    of 896 held, 8 a frame), with either grouped product: a kernel on the
-    chip (nine of them, forward and backward), no row scattered, and the
-    dropless buffer fits."""
+    of 896 held, 8 a frame) in its buffer of 40,960 rows, with either
+    grouped product: a kernel on the chip (twelve to a pass: forward, and
+    in the backward rule the forward again and its transpose; the bare
+    pass and the loop's turn each hold them), the choice between the two
+    and the loop on the device, no row scattered, and with both in it the
+    scratch well under what the buffer of every pair took (2.66 GB)."""
     from dotaclient_tpu.ops import moe
 
     one = SingleDeviceSharding(topo.devices[0])
     frames, D, E, held, I, K = 16384, 2304, 64, 16, 896, 8
+    rows = moe.buffer_rows(frames * K, held, E)
+    assert rows == 40960
 
     def loss(x, wr, wg, wu, wd):
-        y, sizes = moe.expert_layer(x.astype(jnp.bfloat16), moe.route(moe.router_scores(x, wr), K),
-                                    wg, wu, wd, 0, impl)
+        y, sizes, _ = moe.expert_layer(x.astype(jnp.bfloat16), moe.route(moe.router_scores(x, wr), K),
+                                       wg, wu, wd, 0, impl, rows)
         return jnp.sum(y * y), sizes
 
     shapes = [(frames, D), (D, E), (held, D, I), (held, D, I), (held, I, D)]
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one) for s in shapes]
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(*args).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 9
+    assert text.count("tpu_custom_call") >= 24 and " conditional(" in text and " while(" in text
     # no row of frames or of pairs is scattered (the kernel's own tile table, a few hundred integers, is)
-    assert not [ln for ln in text.splitlines() if " scatter(" in ln and ("16384" in ln or "131072" in ln)]
+    assert not [ln for ln in text.splitlines()
+                if " scatter(" in ln and any(n in ln for n in ("16384", "40960", "131072"))]
     assert ("ragged-dot" in text) == (impl == "ragged_dot")
     assert _fits(compiled)
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024**3
+
+
+def test_the_expert_matrices_update_copies_nothing_of_their_shape(topo):
+    """`ExpertLayer` under `jax.checkpoint` with the repo's optimizer, at
+    a size that compiles in seconds: the products' backward kernel writes
+    an expert matrix's gradient by expert, and `moe.by_expert` hands it on
+    in the parameter's own layout. Without that the update runs in the
+    gradient's order and copies each of its outputs back (parameter and
+    both moments: copies that carry no `op_name`, so no layer's time)."""
+    from dotaclient_tpu.models.transformer_policy import ExpertLayer
+    from dotaclient_tpu.parallel.train_step import make_optimizer
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = LearnerConfig(policy=PolicyConfig(
+        arch="transformer", lstm_hidden=256, dtype="bfloat16", moe_experts=8, moe_experts_held=4,
+        moe_top_k=2, moe_hidden=128, moe_impl="megablox"))
+    layer, tx = ExpertLayer(cfg.policy, "tpu"), make_optimizer(cfg)
+    h = jax.ShapeDtypeStruct((4, 512, 256), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(h.shape, h.dtype)))
+    opt_state = jax.eval_shape(tx.init, params)
+
+    def step(params, opt_state, h):
+        loss = lambda p: jnp.sum(jax.checkpoint(lambda p, h: layer.apply(p, h)[0])(p, h) ** 2)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(jax.grad(loss)(params), opt_state, params)
+        return jax.tree.map(jnp.add, params, updates), opt_state
+
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(*_on(one, (params, opt_state, h))).compile().as_text()
+    assert text.count("tpu_custom_call") >= 9
+    unnamed = [ln.strip()[:160] for ln in text.splitlines()
+               if re.search(r"= f32\[(256,4,128|128,4,256)\]\S* copy\(", ln) and "op_name" not in ln]
+    assert not unnamed, unnamed
 
 
 @pytest.mark.parametrize("n_devices", [1, 4])

@@ -3,6 +3,7 @@ chunk-local semantics, SP (ring-attention) train-step parity on the mesh,
 and actor-loop integration."""
 
 import asyncio
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -436,6 +437,58 @@ class TestPublishedBlockShape:
         assert 0 < float(metrics["moe_local_pairs"]) <= frames * 3 * 4
         assert float(metrics["moe_load_max_over_mean"]) >= 1.0
         assert np.isfinite(float(metrics["loss"]))
+
+
+# The published block's shape over enough frames for the expert layer's buffer
+# to be smaller than frames x top_k: 2 rows of 256 frames, 2 choices of 8 experts,
+# 2 held: 1,024 pairs in a buffer of 512 rows (ops/moe.py buffer_rows).
+TF_BUFFERED = dataclasses.replace(
+    TF_MOE, tf_layers=2, tf_layer_kinds="sliding,full", tf_window=100, tf_attn_block=64,
+    moe_experts_held=2, moe_first_expert=0, moe_top_k=2, tf_remat=True,
+)
+
+
+class TestExpertBufferInTheUnroll:
+    B, T = 2, 256
+
+    def _value_and_grad(self, obs):
+        net = P.PolicyNet(TF_BUFFERED)
+        state = P.initial_state(TF_BUFFERED, (self.B,))
+
+        def loss(params):
+            _, out = net.apply(params, state, obs, unroll=True)
+            return jnp.sum(out.value ** 2) + jnp.sum(out.dist.type_logp[..., 0]), out.stats
+
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))
+
+    def test_one_pass_a_layer_under_even_routing_and_more_under_skew(self, monkeypatch):
+        """`moe_passes` in the unroll's counters: the layer count where each
+        layer's held pairs fit its buffer; a layer whose router sends every
+        pair to the held experts takes a second pass, drops nothing, and
+        value and gradients are those of the buffer of every pair."""
+        from dotaclient_tpu.ops import moe
+
+        assert moe.buffer_rows(self.B * self.T * 2, 2, 8) == 512
+        params = P.init_params(TF_BUFFERED, jax.random.PRNGKey(0))
+        obs = _obs(np.random.RandomState(7), self.B, self.T)
+        (_, stats), _ = self._value_and_grad(obs)(params)
+        assert float(stats["moe_passes"]) == 2.0 and float(stats["moe_local_pairs"]) < 2 * 512
+
+        # A router of zeros scores every expert alike, and the first two win
+        # every frame: all 1,024 pairs of block0 are held here.
+        skewed = jax.tree.map(lambda x: x, params)
+        moe0 = skewed["params"]["core"]["tf"]["block0"]["moe"]
+        moe0["router"] = jnp.zeros_like(moe0["router"])
+        (got, stats), g_got = self._value_and_grad(obs)(skewed)
+        assert float(stats["moe_passes"]) == 3.0 and float(stats["moe_local_pairs"]) > 1024
+
+        monkeypatch.setattr(moe, "HEADROOM", 8.0)  # the buffer of every pair: one pass, no loop
+        (want, want_stats), g_want = self._value_and_grad(obs)(skewed)
+        assert float(want_stats["moe_passes"]) == 2.0
+        assert float(want_stats["moe_local_pairs"]) == float(stats["moe_local_pairs"])
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(b)) + 1e-6))
 
 
 # The published block's shape with heads the fused kernel takes (a width of
