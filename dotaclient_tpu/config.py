@@ -70,6 +70,9 @@ class PolicyConfig:
     tf_head_dim: int = 0  # width of one head; 0 = lstm_hidden // tf_heads
     # Kind of each layer, a comma list repeated over tf_layers: "full"
     # (causal) or "sliding" (causal, the last tf_window keys). "" = all full.
+    # "latent" (every layer, or none): causal attention whose queries and
+    # keys/values go through low-rank latents (tf_q_lora_rank and the four
+    # sizes after it, below).
     tf_layer_kinds: str = ""
     tf_window: int = 0  # keys a query of a sliding layer sees, itself included
     tf_rope_theta: float = 10000.0  # rotary base, both kinds
@@ -86,6 +89,24 @@ class PolicyConfig:
     tf_norm_eps: float = 1e-6
     tf_bias: bool = True  # biases on the block's projections
     tf_final_norm: bool = False  # a norm after the last block
+    # Latent attention's sizes (tf_layer_kinds "latent"; tf_heads heads, no
+    # grouping): queries through a normed latent of tf_q_lora_rank; keys
+    # and values from a normed latent of tf_kv_lora_rank, which with one
+    # rotary key of tf_qk_rope_dim shared by every head is all the actor's
+    # cache holds; a head's query and key are tf_qk_nope_dim unrotated
+    # dimensions beside the rotary ones, its value tf_v_head_dim.
+    tf_q_lora_rank: int = 0
+    tf_kv_lora_rank: int = 0
+    tf_qk_nope_dim: int = 0
+    tf_qk_rope_dim: int = 0
+    tf_v_head_dim: int = 0
+    # The dense feed-forward block: "gelu" = two matrices around a GELU,
+    # "swiglu" = (silu(x Wg) * (x Wu)) Wd; width tf_mlp_hidden, 0 = 4x
+    # lstm_hidden. With routed experts the first tf_dense_layers layers
+    # keep the dense block and the layers after them are sparse.
+    tf_mlp_act: str = "gelu"
+    tf_mlp_hidden: int = 0
+    tf_dense_layers: int = 0
     # Routed-expert feed-forward layer in place of the dense MLP
     # (ops/moe.py); 0 experts = the dense MLP. The router scores all
     # moe_experts; this process holds moe_experts_held of them (0 = all),
@@ -96,6 +117,17 @@ class PolicyConfig:
     moe_first_expert: int = 0
     moe_top_k: int = 0  # experts a frame is routed to
     moe_hidden: int = 0  # width of one expert (SwiGLU)
+    # A shared expert beside the routed ones (SwiGLU of this width, 0 =
+    # none): every frame goes through it, on every chip alike, so it is no
+    # part of the share and stands outside the routed layer.
+    moe_shared_hidden: int = 0
+    # The router's form (ops/moe.py route). "softmax": softmax over all
+    # experts, the top_k largest, renormalised. "sigmoid": a sigmoid of
+    # each score, the top_k largest of score + a per-expert bias that
+    # chooses and does not weigh (kept in the tree and not trained by the
+    # loss), the chosen scores renormalised and times moe_route_scale.
+    moe_score: str = "softmax"
+    moe_route_scale: float = 1.0
     # The router's scores are standardised per expert before the softmax:
     # less their running mean, over the root of the running mean of that
     # difference's square, over the chunk's frames so far (causal: the actor's step carries the

@@ -49,7 +49,10 @@ class KVCache(NamedTuple):
     """Actor-side attention state. Every leaf is BATCH-LEADING (like the
     LSTM's (c, h)) so the generic state plumbing — selfplay's per-side
     concat/slice batching, the actor's row resets — works unchanged:
-    k/v [B, L, C, G, Dh] (G key/value heads); pos [B, C] holds absolute positions with
+    k/v [B, L, C, G, Dh] (G key/value heads); with latent layers k is the
+    rotated key that every head shares [B, L, C, 1, tf_qk_rope_dim] and v
+    the normed latent [B, L, C, 1, tf_kv_lora_rank], and no head's keys or
+    values are kept; pos [B, C] holds absolute positions with
     EMPTY_POS in unwritten slots (shared across layers — every layer
     sees the same timeline); idx [B] is each row's next write slot and the
     count of frames stepped."""
@@ -61,9 +64,30 @@ class KVCache(NamedTuple):
     rsum: jnp.ndarray  # [B, L, 2, E] f32: each layer's sums of `ops.moe.standardize` over the frames so far
 
 
+def is_latent(cfg: PolicyConfig) -> bool:
+    """Whether the layers' attention is the latent kind."""
+    return "latent" in (cfg.tf_layer_kinds or "")
+
+
+def latent_shape(cfg: PolicyConfig) -> Tuple[int, int, int, int, int]:
+    """(query latent, key/value latent, a head's unrotated and rotary
+    query/key dimensions, a head's value width) of latent attention."""
+    return (cfg.tf_q_lora_rank, cfg.tf_kv_lora_rank, cfg.tf_qk_nope_dim, cfg.tf_qk_rope_dim,
+            cfg.tf_v_head_dim)
+
+
 def head_shape(cfg: PolicyConfig) -> Tuple[int, int, int]:
-    """(query heads, key/value heads, head width) of the block."""
+    """(query heads, key/value heads, head width) of the block; of latent
+    attention's expanded form, whose keys have a head each, the query's
+    and key's width."""
     N = cfg.tf_heads
+    if is_latent(cfg):
+        if min(latent_shape(cfg)) <= 0 or cfg.tf_qk_rope_dim % 2:
+            raise ValueError(
+                f"latent attention needs tf_q_lora_rank, tf_kv_lora_rank, tf_qk_nope_dim, an even "
+                f"tf_qk_rope_dim and tf_v_head_dim: {latent_shape(cfg)}"
+            )
+        return N, N, cfg.tf_qk_nope_dim + cfg.tf_qk_rope_dim
     G = cfg.tf_kv_heads or N
     if not cfg.tf_head_dim and cfg.lstm_hidden % N:
         raise ValueError(
@@ -82,10 +106,12 @@ def layer_kinds(cfg: PolicyConfig) -> Tuple[str, ...]:
     comma list, repeated."""
     period = [k.strip() for k in (cfg.tf_layer_kinds or "full").split(",")]
     for k in period:
-        if k not in ("full", "sliding"):
-            raise ValueError(f"tf_layer_kinds: unknown kind {k!r} (full|sliding)")
+        if k not in ("full", "sliding", "latent"):
+            raise ValueError(f"tf_layer_kinds: unknown kind {k!r} (full|sliding|latent)")
         if k == "sliding" and cfg.tf_window <= 0:
             raise ValueError("a sliding layer needs tf_window > 0")
+    if "latent" in period and set(period) != {"latent"}:
+        raise ValueError("tf_layer_kinds: latent layers share no KVCache with another kind")
     return tuple(period[i % len(period)] for i in range(cfg.tf_layers))
 
 
@@ -101,9 +127,12 @@ def init_cache(cfg: PolicyConfig, batch_shape) -> KVCache:
     # (2x actor cache bytes); scores still accumulate in f32 inside
     # attention (ADVICE r3 item 3). pos/idx stay int32.
     dt = jnp.dtype(cfg.dtype)
+    k_shape = v_shape = (B, L, C, G, Dh)
+    if is_latent(cfg):
+        k_shape, v_shape = (B, L, C, 1, cfg.tf_qk_rope_dim), (B, L, C, 1, cfg.tf_kv_lora_rank)
     return KVCache(
-        k=jnp.zeros((B, L, C, G, Dh), dt),
-        v=jnp.zeros((B, L, C, G, Dh), dt),
+        k=jnp.zeros(k_shape, dt),
+        v=jnp.zeros(v_shape, dt),
         pos=jnp.full((B, C), A.EMPTY_POS, jnp.int32),
         idx=jnp.zeros((B,), jnp.int32),
         rsum=jnp.zeros((B, L, 2, cfg.moe_experts), jnp.float32),
@@ -141,7 +170,8 @@ def _fan_in_first(key, shape, dtype=jnp.float32):
 
 class ExpertLayer(nn.Module):
     """The routed-expert feed-forward layer (ops/moe.py): a router over
-    all cfg.moe_experts, and the SwiGLU experts held here. The expert
+    all cfg.moe_experts (cfg.moe_score: softmax, or sigmoid with its
+    per-expert bias), and the SwiGLU experts held here. The expert
     matrices are kept input axis first, [D, held, I] and [I, held, D]."""
 
     cfg: PolicyConfig
@@ -167,6 +197,10 @@ class ExpertLayer(nn.Module):
         w_gate = self.param("w_gate", _fan_in_first, (D, held, I))
         w_up = self.param("w_up", _fan_in_first, (D, held, I))
         w_down = self.param("w_down", _fan_in_first, (I, held, D))
+        bias = None
+        if cfg.moe_score == "sigmoid":
+            # chooses and does not weigh; no gradient reaches it, so the optimizer leaves it
+            bias = jax.lax.stop_gradient(self.param("router_bias", nn.initializers.zeros_init(), (E,)))
         scores = moe.router_scores(h, router)  # [B, T, E] f32
         sums = None
         if cfg.moe_standardize_router and seen is None:
@@ -183,26 +217,152 @@ class ExpertLayer(nn.Module):
             impl = "megablox" if (self.platform or jax.default_backend()) == "tpu" else "ragged_dot"
         x = h.reshape(-1, D)
         y, *counts = moe.expert_layer(
-            x.astype(dt), moe.route(scores.reshape(-1, E), cfg.moe_top_k),
+            x.astype(dt), moe.route(scores.reshape(-1, E), cfg.moe_top_k, cfg.moe_score, bias, cfg.moe_route_scale),
             moe.by_expert(w_gate, dt), moe.by_expert(w_up, dt), moe.by_expert(w_down, dt),
             cfg.moe_first_expert, impl, moe.buffer_rows(x.shape[0] * cfg.moe_top_k, held, E),
         )
         return y.reshape(h.shape), tuple(counts), sums
 
 
+def _dense(cfg: PolicyConfig, n: int, name: str) -> nn.Dense:
+    """A projection of the block, made inside its compact method (these
+    are functions and not methods of `Block`: a method would put its own
+    name into the `op_name` of every operation under it)."""
+    return nn.Dense(n, dtype=jnp.dtype(cfg.dtype), use_bias=cfg.tf_bias, name=name)
+
+
+def _swiglu(cfg: PolicyConfig, h: jnp.ndarray, width: int, name: str) -> jnp.ndarray:
+    """(silu(h Wg) * (h Wu)) Wd in the compute type, h [.., D] normed."""
+    h = h.astype(jnp.dtype(cfg.dtype))
+    act = nn.silu(_dense(cfg, width, name + "_gate")(h)) * _dense(cfg, width, name + "_up")(h)
+    return _dense(cfg, h.shape[-1], name + "_down")(act)
+
+
+def _attention(block: "Block", x, positions, cache):
+    """The full or sliding layer's attention part: what is added to
+    x, and the new (k_cache, v_cache) or None."""
+    cfg = block.cfg
+    N, G, Dh = head_shape(cfg)
+    dt = jnp.dtype(cfg.dtype)
+    sliding = block.kind == "sliding"
+    window = cfg.tf_window if sliding else 0
+    table = A.rope_table(Dh, cfg.tf_rope_theta) if sliding or not cfg.tf_yarn_factor else (
+        A.rope_table(Dh, cfg.tf_rope_theta, cfg.tf_yarn_factor, cfg.tf_yarn_original_context,
+                     cfg.tf_yarn_beta_fast, cfg.tf_yarn_beta_slow))
+    h = _norm(cfg, "ln1")(x)
+    qkv = _dense(cfg, (N + 2 * G) * Dh, "qkv")(h.astype(dt))
+    q, k, v = jnp.split(qkv, [N * Dh, (N + G) * Dh], axis=-1)
+    # RoPE at this token's absolute position; cached K were rotated
+    # at write time, so angles are consistent across modes. The
+    # fused kernel wants the scores' 1/sqrt(Dh) in q: it goes into
+    # q's table, where the rotation is still float32.
+    q_table = (table[0], table[1] * Dh**-0.5) if block.fused else table
+    q = A.rope(q.reshape(q.shape[:-1] + (N, Dh)), positions, table=q_table)
+    k = A.rope(k.reshape(k.shape[:-1] + (G, Dh)), positions, table=table)
+    v = v.reshape(v.shape[:-1] + (G, Dh))
+
+    new_cache = None
+    if cache is None:
+        attn = RA.attend(
+            q, k, v, positions, positions,
+            mesh=block.sp_mesh, sp_axis=cfg.tf_sp_axis, sp_mode=cfg.tf_sp_mode,
+            kv_block=cfg.tf_attn_block, window=window, fused=block.fused,
+        )
+    else:
+        k_cache, v_cache, cache_pos, onehot, _ = cache
+        # Write in the cache's own dtype (compute dtype — init_cache):
+        # jnp.where avoids the f32 promotion a mask-blend would cause.
+        sel = onehot[:, :, None, None]  # [B, C, 1, 1] bool
+        k_cache = jnp.where(sel, k.astype(k_cache.dtype), k_cache)
+        v_cache = jnp.where(sel, v.astype(v_cache.dtype), v_cache)
+        attn = RA.attend(q, k_cache, v_cache, positions, cache_pos, window=window)
+        new_cache = (k_cache, v_cache)
+    return _dense(cfg, cfg.lstm_hidden, "attn_out")(attn.astype(dt).reshape(attn.shape[:-2] + (N * Dh,))), new_cache
+
+
+class Kernel(nn.Module):
+    """The matrix of a dense layer without bias, kept as `nn.Dense` keeps
+    it (`kernel`, [in, out], variance 1 / in), for a layer that reads its
+    matrix in more than one form."""
+
+    shape: Tuple[int, int]
+
+    @nn.compact
+    def __call__(self) -> jnp.ndarray:
+        return self.param("kernel", nn.initializers.lecun_normal(), self.shape)
+
+
+def _latent_attention(block: "Block", x, positions, cache):
+    """Latent attention's part. Queries go through a normed latent
+    c_q; keys and values come from a normed latent c and one rotary
+    key k_r that every head shares. The unroll expands c into every
+    head's keys and values and attends as any causal layer does (the
+    fused kernel where it applies). The step keeps c and the rotated
+    k_r of each frame, nothing per head, and attends in the absorbed
+    form: the query carried into c's space by the key half of the
+    expanding matrix, and each head's weighted sum of latents
+    carried out by the value half."""
+    cfg = block.cfg
+    N = cfg.tf_heads
+    q_rank, kv_rank, nope, rope, v_dim = latent_shape(cfg)
+    dt = jnp.dtype(cfg.dtype)
+    table = A.rope_table(rope, cfg.tf_rope_theta)
+    scale = (nope + rope) ** -0.5
+
+    h = _norm(cfg, "ln1")(x).astype(dt)
+    c_q = _norm(cfg, "q_norm")(_dense(cfg, q_rank, "q_a")(h))
+    q = _dense(cfg, N * (nope + rope), "q_b")(c_q.astype(dt))
+    q_n, q_r = jnp.split(q.reshape(q.shape[:-1] + (N, nope + rope)), [nope], axis=-1)
+    c, k_r = jnp.split(_dense(cfg, kv_rank + rope, "kv_a")(h), [kv_rank], axis=-1)
+    c = _norm(cfg, "kv_norm")(c).astype(dt)
+    k_r = A.rope(k_r[..., None, :], positions, table=table)  # one head [B, T, 1, rope]
+    w_kv = Kernel((kv_rank, N * (nope + v_dim)), name="kv_b")().astype(dt)
+
+    new_cache = None
+    if cache is None:
+        q_table = table
+        if block.fused:  # the kernel wants the scores' scale in q (see `_attention`)
+            q_table = (table[0], table[1] * scale)
+            q_n = (q_n.astype(jnp.float32) * scale).astype(dt)
+        q = jnp.concatenate([q_n, A.rope(q_r, positions, table=q_table)], axis=-1)
+        kv = jnp.dot(c, w_kv).reshape(c.shape[:-1] + (N, nope + v_dim))
+        k_n, v = jnp.split(kv, [nope], axis=-1)
+        k = jnp.concatenate([k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
+        attn = RA.attend(
+            q, k, v, positions, positions,
+            mesh=block.sp_mesh, sp_axis=cfg.tf_sp_axis, sp_mode=cfg.tf_sp_mode,
+            kv_block=cfg.tf_attn_block, fused=block.fused,
+        )
+    else:
+        kr_cache, c_cache, cache_pos, onehot, _ = cache
+        sel = onehot[:, :, None, None]  # [B, C, 1, 1] bool
+        kr_cache = jnp.where(sel, k_r.astype(kr_cache.dtype), kr_cache)
+        c_cache = jnp.where(sel, c[:, :, None, :].astype(c_cache.dtype), c_cache)
+        w_k, w_v = jnp.split(w_kv.reshape(kv_rank, N, nope + v_dim), [nope], axis=-1)
+        q_c = jnp.einsum("bqnd,rnd->bqnr", q_n, w_k)
+        u = A.absorbed_attention(q_c, A.rope(q_r, positions, table=table), c_cache[:, :, 0],
+                                 kr_cache[:, :, 0], positions, cache_pos, scale)
+        attn = jnp.einsum("bqnr,rnd->bqnd", u.astype(dt), w_v)
+        new_cache = (kr_cache, c_cache)
+    return _dense(cfg, cfg.lstm_hidden, "attn_out")(attn.astype(dt).reshape(attn.shape[:-2] + (N * v_dim,))), new_cache
+
+
 class Block(nn.Module):
     """Pre-norm transformer block: norm → causal attention (+residual) →
     norm → feed-forward (+residual). The sizes are the config's: grouped
     key/value heads, a head width of its own, a full or a sliding layer
-    with that kind's rotary table, LayerNorm or RMSNorm, a dense GELU MLP
-    of 4x or a routed-expert layer. Matmuls in cfg.dtype (MXU); norms,
-    softmax, router and the residual stream in f32."""
+    with that kind's rotary table, or latent attention; LayerNorm or
+    RMSNorm; a dense MLP (GELU of 4x, or SwiGLU of a width of its own) or
+    a routed-expert layer with or without a shared expert beside it.
+    Matmuls in cfg.dtype (MXU); norms, softmax, router and the residual
+    stream in f32."""
 
     cfg: PolicyConfig
     kind: str = "full"
     sp_mesh: Optional[Mesh] = None
     platform: str = ""  # of the devices the surrounding program runs on, where known
     fused: bool = False  # the unroll's attention goes through the fused kernel (RA.fused_applies)
+    sparse: bool = False  # the feed-forward part is the routed-expert layer (`ff_sparse`)
 
     @nn.compact
     def __call__(
@@ -218,64 +378,45 @@ class Block(nn.Module):
         the router's running sums [B,2,E]):
         T==1 stepping — the block writes its fresh K/V into the cache at
         write_onehot and attends over the merged cache; a sliding layer
-        masks by position. Returns (x_out, new cache or None, the
-        routed-expert layer's counts or None)."""
+        masks by position, a latent layer's two cache arrays are the
+        rotated shared key [B,C,1,rope] and the latent [B,C,1,rank].
+        Returns (x_out, new cache or None, the routed-expert layer's
+        counts or None)."""
         cfg = self.cfg
-        D = cfg.lstm_hidden
-        N, G, Dh = head_shape(cfg)
-        dt = jnp.dtype(cfg.dtype)
-        sliding = self.kind == "sliding"
-        window = cfg.tf_window if sliding else 0
-        dense = lambda n, name: nn.Dense(n, dtype=dt, use_bias=cfg.tf_bias, name=name)
-        table = A.rope_table(Dh, cfg.tf_rope_theta) if sliding or not cfg.tf_yarn_factor else (
-            A.rope_table(Dh, cfg.tf_rope_theta, cfg.tf_yarn_factor, cfg.tf_yarn_original_context,
-                         cfg.tf_yarn_beta_fast, cfg.tf_yarn_beta_slow))
-
         # Named scopes: the layer an operation belongs to, in its
         # `op_name`, the norm that feeds a part inside that part's scope.
-        with jax.named_scope("attn_window" if sliding else "attn_full"):
-            h = _norm(cfg, "ln1")(x)
-            qkv = dense((N + 2 * G) * Dh, "qkv")(h.astype(dt))
-            q, k, v = jnp.split(qkv, [N * Dh, (N + G) * Dh], axis=-1)
-            # RoPE at this token's absolute position; cached K were rotated
-            # at write time, so angles are consistent across modes. The
-            # fused kernel wants the scores' 1/sqrt(Dh) in q: it goes into
-            # q's table, where the rotation is still float32.
-            q_table = (table[0], table[1] * Dh**-0.5) if self.fused else table
-            q = A.rope(q.reshape(q.shape[:-1] + (N, Dh)), positions, table=q_table)
-            k = A.rope(k.reshape(k.shape[:-1] + (G, Dh)), positions, table=table)
-            v = v.reshape(v.shape[:-1] + (G, Dh))
-
-            new_cache = None
-            if cache is None:
-                attn = RA.attend(
-                    q, k, v, positions, positions,
-                    mesh=self.sp_mesh, sp_axis=cfg.tf_sp_axis, sp_mode=cfg.tf_sp_mode,
-                    kv_block=cfg.tf_attn_block, window=window, fused=self.fused,
-                )
-            else:
-                k_cache, v_cache, cache_pos, onehot, _ = cache
-                # Write in the cache's own dtype (compute dtype — init_cache):
-                # jnp.where avoids the f32 promotion a mask-blend would cause.
-                sel = onehot[:, :, None, None]  # [B, C, 1, 1] bool
-                k_cache = jnp.where(sel, k.astype(k_cache.dtype), k_cache)
-                v_cache = jnp.where(sel, v.astype(v_cache.dtype), v_cache)
-                attn = RA.attend(q, k_cache, v_cache, positions, cache_pos, window=window)
-                new_cache = (k_cache, v_cache)
-            out = dense(D, "attn_out")(attn.astype(dt).reshape(attn.shape[:-2] + (N * Dh,)))
+        attention = _latent_attention if self.kind == "latent" else _attention
+        with jax.named_scope({"latent": "attn_latent", "sliding": "attn_window"}.get(self.kind, "attn_full")):
+            out, new_cache = attention(self, x, positions, cache)
             x = x + out.astype(jnp.float32)
 
-        if cfg.moe_experts:
+        if self.sparse:
             with jax.named_scope("moe"):
                 seen = None if cache is None else (cache[4], positions[:, 0])
-                y, counts, rsum = ExpertLayer(cfg, self.platform, name="moe")(_norm(cfg, "ln2")(x), seen)
+                h = _norm(cfg, "ln2")(x)
+                y, counts, rsum = ExpertLayer(cfg, self.platform, name="moe")(h, seen)
+            if cfg.moe_shared_hidden:
+                with jax.named_scope("moe_shared"):
+                    y = y + _swiglu(cfg, h, cfg.moe_shared_hidden, "shared").astype(jnp.float32)
             return x + y, new_cache and new_cache + (rsum,), counts
         with jax.named_scope("mlp"):
             h = _norm(cfg, "ln2")(x)
-            h = dense(4 * D, "mlp_up")(h.astype(dt))
-            h = nn.gelu(h)
-            h = dense(D, "mlp_down")(h)
+            width = cfg.tf_mlp_hidden or 4 * cfg.lstm_hidden
+            if cfg.tf_mlp_act == "swiglu":
+                h = _swiglu(cfg, h, width, "mlp")
+            elif cfg.tf_mlp_act == "gelu":
+                h = nn.gelu(_dense(cfg, width, "mlp_up")(h.astype(jnp.dtype(cfg.dtype))))
+                h = _dense(cfg, cfg.lstm_hidden, "mlp_down")(h)
+            else:
+                raise ValueError(f"unknown tf_mlp_act {cfg.tf_mlp_act!r} (gelu|swiglu)")
             return x + h.astype(jnp.float32), new_cache and new_cache + (cache[4],), None
+
+
+def ff_sparse(cfg: PolicyConfig) -> Tuple[bool, ...]:
+    """For each layer, whether its feed-forward part is the routed-expert
+    layer: with experts, every layer after the cfg.tf_dense_layers dense
+    ones."""
+    return tuple(bool(cfg.moe_experts) and i >= cfg.tf_dense_layers for i in range(cfg.tf_layers))
 
 
 def _moe_stats(counts) -> dict:
@@ -285,7 +426,8 @@ def _moe_stats(counts) -> dict:
     and the passes over the buffers of `moe.buffer_rows` rows, all layers
     (one a layer where its held pairs fit). Empty without a routed-expert
     layer."""
-    if counts[0] is None:
+    counts = [c for c in counts if c is not None]  # a dense layer has none
+    if not counts:
         return {}
     sizes, passes = zip(*counts)
     per_layer = jnp.stack(sizes).astype(jnp.float32)  # [L, held]
@@ -312,7 +454,7 @@ class TransformerCore(nn.Module):
     @nn.compact
     def __call__(self, carry, x: jnp.ndarray, unroll: bool = False):
         cfg = self.cfg
-        kinds = layer_kinds(cfg)
+        kinds, sparse = layer_kinds(cfg), ff_sparse(cfg)
         head_shape(cfg)  # refuses a bad shape before any block is traced
         platform = self.sp_mesh.devices.flat[0].platform if self.sp_mesh is not None else ""
         final = _norm(cfg, "ln_f") if cfg.tf_final_norm else (lambda h: h)
@@ -325,7 +467,7 @@ class TransformerCore(nn.Module):
             fused = RA.fused_applies(
                 platform or jax.default_backend(), (B, T, N, Dh), (B, T, G, Dh), cfg.tf_attn_block,
                 mesh=self.sp_mesh, sp_axis=cfg.tf_sp_axis,
-            )
+            ) and (not is_latent(cfg) or cfg.tf_v_head_dim == Dh)  # the kernel's values have the keys' width
             # cfg.tf_remat: recompute each block's activations in the
             # backward instead of storing them (jax.checkpoint) —
             # O(T·D) residuals per block instead of every intermediate.
@@ -335,7 +477,7 @@ class TransformerCore(nn.Module):
             block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
             counts = []
             for i, kind in enumerate(kinds):
-                h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, fused, name=f"block{i}")(h, positions)
+                h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, fused, sparse[i], name=f"block{i}")(h, positions)
                 counts.append(n)
             stats = {"attn_fused_layers": jnp.float32(len(kinds) if fused else 0), **_moe_stats(counts)}
             return carry, final(h), stats
@@ -355,7 +497,7 @@ class TransformerCore(nn.Module):
         h = x.astype(jnp.float32)[:, None, :]  # [B, 1, D]
         ks, vs, rs = [], [], []
         for i, kind in enumerate(kinds):
-            h, (k_i, v_i, r_i), _ = Block(cfg, kind, platform=platform, name=f"block{i}")(
+            h, (k_i, v_i, r_i), _ = Block(cfg, kind, platform=platform, sparse=sparse[i], name=f"block{i}")(
                 h, positions,
                 cache=(carry.k[:, i], carry.v[:, i], new_pos, onehot, carry.rsum[:, i]),
             )

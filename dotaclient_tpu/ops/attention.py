@@ -163,13 +163,14 @@ def accumulate_block(
     return m_new, l_new, acc * corr[..., None] + pv
 
 
-def init_carry(q: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Zero-state (m, l, acc) for a streaming pass with query block `q`."""
+def init_carry(q: jnp.ndarray, v_width: int = 0) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Zero-state (m, l, acc) for a streaming pass with query block `q`;
+    `v_width`: the values' head width where it is not the queries'."""
     lead = q.shape[:-3]
     Tq, N, Dh = q.shape[-3:]
     m = jnp.full(lead + (N, Tq), _NEG, jnp.float32)
     l = jnp.zeros(lead + (N, Tq), jnp.float32)
-    acc = jnp.zeros(lead + (N, Tq, Dh), jnp.float32)
+    acc = jnp.zeros(lead + (N, Tq, v_width or Dh), jnp.float32)
     return m, l, acc
 
 
@@ -194,13 +195,41 @@ def causal_attention(
 
     q [.., Tq, N, Dh], k/v [.., Tk, G, Dh], q_pos [.., Tq], k_pos [.., Tk]
     → [.., Tq, N, Dh] in q.dtype; `window` as in `accumulate_block`.
+    (v's head width may be another than q's and k's: the output has v's.)
     This is both the reference the ring and blocked paths are tested
     against and the shipping implementation whenever the whole time axis
     fits one device's memory densely.
     """
-    m, l, acc = init_carry(q)
+    m, l, acc = init_carry(q, v.shape[-1])
     m, l, acc = accumulate_block(q, k, v, q_pos, k_pos, m, l, acc, window)
     return finalize_attention(m, l, acc, dtype=q.dtype)
+
+
+def absorbed_attention(
+    q_c: jnp.ndarray,
+    q_r: jnp.ndarray,
+    c: jnp.ndarray,
+    k_r: jnp.ndarray,
+    q_pos: jnp.ndarray,
+    k_pos: jnp.ndarray,
+    scale: float,
+) -> jnp.ndarray:
+    """Latent attention over a cache of latents, in the absorbed form: the
+    keys and values of a frame are never expanded. q_c [B, Tq, N, R] is a
+    head's unrotated query already carried into the latent's space (times
+    the key half of the expanding matrix), q_r [B, Tq, N, Dr] its rotated
+    part; c [B, Tk, R] the cached latents and k_r [B, Tk, Dr] the cached
+    rotated key that every head shares. Score (q_c . c + q_r . k_r) *
+    `scale` where k_pos <= q_pos, softmax in float32, and the result is
+    each head's weighted sum of latents [B, Tq, N, R] in float32, which
+    the caller carries through the value half of the expanding matrix.
+    """
+    s = jnp.einsum("bqnr,bkr->bnqk", q_c, c, preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("bqnd,bkd->bnqk", q_r, k_r, preferred_element_type=jnp.float32)
+    kp, qp = k_pos[:, None, None, :], q_pos[:, None, :, None]
+    valid = (kp <= qp) & (kp != EMPTY_POS)
+    p = jax.nn.softmax(jnp.where(valid, s.astype(jnp.float32) * scale, _NEG), axis=-1)
+    return jnp.einsum("bnqk,bkr->bqnr", jnp.where(valid, p, 0.0), c.astype(jnp.float32))
 
 
 def block_key_range(i: int, block: int, T: int, window: int = 0) -> Tuple[int, int]:
@@ -265,7 +294,7 @@ def blockwise_causal_attention(
 FUSED_RESIDUALS = "attn_fused_residuals"
 
 
-def fused_tiles(T: int, window: int = 0) -> Tuple[int, int]:
+def fused_tiles(T: int, window: int = 0, head_dim: int = 128) -> Tuple[int, int]:
     """(tile of the query and key axes, keys computed at a time inside a
     key tile) of the fused kernel for a chunk of T frames, (0, 0) where it
     has none. The largest of 1,024, 512, 256, 128 that divides T and, in
@@ -274,10 +303,17 @@ def fused_tiles(T: int, window: int = 0) -> Tuple[int, int]:
     on a TPU v5e at 4 rows of T 4,096, 32 heads on 4 of 128 (PERF.md,
     PR 33): forward and backward 14.5 ms at window 1,024 and 18.3 ms
     causal, against 15.5 and 21.1 with tiles of 512, 29.8 and 43.8 with
-    tiles of 256, and 14.9 and 18.7 with all 1,024 keys at a time."""
+    tiles of 256, and 14.9 and 18.7 with all 1,024 keys at a time. Heads
+    wider than 128 take as many fewer keys at a time (256 at a width of
+    256): the backward kernel's scratch for a tile of 1,024 with 512 keys
+    of 256 at a time is 17.4 MB of the 16 MB a kernel may use (the TPU's
+    compiler, PR 36), and with 256 it fits and keeps the tile, whose
+    partial dq take half the memory of a tile of 512's."""
     for tile in (1024, 512, 256, 128):
         if T % tile == 0 and (not window or tile <= max(window, 128)):
-            return tile, min(tile, 512)
+            # 512 keys at a time at a width of 128, fewer in proportion to a
+            # wider head, in whole multiples of 128 (the kernel's lanes)
+            return tile, min(tile, max(128, 512 * 128 // max(head_dim, 128) // 128 * 128))
     return 0, 0
 
 
@@ -322,7 +358,7 @@ def fused_causal_attention(
     T, N, Dh = q.shape[-3:]
     if k.shape[-3] != T or not fused_takes(T, N, k.shape[-2], Dh):
         raise ValueError(f"the fused kernel does not take q {q.shape} with k {k.shape}")
-    tile, compute = tiles or fused_tiles(T, window)
+    tile, compute = tiles or fused_tiles(T, window, Dh)
     mask = masks.LocalMask((T, T), (window - 1, 0), 0) if window else masks.CausalMask((T, T))
     kernel = splash.make_splash_mha_single_device(
         masks.MultiHeadMask([mask] * N),

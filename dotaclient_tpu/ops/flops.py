@@ -55,20 +55,29 @@ def policy_forward_flops_per_frame(cfg: PolicyConfig, chunk_frames: int = 0) -> 
 
     # temporal core
     if cfg.arch == "transformer":
-        from dotaclient_tpu.models.transformer_policy import head_shape, layer_kinds
+        from dotaclient_tpu.models.transformer_policy import ff_sparse, head_shape, is_latent, latent_shape, layer_kinds
 
         N, G, Dh = head_shape(cfg)
         frames = chunk_frames or cfg.tf_context
         proj = 2.0 * H * (N + 2 * G) * Dh + 2.0 * N * Dh * H  # qkv, out
-        if cfg.moe_experts:
-            held = cfg.moe_experts_held or cfg.moe_experts
-            pairs_here = cfg.moe_top_k * held / cfg.moe_experts
-            ff = 2.0 * H * cfg.moe_experts + pairs_here * 3 * 2.0 * H * cfg.moe_hidden
-        else:
-            ff = 2.0 * (2 * H * 4 * H)  # MLP: Hx4H up + 4HxH down
-        for kind in layer_kinds(cfg):
+        per_pair = 4.0 * N * Dh  # QK^T + attn·V
+        if is_latent(cfg):
+            # the expanded form, the learner's: q through its latent, the latent and the
+            # shared rotary key, every head's keys and values out of the latent, out
+            q_rank, kv_rank, nope, rope, v_dim = latent_shape(cfg)
+            proj = 2.0 * (H * q_rank + q_rank * N * (nope + rope) + H * (kv_rank + rope)
+                          + kv_rank * N * (nope + v_dim) + N * v_dim * H)
+            per_pair = 2.0 * N * (nope + rope) + 2.0 * N * v_dim
+        held = cfg.moe_experts_held or cfg.moe_experts
+        pairs_here = cfg.moe_top_k * held / max(cfg.moe_experts, 1)
+        # router, the held pairs of an even routing, the shared expert
+        sparse = (2.0 * H * cfg.moe_experts + pairs_here * 3 * 2.0 * H * cfg.moe_hidden
+                  + 3 * 2.0 * H * cfg.moe_shared_hidden)
+        width = cfg.tf_mlp_hidden or 4 * H
+        dense = (3 if cfg.tf_mlp_act == "swiglu" else 2) * 2.0 * H * width  # gate, up, down | up, down
+        for kind, is_sparse in zip(layer_kinds(cfg), ff_sparse(cfg)):
             pairs = attended_pairs(frames, cfg.tf_window if kind == "sliding" else 0)
-            fl += proj + ff + pairs * 4.0 * N * Dh / frames  # QK^T + attn·V of the kept pairs
+            fl += proj + (sparse if is_sparse else dense) + pairs * per_pair / frames  # of the kept pairs
     else:
         fl += 2.0 * H * 4 * H  # x-projection (input is the trunk's H)
         fl += 2.0 * H * 4 * H  # recurrence hidden projection

@@ -29,12 +29,13 @@ it. Within a pass a row past the held pairs is zeroed wherever it would
 be read. Where the buffer would hold every pair anyway (every expert
 held, the actor's step over a batch) there is neither choice nor loop.
 
-Router product, softmax and top-k are float32 at the highest matmul
-precision whatever the compute type: which experts a frame goes to
-flips on rounding, and a flipped choice is a different function. The
-caller may standardise the scores between `router_scores` and `route`
-(`standardize`; models/transformer_policy.py ExpertLayer,
-cfg.moe_standardize_router).
+Router product, softmax (or sigmoid) and top-k are float32 at the
+highest matmul precision whatever the compute type: which experts a
+frame goes to flips on rounding, and a flipped choice is a different
+function. The caller may standardise the scores between `router_scores`
+and `route` (`standardize`; models/transformer_policy.py ExpertLayer,
+cfg.moe_standardize_router). A shared expert, which every chip computes
+alike for its own frames, is no part of the share and not here (`Block`).
 """
 
 from __future__ import annotations
@@ -84,16 +85,33 @@ def standardize(scores: jnp.ndarray, seen, n: jnp.ndarray, axis=None):
     return diff * jax.lax.rsqrt(squares / n + ROUTER_EPS), (total, squares)
 
 
-def route(scores: jnp.ndarray, top_k: int) -> Routing:
-    """scores [F, E] float32: softmax over all E, the `top_k` largest,
-    their probabilities renormalised to sum to one."""
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    _, experts = jax.lax.top_k(jax.lax.stop_gradient(probs), top_k)
+SIGMOID_EPS = 1e-20  # beside the sum that the chosen sigmoid scores are renormalised by
+
+
+def route(scores: jnp.ndarray, top_k: int, score: str = "softmax",
+          bias: Optional[jnp.ndarray] = None, scale: float = 1.0) -> Routing:
+    """scores [F, E] float32. `score` "softmax": softmax over all E, the
+    `top_k` largest, their probabilities renormalised to sum to one.
+    "sigmoid": a sigmoid of each score, the `top_k` largest of sigmoid +
+    `bias` [E], the chosen sigmoids (the bias chooses and does not weigh)
+    renormalised and times `scale`."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown moe_score {score!r} (softmax|sigmoid)")
+    if score == "softmax":
+        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        ranked = probs
+    else:
+        probs = jax.nn.sigmoid(scores.astype(jnp.float32))
+        ranked = probs + bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(jax.lax.stop_gradient(ranked), top_k)
     # The chosen probabilities by a one-hot sum: its transpose is dense,
     # where top_k's own is a scatter of every pair.
     chosen = experts[..., None] == jnp.arange(probs.shape[-1], dtype=experts.dtype)
     weights = jnp.sum(jnp.where(chosen, probs[..., None, :], 0.0), axis=-1)
-    return Routing(experts.astype(jnp.int32), weights / jnp.sum(weights, axis=-1, keepdims=True))
+    if score == "softmax":
+        return Routing(experts.astype(jnp.int32), weights / jnp.sum(weights, axis=-1, keepdims=True))
+    total = jnp.sum(weights, axis=-1, keepdims=True) + SIGMOID_EPS
+    return Routing(experts.astype(jnp.int32), weights / total * scale)
 
 
 def _rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:

@@ -1,8 +1,10 @@
 """The routed-expert layer (ops/moe.py): the router against its written
 equations, a share's partial sum against a loop over its experts, in a
 buffer of every pair and in smaller ones that take one pass or several,
-the shares of an expert-parallel group adding up to the whole layer, and
-no pair dropped however skewed the routing."""
+the shares of an expert-parallel group adding up to the whole layer, no
+pair dropped however skewed the routing, and the sigmoid router whose
+per-expert bias chooses and does not weigh, with the shared expert
+counted once beside the eight shares."""
 
 import jax
 import jax.numpy as jnp
@@ -192,3 +194,82 @@ def test_standardised_scores_unroll_step_and_equations():
     later[:, 5:] += 1.0
     again, _ = moe.standardize(jnp.asarray(later), None, jnp.arange(1, T + 1, dtype=jnp.float32), axis=1)
     np.testing.assert_array_equal(np.asarray(again)[:, :5], np.asarray(got)[:, :5])
+
+
+def _sigmoid_written(scores, bias, top_k, scale):
+    """The sigmoid router as its equations read, in float64: s = sigmoid,
+    the top_k largest of s + b, w = scale * s_chosen / (sum + 1e-20)."""
+    s = 1.0 / (1.0 + np.exp(-np.asarray(scores, np.float64)))
+    chosen = np.argsort(-(s + np.asarray(bias, np.float64)), axis=-1)[:, :top_k]
+    picked = np.take_along_axis(s, chosen, -1)
+    return chosen, scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+
+
+def _by_expert(experts, weights, n):
+    """[F, n]: each expert's weight for each frame, 0 where not chosen."""
+    out = np.zeros((experts.shape[0], n))
+    np.put_along_axis(out, np.asarray(experts), np.asarray(weights, np.float64), -1)
+    return out
+
+
+def test_the_sigmoid_router_against_its_written_equations():
+    x, wr = _weights(6)[:2]
+    scores = moe.router_scores(x, wr)
+    bias = jnp.asarray(np.random.RandomState(6).randn(E) * 0.2, jnp.float32)
+    got = moe.route(scores, K, "sigmoid", bias, 1.8)
+    chosen, w = _sigmoid_written(scores, bias, K, 1.8)
+    assert (np.sort(np.asarray(got.experts), -1) == np.sort(chosen, -1)).all()
+    np.testing.assert_allclose(_by_expert(got.experts, got.weights, E), _by_expert(chosen, w, E), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got.weights).sum(-1), 1.8, rtol=1e-5)  # renormalised, then scaled
+    assert got.experts.dtype == jnp.int32 and got.weights.dtype == jnp.float32
+    # a zero bias is the sigmoid form still, not the softmax one
+    zero = moe.route(scores, K, "sigmoid", jnp.zeros(E), 1.0)
+    soft = moe.route(scores, K)
+    assert (np.sort(zero.experts, -1) == np.sort(soft.experts, -1)).all()  # both monotone in the score
+    assert np.abs(np.asarray(zero.weights) - np.asarray(soft.weights)).max() > 1e-3
+
+
+def test_the_bias_changes_the_choice_and_not_the_weights():
+    """An expert pushed by the bias is chosen where its score alone would
+    not be; its weight, and every chosen expert's, is still its sigmoid
+    over the chosen sigmoids' sum; and no gradient reaches the bias."""
+    x, wr = _weights(7)[:2]
+    scores = moe.router_scores(x, wr)
+    plain = moe.route(scores, K, "sigmoid", jnp.zeros(E), 1.8)
+    bias = jnp.zeros(E).at[5].set(10.0).at[0].set(-10.0)  # expert 5 always, expert 0 never
+    got = moe.route(scores, K, "sigmoid", bias, 1.8)
+    assert (np.asarray(got.experts) == 5).any(-1).all() and not (np.asarray(got.experts) == 0).any()
+    assert not (np.asarray(plain.experts) == 5).any(-1).all()  # the bias did that
+    s = np.asarray(jax.nn.sigmoid(scores), np.float64)
+    picked = np.take_along_axis(s, np.asarray(got.experts), -1)
+    np.testing.assert_allclose(got.weights, 1.8 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    assert np.asarray(got.weights).max() <= 1.8  # a bias of 10 among the weights would not be
+    g_bias, g_scores = jax.grad(
+        lambda b, s: jnp.sum(jnp.sin(moe.route(s, K, "sigmoid", jax.lax.stop_gradient(b), 1.8).weights)), argnums=(0, 1))(bias, scores)
+    assert not np.asarray(g_bias).any() and np.asarray(g_scores).any()
+    # and without the stop_gradient the choice still carries none: top_k's indices are integers
+    g_bias = jax.grad(lambda b: jnp.sum(jnp.sin(moe.route(scores, K, "sigmoid", b, 1.8).weights)))(bias)
+    assert not np.asarray(g_bias).any()
+
+
+def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+    """The published sparse layer, uncut: S(h) + sum over the chosen e of
+    w_e E_e(h) with the sigmoid router. Cut into eight shares of one
+    expert each, every share computes its routed part, and the shared
+    expert, which every chip computes alike for its own frames, counts
+    once: the sum is the uncut layer, and every pair is computed by one
+    share."""
+    x, wr, wg, wu, wd = _weights(8)
+    r = np.random.RandomState(8)
+    bias = jnp.asarray(r.randn(E) * 0.2, jnp.float32)
+    sg, su, sd = (jnp.asarray(r.randn(*s) / 4, jnp.float32) for s in ((D, I), (D, I), (I, D)))
+    swiglu = lambda h, g, u, d: (jax.nn.silu(h @ g) * (h @ u)) @ d
+    chosen, w = _sigmoid_written(moe.router_scores(x, wr), bias, K, 1.8)
+    w_all = jnp.asarray(_by_expert(chosen, w, E), jnp.float32)
+    whole = swiglu(x, sg, su, sd) + sum(w_all[:, e:e + 1] * swiglu(x, wg[e], wu[e], wd[e]) for e in range(E))
+    routing = moe.route(moe.router_scores(x, wr), K, "sigmoid", bias, 1.8)
+    parts = [moe.expert_layer(x, routing, wg[e:e + 1], wu[e:e + 1], wd[e:e + 1], e) for e in range(E)]
+    np.testing.assert_allclose(swiglu(x, sg, su, sd) + sum(p[0] for p in parts), whole, rtol=1e-4, atol=1e-5)
+    assert sum(int(p[1].sum()) for p in parts) == F * K
+    # eight times the shared expert, one from each share, is not the layer
+    assert np.abs(np.asarray(8 * swiglu(x, sg, su, sd) + sum(p[0] for p in parts) - whole)).max() > 0.1

@@ -4,7 +4,8 @@ topology (libtpu ships the compiler; nothing executes, so this says
 nothing about results or times — a compile that passes is not a chip
 run). It refuses what the chip would refuse: a Mosaic kernel the
 partitioner cannot split, a slice off the tiling, a program that does
-not fit. The whole file is meant to stay inside 40 s.
+not fit. The file took 40 s until it compiled a whole cell's step (PR
+36: 100 s more, the one guard that the 513M-parameter step fits a chip).
 
 The code under test sees `jax.default_backend() == "cpu"` here; what it
 is compiled FOR comes from the described devices handed to it (a mesh
@@ -259,3 +260,61 @@ def test_fused_attention_compiles_at_the_cells_shapes(topo, n_devices):
     assert len(kernels) >= 2 and all("attn_window" in n for n in kernels), kernels
     assert "all-gather" not in text
     assert _fits(compiled)
+
+
+def test_fused_attention_takes_heads_of_256(topo):
+    """The fused kernel at the latent-attention cell's expanded shapes (4
+    rows of 4,096 frames, 20 heads of 256 with keys and values of their
+    own), forward and backward on one described chip: the tiles
+    `fused_tiles` gives a width of 256 (1,024 with 256 keys at a time) fit
+    the kernel's fast memory, where Mellum2's (512 keys at a time) do not,
+    and Mellum2's shapes keep the tiles they had."""
+    from dotaclient_tpu.ops import attention as A
+    from dotaclient_tpu.ops import ring_attention as RA
+
+    one = SingleDeviceSharding(topo.devices[0])
+    B, T, N, Dh = 4, 4096, 20, 256
+    assert A.fused_takes(T, N, N, Dh) and RA.fused_applies("tpu", (B, T, N, Dh), (B, T, N, Dh), 256)
+    assert A.fused_tiles(T, 0, Dh) == (1024, 256)
+    assert A.fused_tiles(T, 0, 128) == A.fused_tiles(T) == A.fused_tiles(T, 1024, 128) == (1024, 512)
+
+    def loss(tiles, q, k, v):
+        with jax.named_scope("attn_latent"):
+            out = A.fused_causal_attention(q, k, v, q_scaled=True, tiles=tiles)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    args = [jax.ShapeDtypeStruct((B, T, N, Dh), jnp.bfloat16, sharding=one)] * 3
+    compiled = jax.jit(jax.grad(lambda *a: loss(None, *a), argnums=(0, 1, 2))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
+    assert len(kernels) >= 2 and all("attn_latent" in n for n in kernels), kernels
+    assert _fits(compiled)
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(jax.grad(lambda *a: loss((1024, 512), *a), argnums=(0, 1, 2))).lower(*args).compile()
+
+
+def test_the_latent_cells_step_compiles_and_fits(topo):
+    """The whole step of `learner-glm47flash-ep8-wire` from its
+    configuration's file (4 rows of 4,096 frames, five layers of the
+    published widths, 513M parameters) for one described chip, as the
+    benchmark's harness builds it: every layer's attention through the
+    fused kernel (heads of 256), the four sparse layers' grouped products
+    through theirs, and arguments, scratch and the two flat buffers of a
+    weight publish together inside the chip's memory. About 110 s."""
+    from benchmark import cells, harness
+
+    bench = cells.load_benchmark()
+    cell = cells.load_cell(bench, "learner-glm47flash-ep8-wire")
+    cfg = harness.learner_config(cell, seed=0, broker_url="mem://x")
+    assert (cfg.batch_size, cfg.seq_len, cfg.policy.tf_layers) == (4, 4095, 5)
+    compiled = _compile_train_step(cfg, topo.devices[:1])
+    text = compiled.as_text()
+    kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
+    assert sum("attn_latent" in n and "splash_mha_fwd" in n for n in kernels) >= 5
+    assert sum("attn_latent" in n and "splash_mha_dkv" in n for n in kernels) >= 5
+    assert sum("/moe/" in n for n in kernels) >= 4 * 12 and not [n for n in kernels if "moe_shared" in n]
+    m = compiled.memory_analysis()
+    params = 4 * 513_183_671
+    assert m.argument_size_in_bytes > 3 * params  # the parameters and Adam's two moments
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes + 2 * params < HBM_BYTES

@@ -558,3 +558,156 @@ class TestFusedAttentionInTheUnroll:
         assert len(flat_want) == len(flat_got)
         for a, b in zip(flat_got, flat_want):
             np.testing.assert_allclose(a, b, rtol=2e-3, atol=1e-4 * float(jnp.max(jnp.abs(b)) + 1e-6))
+
+
+# The second published block's shape: latent attention (a value head of another
+# width than the keys' here, which the published one has not), one dense SwiGLU
+# layer before the sparse ones, a sigmoid router with its bias, a shared expert.
+TF_LATENT = PolicyConfig(
+    arch="transformer", unit_embed_dim=16, lstm_hidden=32, mlp_hidden=16, dtype="float32",
+    tf_layers=3, tf_heads=4, tf_context=16, tf_layer_kinds="latent", tf_rope_theta=1000000.0,
+    tf_q_lora_rank=12, tf_kv_lora_rank=10, tf_qk_nope_dim=6, tf_qk_rope_dim=4, tf_v_head_dim=8,
+    tf_norm="rmsnorm", tf_norm_eps=1e-5, tf_bias=False, tf_final_norm=True,
+    tf_mlp_act="swiglu", tf_mlp_hidden=40, tf_dense_layers=1,
+    moe_experts=8, moe_experts_held=4, moe_first_expert=2, moe_top_k=3, moe_hidden=12,
+    moe_shared_hidden=12, moe_score="sigmoid", moe_route_scale=1.8, moe_standardize_router=True,
+    tf_attn_block=4,
+)
+
+
+def _written_latent_block(p, x, cfg):
+    """Layer 0 of the published block as its equations read, in float64:
+    x [T, D] -> x' = a + FF_0(n(a)), a = x + Attn(n(x))."""
+    N, eps = cfg.tf_heads, cfg.tf_norm_eps
+    nope, rope, v_dim, kv_rank = cfg.tf_qk_nope_dim, cfg.tf_qk_rope_dim, cfg.tf_v_head_dim, cfg.tf_kv_lora_rank
+    p = jax.tree.map(lambda a: np.asarray(a, np.float64), p)
+    n = lambda a, g: a / np.sqrt((a * a).mean(-1, keepdims=True) + eps) * (1.0 + g["scale"])
+    T = x.shape[0]
+
+    def rot(a):  # [T, heads, rope], pairs (i, i + rope / 2), frame t at position t
+        inv = cfg.tf_rope_theta ** (-np.arange(rope // 2) / (rope // 2))
+        ang = np.arange(T)[:, None, None] * inv
+        a1, a2 = a[..., : rope // 2], a[..., rope // 2:]
+        return np.concatenate([a1 * np.cos(ang) - a2 * np.sin(ang), a1 * np.sin(ang) + a2 * np.cos(ang)], -1)
+
+    h = n(x, p["ln1"])
+    c_q = n(h @ p["q_a"]["kernel"], p["q_norm"])
+    q = (c_q @ p["q_b"]["kernel"]).reshape(T, N, nope + rope)
+    ckr = h @ p["kv_a"]["kernel"]
+    c, k_r = n(ckr[:, :kv_rank], p["kv_norm"]), ckr[:, kv_rank:]
+    kv = (c @ p["kv_b"]["kernel"]).reshape(T, N, nope + v_dim)
+    q = np.concatenate([q[..., :nope], rot(q[..., nope:])], -1)
+    k = np.concatenate([kv[..., :nope], np.broadcast_to(rot(k_r[:, None, :]), (T, N, rope))], -1)
+    s = np.einsum("qnd,knd->nqk", q, k) / np.sqrt(nope + rope)
+    s = np.where(np.tril(np.ones((T, T), bool))[None], s, -np.inf)
+    prob = np.exp(s - s.max(-1, keepdims=True))
+    prob /= prob.sum(-1, keepdims=True)
+    o = np.einsum("nqk,knd->qnd", prob, kv[..., nope:]).reshape(T, N * v_dim)
+    a = x + o @ p["attn_out"]["kernel"]
+    h2 = n(a, p["ln2"])
+    silu = lambda z: z / (1.0 + np.exp(-z))
+    return a + (silu(h2 @ p["mlp_gate"]["kernel"]) * (h2 @ p["mlp_up"]["kernel"])) @ p["mlp_down"]["kernel"]
+
+
+class TestLatentBlockShape:
+    @pytest.fixture(scope="class")
+    def net_and_params(self):
+        return P.PolicyNet(TF_LATENT), P.init_params(TF_LATENT, jax.random.PRNGKey(0))
+
+    def test_the_parameter_tree(self, net_and_params):
+        tf = net_and_params[1]["params"]["core"]["tf"]
+        assert set(tf) == {"block0", "block1", "block2", "ln_f"}
+        shapes = jax.tree.map(lambda x: x.shape, tf)
+        attention = {
+            "ln1": {"scale": (32,)}, "q_a": {"kernel": (32, 12)}, "q_norm": {"scale": (12,)},
+            "q_b": {"kernel": (12, 4 * (6 + 4))}, "kv_a": {"kernel": (32, 10 + 4)},
+            "kv_norm": {"scale": (10,)}, "kv_b": {"kernel": (10, 4 * (6 + 8))},
+            "attn_out": {"kernel": (4 * 8, 32)}, "ln2": {"scale": (32,)}}
+        assert shapes["block0"] == {**attention, "mlp_gate": {"kernel": (32, 40)},
+                                    "mlp_up": {"kernel": (32, 40)}, "mlp_down": {"kernel": (40, 32)}}
+        assert shapes["block1"] == shapes["block2"] == {
+            **attention,
+            "moe": {"router": (32, 8), "router_bias": (8,), "w_gate": (32, 4, 12), "w_up": (32, 4, 12),
+                    "w_down": (12, 4, 32)},
+            "shared_gate": {"kernel": (32, 12)}, "shared_up": {"kernel": (32, 12)},
+            "shared_down": {"kernel": (12, 32)}}
+        assert not np.asarray(tf["block1"]["moe"]["router_bias"]).any()  # zero from the seed
+        assert float(jnp.std(tf["block0"]["kv_b"]["kernel"])) == pytest.approx(10 ** -0.5, rel=0.2)
+
+    def test_latent_attention_against_its_written_equations(self, net_and_params):
+        """The leading block (latent attention, then the dense SwiGLU) alone,
+        unrolled, against the equations in float64; the plain blocks cut the
+        14 frames into four query blocks."""
+        from dotaclient_tpu.models.transformer_policy import Block
+
+        params = jax.tree.map(lambda a: a, net_and_params[1]["params"]["core"]["tf"]["block0"])
+        r = np.random.RandomState(8)
+        for name in ("ln1", "q_norm", "kv_norm", "ln2"):  # norms that are not the identity's
+            params[name] = {"scale": jnp.asarray(0.3 * r.randn(*params[name]["scale"].shape), jnp.float32)}
+        x = r.randn(2, 14, 32).astype(np.float32)
+        positions = jnp.broadcast_to(jnp.arange(14, dtype=jnp.int32), (2, 14))
+        got, cache, counts = Block(TF_LATENT, "latent").apply({"params": params}, jnp.asarray(x), positions)
+        assert cache is None and counts is None
+        for b in range(2):
+            np.testing.assert_allclose(got[b], _written_latent_block(params, x[b].astype(np.float64), TF_LATENT),
+                                       rtol=2e-4, atol=2e-5)
+
+    def test_step_mode_equals_unroll_through_the_latent_cache(self, net_and_params):
+        """The unroll attends in the expanded form (every head's keys and
+        values out of the latent), the step in the absorbed form over a
+        cache of the latent and the one rotated key: the same values."""
+        net, params = net_and_params
+        B, T = 2, 14
+        obs = _obs(np.random.RandomState(3), B, T)
+        _, unrolled = net.apply(params, P.initial_state(TF_LATENT, (B,)), obs, unroll=True)
+        state = P.initial_state(TF_LATENT, (B,))
+        assert state.k.shape == (B, 3, 16, 1, 4) and state.v.shape == (B, 3, 16, 1, 10)
+        step = jax.jit(net.apply)
+        values, logps = [], []
+        for t in range(T):
+            state, out = step(params, state, jax.tree.map(lambda x: x[:, t], obs))
+            values.append(out.value)
+            logps.append(out.dist.type_logp)
+            assert out.stats is None
+        np.testing.assert_allclose(np.stack(values, 1), unrolled.value, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(np.stack(logps, 1), unrolled.dist.type_logp, rtol=1e-4, atol=1e-5)
+        assert np.asarray(state.k[:, :, :T]).any() and not np.asarray(state.k[:, :, T:]).any()
+        # a dense layer has no routing to count: the two sparse layers' pairs and one pass each
+        assert 0 < float(unrolled.stats["moe_local_pairs"]) <= B * T * 3 * 2
+        assert float(unrolled.stats["moe_passes"]) == 2.0 and float(unrolled.stats["attn_fused_layers"]) == 0.0
+        # chunk boundaries reset it like any cache
+        again = P.reset_between_chunks(TF_LATENT, state)
+        assert int(again.idx.sum()) == 0 and not np.asarray(again.v).any()
+
+    def test_blocked_remat_and_dense_unrolls_agree(self, net_and_params):
+        net, params = net_and_params
+        obs = _obs(np.random.RandomState(4), 2, 14)
+        state = P.initial_state(TF_LATENT, (2,))
+        _, want = net.apply(params, state, obs, unroll=True)
+        for change in (dict(tf_attn_block=0), dict(tf_remat=True), dict(tf_attn_block=8)):
+            _, got = P.PolicyNet(dataclasses.replace(TF_LATENT, **change)).apply(params, state, obs, unroll=True)
+            np.testing.assert_allclose(got.value, want.value, rtol=1e-5, atol=1e-6)
+
+    def test_the_caches_bytes_a_frame(self):
+        """At the published sizes the actor keeps the 512-wide latent and the
+        64-wide rotary key of a frame and layer, 1,152 B in bfloat16, where
+        the expanded keys and values of 20 heads would be 20,480 B."""
+        from dotaclient_tpu.models.transformer_policy import init_cache
+
+        cfg = dataclasses.replace(
+            TF_LATENT, dtype="bfloat16", lstm_hidden=2048, tf_heads=20, tf_layers=5, tf_context=4096,
+            tf_q_lora_rank=768, tf_kv_lora_rank=512, tf_qk_nope_dim=192, tf_qk_rope_dim=64, tf_v_head_dim=256)
+        cache = jax.eval_shape(lambda: init_cache(cfg, (3,)))
+        assert cache.k.shape == (3, 5, 4096, 1, 64) and cache.v.shape == (3, 5, 4096, 1, 512)
+        per_frame_and_layer = (cache.k.size * cache.k.dtype.itemsize + cache.v.size * cache.v.dtype.itemsize) / (3 * 5 * 4096)
+        assert per_frame_and_layer == 1152
+        assert 20 * (192 + 64 + 256) * 2 == 20480
+
+    def test_bad_shapes_are_refused(self):
+        for change, match in ((dict(tf_layer_kinds="latent,full"), "no KVCache"),
+                              (dict(tf_qk_rope_dim=3), "latent attention needs"),
+                              (dict(tf_kv_lora_rank=0), "latent attention needs"),
+                              (dict(moe_score="tanh"), "moe_score"),
+                              (dict(tf_mlp_act="relu"), "tf_mlp_act")):
+            with pytest.raises(ValueError, match=match):
+                P.init_params(dataclasses.replace(TF_LATENT, **change), jax.random.PRNGKey(0))
