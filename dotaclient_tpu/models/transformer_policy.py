@@ -36,6 +36,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 from jax.sharding import Mesh
 
@@ -238,28 +239,74 @@ def _swiglu(cfg: PolicyConfig, h: jnp.ndarray, width: int, name: str) -> jnp.nda
     return _dense(cfg, h.shape[-1], name + "_down")(act)
 
 
+class Kernel(nn.Module):
+    """The matrix of a dense layer, and its bias or None, kept as
+    `nn.Dense` keeps them (`kernel` [in, out] of variance 1 / in, `bias`
+    [out] of zeros), for a layer that reads its matrix in more than one
+    form or by its columns."""
+
+    shape: Tuple[int, int]
+    bias: bool = False
+
+    @nn.compact
+    def __call__(self):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(), self.shape)
+        bias = self.param("bias", nn.initializers.zeros_init(), self.shape[1:]) if self.bias else None
+        return kernel, bias
+
+
+def _by_head(cfg: PolicyConfig, h: jnp.ndarray, n_heads: int, Dh: int, name: str):
+    """A projection of the block whose output is `n_heads` heads of `Dh`,
+    its parameters those of `_dense(cfg, n_heads * Dh, name)`: returns
+    heads(first, n), h [B, T, D] times the columns that make heads
+    [first, first + n), written by head [B, T, n, Dh]. Taking some heads
+    is a slice of weights; a product that writes [B, T, n_heads * Dh]
+    to be reshaped, or split into q, k and v, costs a copy of every
+    frame's heads on a TPU."""
+    dt = jnp.dtype(cfg.dtype)
+    w, bias = Kernel((h.shape[-1], n_heads * Dh), cfg.tf_bias, name=name)()
+    h = h.astype(dt)
+
+    def heads(first: int, n: int) -> jnp.ndarray:
+        cols = slice(first * Dh, (first + n) * Dh)
+        y = jnp.einsum("btd,dnh->btnh", h, w[:, cols].astype(dt).reshape(-1, n, Dh))
+        return y if bias is None else y + bias[cols].astype(dt).reshape(n, Dh)
+
+    return heads
+
+
+def _from_heads(cfg: PolicyConfig, attn: jnp.ndarray, name: str) -> jnp.ndarray:
+    """The projection of every head's output back to the block's width,
+    attn [B, T, N, Dv] -> [B, T, D], its parameters those of
+    `_dense(cfg, D, name)` over the heads side by side: the product reads
+    the heads as attention wrote them, not a copy that sets them side by
+    side."""
+    dt = jnp.dtype(cfg.dtype)
+    N, Dv = attn.shape[-2:]
+    w, bias = Kernel((N * Dv, cfg.lstm_hidden), cfg.tf_bias, name=name)()
+    y = jnp.einsum("btnh,nhd->btd", attn.astype(dt), w.astype(dt).reshape(N, Dv, -1))
+    return y if bias is None else y + bias.astype(dt)
+
+
 def _attention(block: "Block", x, positions, cache):
     """The full or sliding layer's attention part: what is added to
-    x, and the new (k_cache, v_cache) or None."""
+    x, and the new (k_cache, v_cache) or None. q, k and v leave their
+    products as whole heads, [B, T, heads, Dh], and reach attention so."""
     cfg = block.cfg
     N, G, Dh = head_shape(cfg)
-    dt = jnp.dtype(cfg.dtype)
     sliding = block.kind == "sliding"
     window = cfg.tf_window if sliding else 0
     table = A.rope_table(Dh, cfg.tf_rope_theta) if sliding or not cfg.tf_yarn_factor else (
         A.rope_table(Dh, cfg.tf_rope_theta, cfg.tf_yarn_factor, cfg.tf_yarn_original_context,
                      cfg.tf_yarn_beta_fast, cfg.tf_yarn_beta_slow))
-    h = _norm(cfg, "ln1")(x)
-    qkv = _dense(cfg, (N + 2 * G) * Dh, "qkv")(h.astype(dt))
-    q, k, v = jnp.split(qkv, [N * Dh, (N + G) * Dh], axis=-1)
+    heads = _by_head(cfg, _norm(cfg, "ln1")(x), N + 2 * G, Dh, "qkv")
     # RoPE at this token's absolute position; cached K were rotated
     # at write time, so angles are consistent across modes. The
     # fused kernel wants the scores' 1/sqrt(Dh) in q: it goes into
-    # q's table, where the rotation is still float32.
-    q_table = (table[0], table[1] * Dh**-0.5) if block.fused else table
-    q = A.rope(q.reshape(q.shape[:-1] + (N, Dh)), positions, table=q_table)
-    k = A.rope(k.reshape(k.shape[:-1] + (G, Dh)), positions, table=table)
-    v = v.reshape(v.shape[:-1] + (G, Dh))
+    # the rotation, which is still float32.
+    q = A.rope(heads(0, N), positions, table=table, scale=Dh**-0.5 if block.fused else 1.0)
+    k = A.rope(heads(N, G), positions, table=table)
+    v = heads(N + G, G)
 
     new_cache = None
     if cache is None:
@@ -277,19 +324,7 @@ def _attention(block: "Block", x, positions, cache):
         v_cache = jnp.where(sel, v.astype(v_cache.dtype), v_cache)
         attn = RA.attend(q, k_cache, v_cache, positions, cache_pos, window=window)
         new_cache = (k_cache, v_cache)
-    return _dense(cfg, cfg.lstm_hidden, "attn_out")(attn.astype(dt).reshape(attn.shape[:-2] + (N * Dh,))), new_cache
-
-
-class Kernel(nn.Module):
-    """The matrix of a dense layer without bias, kept as `nn.Dense` keeps
-    it (`kernel`, [in, out], variance 1 / in), for a layer that reads its
-    matrix in more than one form."""
-
-    shape: Tuple[int, int]
-
-    @nn.compact
-    def __call__(self) -> jnp.ndarray:
-        return self.param("kernel", nn.initializers.lecun_normal(), self.shape)
+    return _from_heads(cfg, attn, "attn_out"), new_cache
 
 
 def _latent_attention(block: "Block", x, positions, cache):
@@ -301,7 +336,18 @@ def _latent_attention(block: "Block", x, positions, cache):
     k_r of each frame, nothing per head, and attends in the absorbed
     form: the query carried into c's space by the key half of the
     expanding matrix, and each head's weighted sum of latents
-    carried out by the value half."""
+    carried out by the value half.
+
+    What the unroll hands on is whole heads, [B, T, N, nope + rope] and
+    [B, T, N, v_dim], each written once by a product: q by q_b's and
+    rotated in place (`A.rope`'s span, which also scales the lanes
+    beside it), k by one product of [c | k_r] with the key half of
+    `kv_b` over constant rows that carry k_r into every head's rotary
+    lanes (ones and zeros: exact), v by c's with the value half. The
+    form it replaces split q at lane 192 of 256 and kv_b's output at 192
+    of 448 and concatenated q's and k's parts again: copies of part of
+    a head, in each of three passes, 15 ms a layer where the layer's
+    products take 17 (PERF.md, PR 37)."""
     cfg = block.cfg
     N = cfg.tf_heads
     q_rank, kv_rank, nope, rope, v_dim = latent_shape(cfg)
@@ -311,23 +357,22 @@ def _latent_attention(block: "Block", x, positions, cache):
 
     h = _norm(cfg, "ln1")(x).astype(dt)
     c_q = _norm(cfg, "q_norm")(_dense(cfg, q_rank, "q_a")(h))
-    q = _dense(cfg, N * (nope + rope), "q_b")(c_q.astype(dt))
-    q_n, q_r = jnp.split(q.reshape(q.shape[:-1] + (N, nope + rope)), [nope], axis=-1)
+    q = _by_head(cfg, c_q, N, nope + rope, "q_b")(0, N)
     c, k_r = jnp.split(_dense(cfg, kv_rank + rope, "kv_a")(h), [kv_rank], axis=-1)
     c = _norm(cfg, "kv_norm")(c).astype(dt)
     k_r = A.rope(k_r[..., None, :], positions, table=table)  # one head [B, T, 1, rope]
-    w_kv = Kernel((kv_rank, N * (nope + v_dim)), name="kv_b")().astype(dt)
+    w_kv, _ = Kernel((kv_rank, N * (nope + v_dim)), name="kv_b")()
+    w_k, w_v = jnp.split(w_kv.astype(dt).reshape(kv_rank, N, nope + v_dim), [nope], axis=-1)  # of weights
 
     new_cache = None
     if cache is None:
-        q_table = table
-        if block.fused:  # the kernel wants the scores' scale in q (see `_attention`)
-            q_table = (table[0], table[1] * scale)
-            q_n = (q_n.astype(jnp.float32) * scale).astype(dt)
-        q = jnp.concatenate([q_n, A.rope(q_r, positions, table=q_table)], axis=-1)
-        kv = jnp.dot(c, w_kv).reshape(c.shape[:-1] + (N, nope + v_dim))
-        k_n, v = jnp.split(kv, [nope], axis=-1)
-        k = jnp.concatenate([k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
+        # the kernel wants the scores' scale in q (see `_attention`)
+        q = A.rope(q, positions, table=table, span=(nope, rope), scale=scale if block.fused else 1.0)
+        shared = np.zeros((rope, N, nope + rope), np.float32)  # k_r's lane r into lane nope + r of every head
+        shared[np.arange(rope), :, nope + np.arange(rope)] = 1.0
+        w_k = jnp.concatenate([jnp.pad(w_k, ((0, 0), (0, 0), (0, rope))), jnp.asarray(shared, dt)])
+        k = jnp.einsum("btr,rnd->btnd", jnp.concatenate([c, k_r[..., 0, :]], axis=-1), w_k)
+        v = jnp.einsum("btr,rnd->btnd", c, w_v)
         attn = RA.attend(
             q, k, v, positions, positions,
             mesh=block.sp_mesh, sp_axis=cfg.tf_sp_axis, sp_mode=cfg.tf_sp_mode,
@@ -338,13 +383,13 @@ def _latent_attention(block: "Block", x, positions, cache):
         sel = onehot[:, :, None, None]  # [B, C, 1, 1] bool
         kr_cache = jnp.where(sel, k_r.astype(kr_cache.dtype), kr_cache)
         c_cache = jnp.where(sel, c[:, :, None, :].astype(c_cache.dtype), c_cache)
-        w_k, w_v = jnp.split(w_kv.reshape(kv_rank, N, nope + v_dim), [nope], axis=-1)
+        q_n, q_r = jnp.split(q, [nope], axis=-1)
         q_c = jnp.einsum("bqnd,rnd->bqnr", q_n, w_k)
         u = A.absorbed_attention(q_c, A.rope(q_r, positions, table=table), c_cache[:, :, 0],
                                  kr_cache[:, :, 0], positions, cache_pos, scale)
         attn = jnp.einsum("bqnr,rnd->bqnd", u.astype(dt), w_v)
         new_cache = (kr_cache, c_cache)
-    return _dense(cfg, cfg.lstm_hidden, "attn_out")(attn.astype(dt).reshape(attn.shape[:-2] + (N * v_dim,))), new_cache
+    return _from_heads(cfg, attn, "attn_out"), new_cache
 
 
 class Block(nn.Module):

@@ -86,27 +86,66 @@ def rope_table(
     return inv.astype(np.float32), 0.1 * math.log(yarn_factor) + 1.0
 
 
-def rope(
-    x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0, table=None
-) -> jnp.ndarray:
-    """Rotary position embedding.
+def _partner(start: int, width: int, head_dim: int) -> np.ndarray:
+    """The 0/1 matrix [head_dim, head_dim] that hands every lane of the
+    rotary span [start, start + width) the lane half a span away, and
+    the lanes outside it nothing: x @ it is the swap of the span's two
+    halves."""
+    half = width // 2
+    swap = np.zeros((head_dim, head_dim), np.float32)
+    lanes = start + np.arange(half)
+    swap[lanes + half, lanes] = swap[lanes, lanes + half] = 1.0
+    return swap
 
-    x [.., T, N, Dh] (Dh even), positions [.., T] int32 absolute
-    positions. `table`: a `rope_table` in place of `base`'s default one.
-    Angle math in f32; result cast back to x.dtype.
+
+def rope(
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    base: float = 10000.0,
+    table=None,
+    span: Optional[Tuple[int, int]] = None,
+    scale: float = 1.0,
+) -> jnp.ndarray:
+    """Rotary position embedding, at the head's whole width.
+
+    x [.., T, N, Dh], positions [.., T] int32 absolute positions.
+    `table`: a `rope_table` in place of `base`'s default one. `span`:
+    the (first lane, even width) of a head that rotates, lane i of its
+    first half with lane i of its second; the whole head where None.
+    `scale`: a factor on every lane, rotated or not (the fused kernel
+    wants the scores' 1/sqrt(Dh) in q, and here q is still float32).
+    Angle math in f32; result cast back to x.dtype, its one rounding.
+
+    out = x * C + partner(x) * S, with C = [cos, cos] and S = [-sin, sin]
+    over the span and (scale, 0) on the lanes beside it, tables of the
+    head's width, and partner(x) the swap of the span's halves as a
+    product with a constant 0/1 matrix (`_partner`; exact in any type:
+    an output is one input times one). x comes in and goes out whole,
+    [.., N, Dh], on full lanes. The split form (x[..., :half] and
+    x[..., half:] rotated apart and concatenated, and for a span inside
+    a wider head a split and a concatenation around that) computes the
+    same numbers, and on a TPU each of its pieces is a copy of part of a
+    head, 32 to 192 lanes of it, that no product hides, forward, in the
+    rematerialisation and in the backward pass. With the splits around
+    the projections they were 78 ms of the latent cell's 456-ms step and
+    37 of the grouped-query cell's 358 (PERF.md, PR 37).
     """
-    half = x.shape[-1] // 2
-    freqs, scale = table if table is not None else rope_table(x.shape[-1], base)
+    Dh = x.shape[-1]
+    start, width = span or (0, Dh)
+    freqs, factor = table if table is not None else rope_table(width, base)
     # Sentinel positions would produce garbage angles; they belong to
     # empty cache slots whose scores are masked anyway, so zero them to
     # keep the trig finite.
     pos = jnp.where(positions == EMPTY_POS, 0, positions).astype(jnp.float32)
-    ang = pos[..., None] * freqs  # [.., T, half]
-    cos = (jnp.cos(ang) * scale)[..., None, :]  # [.., T, 1, half] — broadcast over heads
-    sin = (jnp.sin(ang) * scale)[..., None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    ang = pos[..., None] * freqs  # [.., T, width // 2]
+    cos, sin = jnp.cos(ang) * (factor * scale), jnp.sin(ang) * (factor * scale)
+    beside = [(0, 0)] * (ang.ndim - 1) + [(start, Dh - start - width)]
+    C = jnp.pad(jnp.concatenate([cos, cos], axis=-1), beside, constant_values=scale)
+    S = jnp.pad(jnp.concatenate([-sin, sin], axis=-1), beside)
+    partner = jnp.dot(x, jnp.asarray(_partner(start, width, Dh), x.dtype),
+                      precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+    # [.., T, 1, Dh] tables broadcast over heads
+    out = x.astype(jnp.float32) * C[..., None, :] + partner * S[..., None, :]
     return out.astype(x.dtype)
 
 
@@ -344,6 +383,18 @@ def fused_causal_attention(
     MXU in their own type; scores, running maximum, normaliser and
     accumulators are float32. The kernel's output and log-sum-exp carry
     the checkpoint name `FUSED_RESIDUALS`.
+
+    Layout: the kernel reads and writes [B, heads, T, Dh]; this function
+    takes and hands on [B, T, heads, Dh], and the swap of the two axes on
+    either side (`head_major`) is one of logical axes only where each
+    operand comes whole out of a product and the output goes whole into
+    one: the TPU's compiler then has the product write, and read, the
+    kernel's layout, and no copy stands between them. That is how the
+    unroll calls it (models/transformer_policy.py: `_by_head`,
+    `_from_heads`, `rope` at the head's whole width). An operand put
+    together from parts of heads is copied part by part and once more
+    into this layout: `transpose` and `concatenate` copies were 36.7 ms
+    of the latent cell's step, and are gone (PERF.md, PR 37).
 
     `q_scaled`: q already holds the 1/sqrt(Dh) (the caller folded it in
     where q was still float32, `rope`'s table); otherwise it is applied

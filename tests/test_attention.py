@@ -398,6 +398,93 @@ class TestBlockedWindowedGroupedAttention:
             RA.attend(q, q, q, pos, pos, mesh=mesh, sp_axis="sp", window=4)
 
 
+def split_rope(x, positions, base=10000.0, table=None):
+    """`A.rope` as it was written until PR 37, kept as the plain reference:
+    the head cut into halves, each rotated, and concatenated."""
+    half = x.shape[-1] // 2
+    freqs, scale = table if table is not None else A.rope_table(x.shape[-1], base)
+    pos = jnp.where(positions == A.EMPTY_POS, 0, positions).astype(jnp.float32)
+    ang = pos[..., None] * freqs
+    cos = (jnp.cos(ang) * scale)[..., None, :]
+    sin = (jnp.sin(ang) * scale)[..., None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+
+
+_YARN = (128, 500000.0, 16.0, 8192, 32.0, 1.0)
+
+
+def _whole_head(table, scale=1.0):
+    """(the rotation at full width, the split form) of a whole head of 128."""
+    whole = lambda x, pos: A.rope(x, pos, table=table and A.rope_table(*table), scale=scale)
+    folded = table and (A.rope_table(*table)[0], A.rope_table(*table)[1] * scale)
+    return 128, whole, lambda x, pos: split_rope(x, pos, table=folded)
+
+
+def _span_in_a_wider_head():
+    """Latent q: 192 lanes that take the scale alone, then 64 that rotate."""
+    table, scale = A.rope_table(64, 1000000.0), 256 ** -0.5
+    whole = lambda x, pos: A.rope(x, pos, table=table, span=(192, 64), scale=scale)
+
+    def split(x, pos):
+        beside = (x[..., :192].astype(jnp.float32) * scale).astype(x.dtype)
+        return jnp.concatenate([beside, split_rope(x[..., 192:], pos, table=(table[0], table[1] * scale))], axis=-1)
+
+    return 256, whole, split
+
+
+class TestFullWidthRope:
+    """The rotation works on whole heads over full lanes (x * C +
+    partner(x) * S): the same numbers as the half-split form."""
+
+    CASES = {
+        "default_table": lambda: _whole_head(None),
+        "yarn_table": lambda: _whole_head(_YARN),
+        "q_table_with_the_scale": lambda: _whole_head(_YARN, 128 ** -0.5),
+        "span_in_a_wider_head": _span_in_a_wider_head,
+    }
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_equals_the_half_split_form(self, case, dtype):
+        """Bit for bit in float32; in bfloat16 within the one rounding at
+        the end (and, as it turns out, bit for bit too)."""
+        width, whole, split = self.CASES[case]()
+        x = jnp.asarray(_rand((2, 9, 3, width), 80), dtype)
+        pos = jnp.asarray(np.random.RandomState(81).randint(0, 5000, (2, 9)), jnp.int32)
+        got, want = whole(x, pos), split(x, pos)
+        assert got.dtype == want.dtype == dtype and got.shape == x.shape
+        if dtype == jnp.float32:
+            np.testing.assert_array_equal(got, want)
+        else:
+            one_rounding = 2.0 ** -8 * np.abs(np.asarray(want, np.float32))
+            assert (np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32)) <= one_rounding).all()
+        # compiled, the CPU may contract a product and a sum into one rounding: one unit in the last place
+        jitted = jax.jit(whole)(x, pos)
+        np.testing.assert_allclose(np.asarray(jitted, np.float32), np.asarray(want, np.float32),
+                                   rtol=2.0 ** (-23 if dtype == jnp.float32 else -8), atol=1e-6)
+
+    def test_gradients_are_those_of_the_half_split_form(self):
+        _, whole, split = _span_in_a_wider_head()
+        x = jnp.asarray(_rand((1, 5, 2, 256), 82))
+        pos, w = _positions(1, 5) + 7, jnp.asarray(_rand((1, 5, 2, 256), 83))
+        got = jax.grad(lambda x: jnp.sum(whole(x, pos) * w))(x)
+        np.testing.assert_allclose(got, jax.grad(lambda x: jnp.sum(split(x, pos) * w))(x), rtol=1e-6, atol=1e-7)
+
+    def test_sentinel_positions_stay_finite_in_a_span(self):
+        x = jnp.asarray(_rand((1, 3, 2, 16), 84))
+        pos = np.full((1, 3), int(A.EMPTY_POS), np.int32)
+        out = A.rope(x, pos, table=A.rope_table(4), span=(12, 4), scale=0.25)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_array_equal(out, x * 0.25)  # position 0: the scale alone
+
+    def test_the_partner_is_a_swap_of_the_spans_halves(self):
+        swap = A._partner(2, 4, 8)
+        np.testing.assert_array_equal(np.arange(8.0) @ swap, [0, 0, 4, 5, 2, 3, 0, 0])
+        assert (swap.sum(0) <= 1).all() and (swap.sum(1) <= 1).all()  # one input an output, times one
+
+
 class TestRopeTable:
     def test_the_default_table_is_the_old_one(self):
         want = 10000.0 ** (-jnp.arange(16, dtype=jnp.float32) / 16)

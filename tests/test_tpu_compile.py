@@ -12,6 +12,7 @@ is compiled FOR comes from the described devices handed to it (a mesh
 for the train steps, argument shardings for the rest).
 """
 
+import dataclasses
 import os
 import re
 
@@ -228,6 +229,51 @@ def test_the_expert_matrices_update_copies_nothing_of_their_shape(topo):
     unnamed = [ln.strip()[:160] for ln in text.splitlines()
                if re.search(r"= f32\[(256,4,128|128,4,256)\]\S* copy\(", ln) and "op_name" not in ln]
     assert not unnamed, unnamed
+
+
+@pytest.mark.parametrize("cell,kind,scope", [
+    ("learner-glm47flash-ep8-wire", "latent", "attn_latent"),
+    ("learner-mellum2-ep4-wire", "sliding", "attn_window"),
+])
+def test_attention_moves_no_part_of_a_head(topo, cell, kind, scope):
+    """One block of the cell's attention shapes (4 rows of 4,096 frames;
+    its feed-forward part a SwiGLU of 256, so that it compiles in 15 s),
+    forward and backward under `nn.remat` with the step's policy: between
+    the projections and the fused kernel, and in their mirror, no array of
+    activations is sliced, padded, concatenated or copied along a head's
+    width. The split construction left 26 such operations in the latent
+    block and 20 in the sliding one (halves of 64 and 32 lanes, 192 and 64
+    of q's 256, 192 of kv_b's 448), each a pass over every frame's heads
+    that no product hides (PERF.md, PR 37)."""
+    from flax import linen as nn
+
+    from benchmark import cells, harness
+    from dotaclient_tpu.models.transformer_policy import Block
+    from dotaclient_tpu.ops import attention as A
+
+    one = SingleDeviceSharding(topo.devices[0])
+    policy = harness.learner_config(cells.load_cell(cells.load_benchmark(), cell), seed=0, broker_url="mem://x").policy
+    assert policy.tf_remat
+    cfg = dataclasses.replace(policy, moe_experts=0, moe_experts_held=0, moe_shared_hidden=0, tf_dense_layers=0,
+                              tf_mlp_act="swiglu", tf_mlp_hidden=256)
+    B, T, D = 4, 4096, cfg.lstm_hidden
+    keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS)
+    block = nn.remat(Block, policy=keep)(cfg, kind, None, "tpu", True, False)
+    positions = lambda: jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    x = jax.ShapeDtypeStruct((B, T, D), jnp.float32)
+    params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), positions()))
+
+    def loss(params, x):
+        return jnp.sum(block.apply(params, x, positions())[0] ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*_on(one, (params, x))).compile().as_text()
+    part_of_a_head = re.compile(
+        r"= (bf16|f32)\[4,(4096,(20|32|4)|(20|32|4),4096),(32|64|192|448)\]\S* (slice|dynamic-slice|pad|concatenate|copy)\(")
+    found = [ln.strip()[:200] for ln in text.splitlines() if part_of_a_head.search(ln)]
+    assert not found, found
+    kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
+    assert sum("splash_mha_fwd" in n for n in kernels) >= 1 and sum("splash_mha_dkv" in n for n in kernels) >= 1
+    assert all(f"/{scope}/" in n for n in kernels), kernels
 
 
 @pytest.mark.parametrize("n_devices", [1, 4])

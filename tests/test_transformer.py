@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from flax import linen as nn
 
 from dotaclient_tpu.config import ActorConfig, LearnerConfig, PolicyConfig
 from dotaclient_tpu.env import featurizer as F
@@ -347,6 +348,16 @@ TF_MOE = PolicyConfig(
 )
 
 
+def _handed_to_attention(monkeypatch) -> dict:
+    """The q, k and v of the last call of the blocks' `RA.attend`, which
+    runs as it would."""
+    from dotaclient_tpu.models import transformer_policy as TP
+
+    handed, attend = {}, TP.RA.attend
+    monkeypatch.setattr(TP.RA, "attend", lambda q, k, v, *a, **kw: handed.update(q=q, k=k, v=v) or attend(q, k, v, *a, **kw))
+    return handed
+
+
 class TestPublishedBlockShape:
     @pytest.fixture(scope="class")
     def net_and_params(self):
@@ -396,6 +407,34 @@ class TestPublishedBlockShape:
             _, got = P.PolicyNet(dataclasses.replace(TF_MOE, **change)).apply(
                 params, state, obs, unroll=True)
             np.testing.assert_allclose(got.value, want.value, rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("bias", [False, True])
+    def test_the_unroll_hands_on_the_heads_of_one_product_and_a_split(self, monkeypatch, bias):
+        """q, k and v come each from a product with its columns of the one
+        `qkv` matrix, written by head: the numbers of one product split in
+        three and rotated by the half-split rule, the form kept here."""
+        from dotaclient_tpu.models import transformer_policy as TP
+        from tests.test_attention import split_rope
+
+        cfg = dataclasses.replace(TF_MOE, tf_bias=bias)
+        block = TP.Block(cfg, "full")
+        r = np.random.RandomState(9)
+        x = jnp.asarray(r.randn(2, 14, 32), jnp.float32)
+        positions = jnp.broadcast_to(jnp.arange(14, dtype=jnp.int32), (2, 14))
+        params = block.init(jax.random.PRNGKey(1), x, positions)["params"]
+        if bias:
+            params["qkv"]["bias"] = jnp.asarray(r.randn(64), jnp.float32)
+        assert jax.tree.map(jnp.shape, params["qkv"]) == {"kernel": (32, 64), **({"bias": (64,)} if bias else {})}
+        handed = _handed_to_attention(monkeypatch)
+        block.apply({"params": params}, x, positions)
+        g = 1.0 + params["ln1"]["scale"]
+        h = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + cfg.tf_norm_eps) * g
+        qkv = h @ params["qkv"]["kernel"] + (params["qkv"]["bias"] if bias else 0.0)
+        q, k, v = jnp.split(qkv, [4 * 8, 6 * 8], axis=-1)
+        table = TP.A.rope_table(8, 500000.0, 16.0, 8, 32.0, 1.0)
+        np.testing.assert_allclose(handed["q"], split_rope(q.reshape(2, 14, 4, 8), positions, table=table), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(handed["k"], split_rope(k.reshape(2, 14, 2, 8), positions, table=table), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(handed["v"], v.reshape(2, 14, 2, 8), rtol=1e-5, atol=1e-6)
 
     def test_a_sliding_layer_forgets_what_left_its_window(self, net_and_params):
         """With every layer sliding, frame t does not see frame t - window;
@@ -609,6 +648,48 @@ def _written_latent_block(p, x, cfg):
     return a + (silu(h2 @ p["mlp_gate"]["kernel"]) * (h2 @ p["mlp_up"]["kernel"])) @ p["mlp_down"]["kernel"]
 
 
+def _split_latent_block(p, x, positions, cfg, handed=None):
+    """Layer 0 of the latent block as the unroll built it until PR 37, in
+    the compute type, kept as the plain reference of the construction
+    that took its place: q split at its unrotated width and concatenated
+    again, kv_b's output split into every head's keys and values, the one
+    rotary key broadcast and concatenated behind each head's keys."""
+    from dotaclient_tpu.ops import attention as A
+    from tests.test_attention import split_rope
+
+    N, eps = cfg.tf_heads, cfg.tf_norm_eps
+    nope, rope, v_dim, kv_rank = cfg.tf_qk_nope_dim, cfg.tf_qk_rope_dim, cfg.tf_v_head_dim, cfg.tf_kv_lora_rank
+    n = lambda a, g: a * jax.lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True) + eps) * (1.0 + g["scale"])
+    table = A.rope_table(rope, cfg.tf_rope_theta)
+    h = n(x, p["ln1"])
+    c_q = n(h @ p["q_a"]["kernel"], p["q_norm"])
+    q = (c_q @ p["q_b"]["kernel"]).reshape(x.shape[:-1] + (N, nope + rope))
+    q_n, q_r = jnp.split(q, [nope], axis=-1)
+    c, k_r = jnp.split(h @ p["kv_a"]["kernel"], [kv_rank], axis=-1)
+    c = n(c, p["kv_norm"])
+    k_r = split_rope(k_r[..., None, :], positions, table=table)
+    q = jnp.concatenate([q_n, split_rope(q_r, positions, table=table)], axis=-1)
+    kv = (c @ p["kv_b"]["kernel"]).reshape(c.shape[:-1] + (N, nope + v_dim))
+    k_n, v = jnp.split(kv, [nope], axis=-1)
+    k = jnp.concatenate([k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
+    if handed is not None:
+        handed.update(q=q, k=k, v=v)
+    attn = A.causal_attention(q, k, v, positions, positions)
+    a = x + attn.reshape(attn.shape[:-2] + (N * v_dim,)) @ p["attn_out"]["kernel"]
+    h2 = n(a, p["ln2"])
+    return a + (jax.nn.silu(h2 @ p["mlp_gate"]["kernel"]) * (h2 @ p["mlp_up"]["kernel"])) @ p["mlp_down"]["kernel"]
+
+
+def _latent_block0(net_and_params, seed):
+    """(layer 0's parameters with norms that are not the identity's, x, positions)."""
+    params = jax.tree.map(lambda a: a, net_and_params[1]["params"]["core"]["tf"]["block0"])
+    r = np.random.RandomState(seed)
+    for name in ("ln1", "q_norm", "kv_norm", "ln2"):
+        params[name] = {"scale": jnp.asarray(0.3 * r.randn(*params[name]["scale"].shape), jnp.float32)}
+    x = jnp.asarray(r.randn(2, 14, 32), jnp.float32)
+    return params, x, jnp.broadcast_to(jnp.arange(14, dtype=jnp.int32), (2, 14))
+
+
 class TestLatentBlockShape:
     @pytest.fixture(scope="class")
     def net_and_params(self):
@@ -651,6 +732,43 @@ class TestLatentBlockShape:
         for b in range(2):
             np.testing.assert_allclose(got[b], _written_latent_block(params, x[b].astype(np.float64), TF_LATENT),
                                        rtol=2e-4, atol=2e-5)
+
+    def test_the_unroll_hands_on_whole_heads_with_the_split_constructions_numbers(self, net_and_params, monkeypatch):
+        """What reaches attention: q [B, T, N, 10], k [B, T, N, 10] and
+        v [B, T, N, 8], whole heads, with the numbers of the split form;
+        the constant rows carry the one rotary key into every head's last
+        lanes exactly."""
+        from dotaclient_tpu.models import transformer_policy as TP
+
+        params, x, positions = _latent_block0(net_and_params, 8)
+        handed, want = _handed_to_attention(monkeypatch), {}
+        got = TP.Block(TF_LATENT, "latent").apply({"params": params}, x, positions)[0]
+        np.testing.assert_allclose(got, _split_latent_block(params, x, positions, TF_LATENT, want), rtol=1e-5, atol=1e-6)
+        assert [handed[n].shape for n in "qkv"] == [(2, 14, 4, 10), (2, 14, 4, 10), (2, 14, 4, 8)]
+        for name in "qkv":
+            np.testing.assert_allclose(handed[name], want[name], rtol=1e-5, atol=1e-6)
+        rotary = np.asarray(handed["k"][..., 6:])
+        np.testing.assert_array_equal(rotary, np.broadcast_to(rotary[:, :, :1], rotary.shape))  # one key, every head
+        np.testing.assert_array_equal(rotary, np.asarray(want["k"][..., 6:]))
+
+    @pytest.mark.parametrize("change", [dict(), dict(tf_remat=True)], ids=["plain", "remat"])
+    def test_gradients_are_those_of_the_split_construction(self, net_and_params, change):
+        """Every parameter's and the input's: `kv_b` stays one matrix with
+        one gradient of its shape, whatever forms the unroll reads it in,
+        and the rows of ones and zeros that extend its key half are
+        constants of the program, no leaf of the tree and of no gradient."""
+        from dotaclient_tpu.models.transformer_policy import Block
+
+        cfg = dataclasses.replace(TF_LATENT, **change)
+        params, x, positions = _latent_block0(net_and_params, 10)
+        w = jnp.asarray(np.random.RandomState(11).randn(2, 14, 32), jnp.float32)
+        block = nn.remat(Block) if cfg.tf_remat else Block
+        got = jax.grad(lambda p, x: jnp.sum(block(cfg, "latent").apply({"params": p}, x, positions)[0] * w), (0, 1))(params, x)
+        want = jax.grad(lambda p, x: jnp.sum(_split_latent_block(p, x, positions, cfg) * w), (0, 1))(params, x)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        assert got[0]["kv_b"]["kernel"].shape == params["kv_b"]["kernel"].shape == (10, 4 * (6 + 8))
+        for (path, g), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(g, b, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(b))), err_msg=str(path))
 
     def test_step_mode_equals_unroll_through_the_latent_cache(self, net_and_params):
         """The unroll attends in the expanded form (every head's keys and
@@ -711,3 +829,24 @@ class TestLatentBlockShape:
                               (dict(tf_mlp_act="relu"), "tf_mlp_act")):
             with pytest.raises(ValueError, match=match):
                 P.init_params(dataclasses.replace(TF_LATENT, **change), jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("cell,count,leaves,digest", [
+    ("learner-lstm4096-wire", 136_584_631, 23, "bb9966d3313eb21e"),
+    ("learner-mellum2-ep4-wire", 483_239_607, 53, "873687eec0b03b99"),
+    ("learner-glm47flash-ep8-wire", 513_183_671, 101, "a55da48890cbb943"),
+])
+def test_the_cells_parameter_trees_are_what_they_were(cell, count, leaves, digest):
+    """Names, shapes and types of every parameter of the three benchmark
+    cells' policies, as commit 83325ac (PR 36) made them: the published
+    weight frame and every checkpoint are laid out by this tree, so a
+    projection that reads its matrix another way keeps the matrix."""
+    import hashlib
+
+    from benchmark import cells, harness
+
+    cfg = harness.learner_config(cells.load_cell(cells.load_benchmark(), cell), seed=0, broker_url="mem://x").policy
+    tree = jax.eval_shape(lambda: P.init_params(cfg, jax.random.PRNGKey(0)))
+    lines = sorted(f"{jax.tree_util.keystr(k)}:{v.shape}:{v.dtype}" for k, v in jax.tree_util.tree_leaves_with_path(tree))
+    assert sum(int(np.prod(v.shape)) for v in jax.tree.leaves(tree)) == count and len(lines) == leaves
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16] == digest, lines
