@@ -73,9 +73,14 @@ class PolicyConfig:
     # "latent" (every layer, or none): causal attention whose queries and
     # keys/values go through low-rank latents (tf_q_lora_rank and the four
     # sizes after it, below).
+    # "gated": a full layer with an RMSNorm on each head's q and k and a
+    # sigmoid gate on the heads' output, a column block of `qkv` beside q's.
+    # "linear": a gated-delta-rule layer (ops/gated_delta.py; tf_lin_* below),
+    # whose cost is linear in the chunk and whose state is a matrix a head.
     tf_layer_kinds: str = ""
     tf_window: int = 0  # keys a query of a sliding layer sees, itself included
     tf_rope_theta: float = 10000.0  # rotary base, both kinds
+    tf_rotary_dim: int = 0  # lanes of a full/sliding/gated head that rotate, from lane 0; 0 = the whole head
     # YaRN on the full layers' rotary table (0 = the default table there
     # too): inverse frequencies blended between the default and default /
     # factor by the linear ramp over the correction range of (beta_fast,
@@ -100,6 +105,16 @@ class PolicyConfig:
     tf_qk_nope_dim: int = 0
     tf_qk_rope_dim: int = 0
     tf_v_head_dim: int = 0
+    # A linear layer's sizes: tf_lin_key_heads key heads serve
+    # tf_lin_value_heads value heads (a multiple) of tf_lin_head_dim (keys',
+    # queries' and values' alike); q, k and v pass a causal depthwise
+    # convolution over the last tf_lin_conv frames. The actor's state is a
+    # [tf_lin_head_dim, tf_lin_head_dim] matrix a value head and the
+    # convolution's last tf_lin_conv - 1 inputs, and nothing per frame.
+    tf_lin_key_heads: int = 0
+    tf_lin_value_heads: int = 0
+    tf_lin_head_dim: int = 0
+    tf_lin_conv: int = 4
     # The dense feed-forward block: "gelu" = two matrices around a GELU,
     # "swiglu" = (silu(x Wg) * (x Wu)) Wd; width tf_mlp_hidden, 0 = 4x
     # lstm_hidden. With routed experts the first tf_dense_layers layers
@@ -121,6 +136,7 @@ class PolicyConfig:
     # none): every frame goes through it, on every chip alike, so it is no
     # part of the share and stands outside the routed layer.
     moe_shared_hidden: int = 0
+    moe_shared_gate: bool = False  # the shared expert's output times a sigmoid of one product of the frame
     # The router's form (ops/moe.py route). "softmax": softmax over all
     # experts, the top_k largest, renormalised. "sigmoid": a sigmoid of
     # each score, the top_k largest of score + a per-expert bias that

@@ -236,7 +236,9 @@ class PolicyNet(nn.Module):
 
 def initial_state(cfg: PolicyConfig, batch_shape):
     """Fresh temporal state without needing a module instance (host-side
-    use): LSTM (c, h) zeros, or the transformer family's empty KVCache.
+    use): LSTM (c, h) zeros, or the transformer family's empty KVCache
+    (keys and values for the layers that attend over their past, the
+    gated delta rule's state and convolution tail for the linear ones).
     Every leaf is batch-leading in both families."""
     if cfg.arch == "transformer":
         from dotaclient_tpu.models.transformer_policy import init_cache
@@ -249,9 +251,9 @@ def initial_state(cfg: PolicyConfig, batch_shape):
 def wire_state(cfg: PolicyConfig, state):
     """The (c, h) [B, H] f32 pair the fixed wire format ships with each
     rollout (transport/serialize.py). The LSTM's state IS that pair; a
-    transformer KVCache maps to zeros — the learner's unroll is
-    chunk-local and ignores initial state, so nothing real is lost and
-    the wire format stays family-agnostic."""
+    transformer KVCache (a linear layer's state among it) maps to zeros —
+    the learner's unroll is chunk-local and ignores initial state, so
+    nothing real is lost and the wire format stays family-agnostic."""
     if cfg.arch == "transformer":
         import numpy as np
 
@@ -266,7 +268,9 @@ def reset_between_chunks(cfg: PolicyConfig, state):
     its state across chunks (the learner receives it on the wire —
     SURVEY.md §7 "LSTM state handoff"); the transformer family resets to
     an empty cache so acting context matches the learner's chunk-local
-    teacher-forced re-eval exactly."""
+    teacher-forced re-eval exactly: a linear layer's state and
+    convolution tail go to zero with the keys and values, which is where
+    the learner's unroll starts them."""
     if cfg.arch == "transformer":
         from dotaclient_tpu.models.transformer_policy import init_cache
 

@@ -14,7 +14,13 @@ train step, staging and wire format are all family-agnostic:
 - `unroll=False` (actor): the carried state is a `KVCache`; one step
   writes the new token's K/V at each row's slot and attends over the
   cache. Per-row write indices mean batched actors at different episode
-  phases share one compiled step.
+  phases share one compiled step. What the carry holds follows the
+  layers' kinds (`layer_kinds`): a full, sliding or gated layer keeps
+  every frame's keys and values; a latent layer every frame's latent and
+  the one rotated key; a linear layer keeps nothing per frame, only its
+  rule's state (a matrix a value head) and the last frames its
+  convolution still reads. Each kind's arrays have a slot for the layers
+  of that kind alone.
 - `unroll=True` (learner): teacher-forced causal attention over the
   whole [B, T, ...] chunk; the passed state is IGNORED — context is
   chunk-local by design, and the actor resets its cache at every chunk
@@ -42,6 +48,7 @@ from jax.sharding import Mesh
 
 from dotaclient_tpu.config import PolicyConfig
 from dotaclient_tpu.ops import attention as A
+from dotaclient_tpu.ops import gated_delta as GD
 from dotaclient_tpu.ops import moe
 from dotaclient_tpu.ops import ring_attention as RA
 
@@ -50,19 +57,26 @@ class KVCache(NamedTuple):
     """Actor-side attention state. Every leaf is BATCH-LEADING (like the
     LSTM's (c, h)) so the generic state plumbing — selfplay's per-side
     concat/slice batching, the actor's row resets — works unchanged:
-    k/v [B, L, C, G, Dh] (G key/value heads); with latent layers k is the
-    rotated key that every head shares [B, L, C, 1, tf_qk_rope_dim] and v
-    the normed latent [B, L, C, 1, tf_kv_lora_rank], and no head's keys or
-    values are kept; pos [B, C] holds absolute positions with
-    EMPTY_POS in unwritten slots (shared across layers — every layer
-    sees the same timeline); idx [B] is each row's next write slot and the
-    count of frames stepped."""
+    k/v [B, La, C, G, Dh] (G key/value heads) for the La layers that attend
+    over their past (full, sliding, gated; all L without linear layers);
+    with latent layers k is the rotated key that every head shares
+    [B, L, C, 1, tf_qk_rope_dim] and v the normed latent
+    [B, L, C, 1, tf_kv_lora_rank], and no head's keys or values are kept;
+    pos [B, C] holds absolute positions with EMPTY_POS in unwritten slots
+    (shared across layers — every layer sees the same timeline); idx [B]
+    is each row's next write slot and the count of frames stepped. The Ll
+    linear layers keep no frame: s [B, Ll, Hv, d, d] f32 is each value
+    head's state of the gated delta rule and conv [B, Ll, K - 1, channels]
+    the inputs of the last K - 1 frames that the convolution of q, k and v
+    still reads; both None in a model without linear layers."""
 
     k: jnp.ndarray
     v: jnp.ndarray
     pos: jnp.ndarray
     idx: jnp.ndarray
     rsum: jnp.ndarray  # [B, L, 2, E] f32: each layer's sums of `ops.moe.standardize` over the frames so far
+    s: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
 
 
 def is_latent(cfg: PolicyConfig) -> bool:
@@ -102,15 +116,31 @@ def head_shape(cfg: PolicyConfig) -> Tuple[int, int, int]:
     return N, G, Dh
 
 
+def linear_shape(cfg: PolicyConfig) -> Tuple[int, int, int, int]:
+    """(key heads, value heads, head width, convolution taps) of a linear
+    layer."""
+    Hk, Hv, d, K = cfg.tf_lin_key_heads, cfg.tf_lin_value_heads, cfg.tf_lin_head_dim, cfg.tf_lin_conv
+    if min(Hk, Hv, d) <= 0 or Hv % Hk or K < 1:
+        raise ValueError(
+            f"a linear layer needs tf_lin_key_heads dividing tf_lin_value_heads, tf_lin_head_dim and "
+            f"tf_lin_conv >= 1: {(Hk, Hv, d, K)}"
+        )
+    return Hk, Hv, d, K
+
+
 def layer_kinds(cfg: PolicyConfig) -> Tuple[str, ...]:
     """The kind of each of the tf_layers layers: cfg.tf_layer_kinds'
     comma list, repeated."""
     period = [k.strip() for k in (cfg.tf_layer_kinds or "full").split(",")]
     for k in period:
-        if k not in ("full", "sliding", "latent"):
-            raise ValueError(f"tf_layer_kinds: unknown kind {k!r} (full|sliding|latent)")
+        if k not in ("full", "sliding", "latent", "gated", "linear"):
+            raise ValueError(
+                f"tf_layer_kinds: unknown kind {k!r} (full|sliding|gated: keys and values of every frame in "
+                f"the carry; latent: every frame's latent; linear: a state matrix a head and no frame)")
         if k == "sliding" and cfg.tf_window <= 0:
             raise ValueError("a sliding layer needs tf_window > 0")
+        if k == "linear":
+            linear_shape(cfg)
     if "latent" in period and set(period) != {"latent"}:
         raise ValueError("tf_layer_kinds: latent layers share no KVCache with another kind")
     return tuple(period[i % len(period)] for i in range(cfg.tf_layers))
@@ -128,15 +158,23 @@ def init_cache(cfg: PolicyConfig, batch_shape) -> KVCache:
     # (2x actor cache bytes); scores still accumulate in f32 inside
     # attention (ADVICE r3 item 3). pos/idx stay int32.
     dt = jnp.dtype(cfg.dtype)
-    k_shape = v_shape = (B, L, C, G, Dh)
+    n_linear = layer_kinds(cfg).count("linear")
+    k_shape = v_shape = (B, L - n_linear, C, G, Dh)
     if is_latent(cfg):
         k_shape, v_shape = (B, L, C, 1, cfg.tf_qk_rope_dim), (B, L, C, 1, cfg.tf_kv_lora_rank)
+    s = conv = None
+    if n_linear:
+        Hk, Hv, d, K = linear_shape(cfg)
+        s = jnp.zeros((B, n_linear, Hv, d, d), jnp.float32)
+        conv = jnp.zeros((B, n_linear, K - 1, (2 * Hk + Hv) * d), dt)
     return KVCache(
         k=jnp.zeros(k_shape, dt),
         v=jnp.zeros(v_shape, dt),
         pos=jnp.full((B, C), A.EMPTY_POS, jnp.int32),
         idx=jnp.zeros((B,), jnp.int32),
         rsum=jnp.zeros((B, L, 2, cfg.moe_experts), jnp.float32),
+        s=s,
+        conv=conv,
     )
 
 
@@ -289,23 +327,32 @@ def _from_heads(cfg: PolicyConfig, attn: jnp.ndarray, name: str) -> jnp.ndarray:
 
 
 def _attention(block: "Block", x, positions, cache):
-    """The full or sliding layer's attention part: what is added to
-    x, and the new (k_cache, v_cache) or None. q, k and v leave their
-    products as whole heads, [B, T, heads, Dh], and reach attention so."""
+    """The full, sliding or gated layer's attention part: what is added
+    to x, and the new (k_cache, v_cache) or None. q, k and v leave their
+    products as whole heads, [B, T, heads, Dh], and reach attention so.
+    A gated layer is a full one with a norm of the config's kind on each
+    head's q and k (one weight vector for all heads) and the heads'
+    output times a sigmoid of a gate, N more heads of `qkv`'s columns
+    after v's, applied to the heads as attention wrote them."""
     cfg = block.cfg
     N, G, Dh = head_shape(cfg)
-    sliding = block.kind == "sliding"
+    dt = jnp.dtype(cfg.dtype)
+    sliding, gated = block.kind == "sliding", block.kind == "gated"
     window = cfg.tf_window if sliding else 0
-    table = A.rope_table(Dh, cfg.tf_rope_theta) if sliding or not cfg.tf_yarn_factor else (
-        A.rope_table(Dh, cfg.tf_rope_theta, cfg.tf_yarn_factor, cfg.tf_yarn_original_context,
+    rotary = cfg.tf_rotary_dim or Dh
+    span = (0, rotary) if rotary < Dh else None
+    table = A.rope_table(rotary, cfg.tf_rope_theta) if sliding or not cfg.tf_yarn_factor else (
+        A.rope_table(rotary, cfg.tf_rope_theta, cfg.tf_yarn_factor, cfg.tf_yarn_original_context,
                      cfg.tf_yarn_beta_fast, cfg.tf_yarn_beta_slow))
-    heads = _by_head(cfg, _norm(cfg, "ln1")(x), N + 2 * G, Dh, "qkv")
+    heads = _by_head(cfg, _norm(cfg, "ln1")(x), N + 2 * G + (N if gated else 0), Dh, "qkv")
+    normed = (lambda a, name: _norm(cfg, name)(a).astype(dt)) if gated else (lambda a, name: a)
     # RoPE at this token's absolute position; cached K were rotated
     # at write time, so angles are consistent across modes. The
     # fused kernel wants the scores' 1/sqrt(Dh) in q: it goes into
     # the rotation, which is still float32.
-    q = A.rope(heads(0, N), positions, table=table, scale=Dh**-0.5 if block.fused else 1.0)
-    k = A.rope(heads(N, G), positions, table=table)
+    q = A.rope(normed(heads(0, N), "q_norm"), positions, table=table, span=span,
+               scale=Dh**-0.5 if block.fused else 1.0)
+    k = A.rope(normed(heads(N, G), "k_norm"), positions, table=table, span=span)
     v = heads(N + G, G)
 
     new_cache = None
@@ -324,7 +371,55 @@ def _attention(block: "Block", x, positions, cache):
         v_cache = jnp.where(sel, v.astype(v_cache.dtype), v_cache)
         attn = RA.attend(q, k_cache, v_cache, positions, cache_pos, window=window)
         new_cache = (k_cache, v_cache)
+    if gated:
+        attn = attn.astype(jnp.float32) * jax.nn.sigmoid(heads(N + 2 * G, N).astype(jnp.float32))
     return _from_heads(cfg, attn, "attn_out"), new_cache
+
+
+def _linear_attention(block: "Block", x, positions, cache):
+    """The linear layer's part (ops/gated_delta.py has the rule): what is
+    added to x, and the new (state, convolution tail) or None.
+
+        [q | k | v | z] = n(x) W_qkvz   Hk, Hk, Hv, Hv heads of d, written by head
+        [b | a]         = n(x) W_ba     one scalar each a value head
+        q, k, v <- silu(conv(q), conv(k), conv(v))     causal, depthwise, K taps
+        beta = sigmoid(b),  g = -exp(A_log) softplus(a + dt_bias) <= 0
+        q <- q / |q| / sqrt(d),  k <- k / |k|
+        o = rule(q, k, v, beta, g)      chunked in the unroll, one frame in the step
+        y = (n_head(o) * silu(z)) W_o   n_head: one weight vector [d] for all heads
+
+    The convolution's filter is one [K, channels] matrix over q's, k's
+    and v's channels side by side, taken by its columns as the heads are;
+    decays, beta, the norms and the state are float32."""
+    cfg = block.cfg
+    Hk, Hv, d, K = linear_shape(cfg)
+    dt, f32 = jnp.dtype(cfg.dtype), jnp.float32
+    h = _norm(cfg, "ln1")(x)
+    heads = _by_head(cfg, h, 2 * Hk + 2 * Hv, d, "qkvz")
+    ba = _dense(cfg, 2 * Hv, "ba")(h.astype(dt)).astype(f32)
+    decay = -jnp.exp(block.param("A_log", nn.initializers.zeros_init(), (Hv,)))
+    dt_bias = block.param("dt_bias", nn.initializers.zeros_init(), (Hv,))
+    beta, g = jax.nn.sigmoid(ba[..., :Hv]), decay * jax.nn.softplus(ba[..., Hv:] + dt_bias)
+    filt, _ = Kernel((K, (2 * Hk + Hv) * d), name="conv")()
+    tail = None if cache is None else cache[1]
+    mixed, tails = [], []
+    for first, n in ((0, Hk), (Hk, Hk), (2 * Hk, Hv)):  # q, k, v: by head through the filter's columns
+        cols = slice(first * d, (first + n) * d)
+        before = None if tail is None else tail[..., cols].reshape(tail.shape[:2] + (n, d))
+        out, last = GD.causal_conv(heads(first, n), filt[:, cols].reshape(K, n, d), before)
+        mixed.append(nn.silu(out))
+        tails.append(last.reshape(last.shape[:2] + (n * d,)))
+    q, k, v = mixed
+    q, k, v = (GD.l2norm(q) * d**-0.5).astype(dt), GD.l2norm(k).astype(dt), v.astype(dt)
+
+    new_cache = None
+    if cache is None:
+        o, _ = GD.chunked(q, k, v, beta, g, GD.CHUNK)  # a short row is padded to a chunk
+    else:
+        S, o = GD.step(cache[0], q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0])
+        o, new_cache = o[:, None], (S, jnp.concatenate(tails, axis=-1).astype(tail.dtype))
+    o = RMSNorm(cfg.tf_norm_eps, name="out_norm")(o) * nn.silu(heads(2 * Hk + Hv, Hv).astype(f32))
+    return _from_heads(cfg, o, "attn_out"), new_cache
 
 
 def _latent_attention(block: "Block", x, positions, cache):
@@ -392,11 +487,16 @@ def _latent_attention(block: "Block", x, positions, cache):
     return _from_heads(cfg, attn, "attn_out"), new_cache
 
 
+# The named scope of a layer kind's attention part ("full": attn_full).
+SCOPES = {"latent": "attn_latent", "sliding": "attn_window", "gated": "attn_gated", "linear": "attn_linear"}
+
+
 class Block(nn.Module):
     """Pre-norm transformer block: norm → causal attention (+residual) →
     norm → feed-forward (+residual). The sizes are the config's: grouped
-    key/value heads, a head width of its own, a full or a sliding layer
-    with that kind's rotary table, or latent attention; LayerNorm or
+    key/value heads, a head width of its own, a full, a sliding or a gated
+    layer with that kind's rotary table, latent attention, or a linear
+    layer (the gated delta rule in attention's place); LayerNorm or
     RMSNorm; a dense MLP (GELU of 4x, or SwiGLU of a width of its own) or
     a routed-expert layer with or without a shared expert beside it.
     Matmuls in cfg.dtype (MXU); norms, softmax, router and the residual
@@ -424,14 +524,16 @@ class Block(nn.Module):
         T==1 stepping — the block writes its fresh K/V into the cache at
         write_onehot and attends over the merged cache; a sliding layer
         masks by position, a latent layer's two cache arrays are the
-        rotated shared key [B,C,1,rope] and the latent [B,C,1,rank].
+        rotated shared key [B,C,1,rope] and the latent [B,C,1,rank], a
+        linear layer's its rule's state [B,Hv,d,d] and its convolution's
+        tail [B,K-1,channels], which it replaces.
         Returns (x_out, new cache or None, the routed-expert layer's
         counts or None)."""
         cfg = self.cfg
         # Named scopes: the layer an operation belongs to, in its
         # `op_name`, the norm that feeds a part inside that part's scope.
-        attention = _latent_attention if self.kind == "latent" else _attention
-        with jax.named_scope({"latent": "attn_latent", "sliding": "attn_window"}.get(self.kind, "attn_full")):
+        attention = {"latent": _latent_attention, "linear": _linear_attention}.get(self.kind, _attention)
+        with jax.named_scope(SCOPES.get(self.kind, "attn_full")):
             out, new_cache = attention(self, x, positions, cache)
             x = x + out.astype(jnp.float32)
 
@@ -442,7 +544,11 @@ class Block(nn.Module):
                 y, counts, rsum = ExpertLayer(cfg, self.platform, name="moe")(h, seen)
             if cfg.moe_shared_hidden:
                 with jax.named_scope("moe_shared"):
-                    y = y + _swiglu(cfg, h, cfg.moe_shared_hidden, "shared").astype(jnp.float32)
+                    shared = _swiglu(cfg, h, cfg.moe_shared_hidden, "shared").astype(jnp.float32)
+                    if cfg.moe_shared_gate:
+                        gate = _dense(cfg, 1, "shared_expert_gate")(h.astype(jnp.dtype(cfg.dtype)))
+                        shared = shared * jax.nn.sigmoid(gate.astype(jnp.float32))
+                    y = y + shared
             return x + y, new_cache and new_cache + (rsum,), counts
         with jax.named_scope("mlp"):
             h = _norm(cfg, "ln2")(x)
@@ -520,11 +626,14 @@ class TransformerCore(nn.Module):
             # name is the policy's), so its forward pass runs once a step.
             keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS) if fused else None
             block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
+            n_linear = kinds.count("linear")
+            if n_linear and self.sp_mesh is not None and cfg.tf_sp_axis in self.sp_mesh.axis_names:
+                raise ValueError("a linear layer's state is not carried over the sp axis")
             counts = []
             for i, kind in enumerate(kinds):
                 h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, fused, sparse[i], name=f"block{i}")(h, positions)
                 counts.append(n)
-            stats = {"attn_fused_layers": jnp.float32(len(kinds) if fused else 0), **_moe_stats(counts)}
+            stats = {"attn_fused_layers": jnp.float32(len(kinds) - n_linear if fused else 0), **_moe_stats(counts)}
             return carry, final(h), stats
 
         assert isinstance(carry, KVCache), "transformer step mode needs a KVCache carry"
@@ -540,19 +649,23 @@ class TransformerCore(nn.Module):
         new_pos = jnp.where(onehot > 0, positions, carry.pos).astype(jnp.int32)
 
         h = x.astype(jnp.float32)[:, None, :]  # [B, 1, D]
-        ks, vs, rs = [], [], []
+        # each kind's pair of arrays has a slot for the layers of that kind, in layer order
+        old = {"linear": (carry.s, carry.conv), "attend": (carry.k, carry.v)}
+        new = {"linear": [], "attend": []}
+        rs = []
         for i, kind in enumerate(kinds):
-            h, (k_i, v_i, r_i), _ = Block(cfg, kind, platform=platform, sparse=sparse[i], name=f"block{i}")(
-                h, positions,
-                cache=(carry.k[:, i], carry.v[:, i], new_pos, onehot, carry.rsum[:, i]),
-            )
-            ks.append(k_i)
-            vs.append(v_i)
-            rs.append(r_i)
-        new_carry = KVCache(
-            k=jnp.stack(ks, axis=1), v=jnp.stack(vs, axis=1), pos=new_pos, idx=carry.idx + 1,
-            rsum=jnp.stack(rs, axis=1),
-        )
+            mine = "linear" if kind == "linear" else "attend"
+            a, b = (x[:, len(new[mine])] for x in old[mine])
+            h, (a, b, r), _ = Block(cfg, kind, platform=platform, sparse=sparse[i], name=f"block{i}")(
+                h, positions, cache=(a, b, new_pos, onehot, carry.rsum[:, i]))
+            new[mine].append((a, b))
+            rs.append(r)
+
+        def stacked(kind):  # a kind with no layer keeps what it came with (empty arrays, or None)
+            return tuple(jnp.stack(x, axis=1) for x in zip(*new[kind])) if new[kind] else old[kind]
+
+        (k, v), (s, conv) = stacked("attend"), stacked("linear")
+        new_carry = KVCache(k=k, v=v, pos=new_pos, idx=carry.idx + 1, rsum=jnp.stack(rs, axis=1), s=s, conv=conv)
         return new_carry, final(h)[:, 0, :], None
 
 

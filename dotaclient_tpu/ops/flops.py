@@ -55,7 +55,8 @@ def policy_forward_flops_per_frame(cfg: PolicyConfig, chunk_frames: int = 0) -> 
 
     # temporal core
     if cfg.arch == "transformer":
-        from dotaclient_tpu.models.transformer_policy import ff_sparse, head_shape, is_latent, latent_shape, layer_kinds
+        from dotaclient_tpu.models.transformer_policy import (ff_sparse, head_shape, is_latent, latent_shape,
+                                                              layer_kinds, linear_shape)
 
         N, G, Dh = head_shape(cfg)
         frames = chunk_frames or cfg.tf_context
@@ -70,14 +71,22 @@ def policy_forward_flops_per_frame(cfg: PolicyConfig, chunk_frames: int = 0) -> 
             per_pair = 2.0 * N * (nope + rope) + 2.0 * N * v_dim
         held = cfg.moe_experts_held or cfg.moe_experts
         pairs_here = cfg.moe_top_k * held / max(cfg.moe_experts, 1)
-        # router, the held pairs of an even routing, the shared expert
+        # router, the held pairs of an even routing, the shared expert and its gate's one column
         sparse = (2.0 * H * cfg.moe_experts + pairs_here * 3 * 2.0 * H * cfg.moe_hidden
-                  + 3 * 2.0 * H * cfg.moe_shared_hidden)
+                  + 3 * 2.0 * H * cfg.moe_shared_hidden + (2.0 * H if cfg.moe_shared_gate else 0.0))
         width = cfg.tf_mlp_hidden or 4 * H
         dense = (3 if cfg.tf_mlp_act == "swiglu" else 2) * 2.0 * H * width  # gate, up, down | up, down
         for kind, is_sparse in zip(layer_kinds(cfg), ff_sparse(cfg)):
+            fl += sparse if is_sparse else dense
+            if kind == "linear":
+                # qkvz, ba, out; the rule by its recurrence, whatever form computes it: S^T k,
+                # k u^T and S^T q a value head and frame (the convolution multiplies no matrices)
+                Hk, Hv, d, _ = linear_shape(cfg)
+                fl += 2.0 * H * (2 * Hk + 2 * Hv) * d + 2.0 * H * 2 * Hv + 2.0 * Hv * d * H + 3 * 2.0 * d * d * Hv
+                continue
             pairs = attended_pairs(frames, cfg.tf_window if kind == "sliding" else 0)
-            fl += proj + (sparse if is_sparse else dense) + pairs * per_pair / frames  # of the kept pairs
+            gate = 2.0 * H * N * Dh if kind == "gated" else 0.0  # the output gate's columns of qkv
+            fl += proj + gate + pairs * per_pair / frames  # of the kept pairs
     else:
         fl += 2.0 * H * 4 * H  # x-projection (input is the trunk's H)
         fl += 2.0 * H * 4 * H  # recurrence hidden projection

@@ -364,3 +364,59 @@ def test_the_latent_cells_step_compiles_and_fits(topo):
     params = 4 * 513_183_671
     assert m.argument_size_in_bytes > 3 * params  # the parameters and Adam's two moments
     assert m.temp_size_in_bytes + m.argument_size_in_bytes + 2 * params < HBM_BYTES
+
+
+@pytest.mark.parametrize("kind,scope", [("linear", "attn_linear"), ("gated", "attn_gated")])
+def test_the_linear_cells_blocks_compile_and_fit(topo, kind, scope):
+    """One linear and one gated block of `learner-qwen3next-ep32-wire` at
+    its shapes (4 rows of 4,096 frames; the feed-forward part a SwiGLU of
+    256, so that each compiles in 10-25 s), forward and backward under
+    `nn.remat` with the step's policy, for one described chip. The linear
+    block: the rule's loops over segments and chunks under `attn_linear`,
+    no kernel, and a scratch of a segment's and not the row's (2.7 GB by
+    the compiler's count; 7.2 GB with the whole row's triangular systems
+    and states kept for the backward pass, with which the step's 10.8 GB
+    of scratch did not fit beside 4.2 GB of state and the publish's
+    buffers). The gated block: the two splash kernels under `attn_gated`
+    at 16 query heads on 2 of 256, and between the one product and the
+    kernel no slice, pad, concatenation or copy of part of a head (the
+    rotary span is lanes 0-63 of whole heads; the gate reaches the heads
+    as attention wrote them)."""
+    from flax import linen as nn
+
+    from benchmark import cells, harness
+    from dotaclient_tpu.models.transformer_policy import Block
+    from dotaclient_tpu.ops import attention as A
+    from dotaclient_tpu.ops import gated_delta as GD
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cell = cells.load_cell(cells.load_benchmark(), "learner-qwen3next-ep32-wire")
+    policy = harness.learner_config(cell, seed=0, broker_url="mem://x").policy
+    assert policy.tf_remat and GD.CHUNK == 64
+    cfg = dataclasses.replace(policy, moe_experts=0, moe_experts_held=0, moe_shared_hidden=0,
+                              tf_mlp_act="swiglu", tf_mlp_hidden=256)
+    B, T, D = 4, 4096, cfg.lstm_hidden
+    keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS)
+    block = nn.remat(Block, policy=keep)(cfg, kind, None, "tpu", True, False)
+    positions = lambda: jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    x = jax.ShapeDtypeStruct((B, T, D), jnp.float32)
+    params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), positions()))
+
+    def loss(params, x):
+        return jnp.sum(block.apply(params, x, positions())[0] ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*_on(one, (params, x))).compile()
+    text = compiled.as_text()
+    kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
+    assert _fits(compiled)
+    if kind == "linear":
+        loops = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("/while")]
+        assert not kernels and loops and all(f"/{scope}/" in n for n in loops), loops
+        assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+        return
+    assert sum("splash_mha_fwd" in n for n in kernels) >= 1 and sum("splash_mha_dkv" in n for n in kernels) >= 1
+    assert all(f"/{scope}/" in n for n in kernels), kernels
+    part_of_a_head = re.compile(
+        r"= (bf16|f32)\[4,(4096,(16|2)|(16|2),4096),(32|64|192)\]\S* (slice|dynamic-slice|pad|concatenate|copy)\(")
+    found = [ln.strip()[:200] for ln in text.splitlines() if part_of_a_head.search(ln)]
+    assert not found, found

@@ -835,6 +835,7 @@ class TestLatentBlockShape:
     ("learner-lstm4096-wire", 136_584_631, 23, "bb9966d3313eb21e"),
     ("learner-mellum2-ep4-wire", 483_239_607, 53, "873687eec0b03b99"),
     ("learner-glm47flash-ep8-wire", 513_183_671, 101, "a55da48890cbb943"),
+    ("learner-qwen3next-ep32-wire", 347_736_567, 86, "f725b3687b64af7f"),  # as PR 38 made it
 ])
 def test_the_cells_parameter_trees_are_what_they_were(cell, count, leaves, digest):
     """Names, shapes and types of every parameter of the three benchmark
