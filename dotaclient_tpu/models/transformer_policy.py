@@ -622,11 +622,13 @@ class TransformerCore(nn.Module):
             # cfg.tf_remat: recompute each block's activations in the
             # backward instead of storing them (jax.checkpoint) —
             # O(T·D) residuals per block instead of every intermediate.
-            # The fused kernel's output and log-sum-exp are kept (their
-            # name is the policy's), so its forward pass runs once a step.
-            keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS) if fused else None
-            block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
+            # Kept by name, so that each runs forward once a step: the
+            # fused kernel's output and log-sum-exp, and a linear layer's
+            # solved systems, chunk-entering states, u and o (GD.chunked).
             n_linear = kinds.count("linear")
+            names = [A.FUSED_RESIDUALS] * bool(fused) + [GD.RULE_RESIDUALS] * bool(n_linear)
+            keep = jax.checkpoint_policies.save_only_these_names(*names) if names else None
+            block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
             if n_linear and self.sp_mesh is not None and cfg.tf_sp_axis in self.sp_mesh.axis_names:
                 raise ValueError("a linear layer's state is not carried over the sp axis")
             counts = []

@@ -366,18 +366,54 @@ def test_the_latent_cells_step_compiles_and_fits(topo):
     assert m.temp_size_in_bytes + m.argument_size_in_bytes + 2 * params < HBM_BYTES
 
 
+def _loops(text: str) -> list:
+    """The `op_name` of every `while` operation of a compiled program."""
+    return [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in text.splitlines()
+            if " while(" in ln and "condition=" in ln]
+
+
+def test_the_linear_cells_step_compiles_and_fits(topo):
+    """The whole step of `learner-qwen3next-ep32-wire` from its
+    configuration's file (4 rows of 4,096 frames, three linear layers to
+    one gated, 348M parameters) for one described chip, as the benchmark's
+    harness builds it: per linear layer one scan over the row's segments
+    in the forward pass and one reverse scan in the backward, none in the
+    rematerialised forward (the rule's solved systems, entering states,
+    u and o are kept by name: 2.2 GB of the 7.8 GB of scratch), and
+    arguments, scratch and the two flat buffers of a weight publish
+    together inside the chip's memory. About 115 s, as the latent cell's."""
+    from benchmark import cells, harness
+
+    cell = cells.load_cell(cells.load_benchmark(), "learner-qwen3next-ep32-wire")
+    cfg = harness.learner_config(cell, seed=0, broker_url="mem://x")
+    assert (cfg.batch_size, cfg.seq_len, cfg.policy.tf_layers) == (4, 4095, 4)
+    compiled = _compile_train_step(cfg, topo.devices[:1])
+    rule = [n for n in _loops(compiled.as_text()) if "/attn_linear/" in n]
+    assert sum("/while/body/" not in n for n in rule) == 6 and not [n for n in rule if "rematted_computation" in n], rule
+    m = compiled.memory_analysis()
+    params = 4 * 347_736_567
+    assert m.argument_size_in_bytes > 3 * params  # the parameters and Adam's two moments
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes + 2 * params < HBM_BYTES
+
+
 @pytest.mark.parametrize("kind,scope", [("linear", "attn_linear"), ("gated", "attn_gated")])
 def test_the_linear_cells_blocks_compile_and_fit(topo, kind, scope):
     """One linear and one gated block of `learner-qwen3next-ep32-wire` at
     its shapes (4 rows of 4,096 frames; the feed-forward part a SwiGLU of
     256, so that each compiles in 10-25 s), forward and backward under
     `nn.remat` with the step's policy, for one described chip. The linear
-    block: the rule's loops over segments and chunks under `attn_linear`,
-    no kernel, and a scratch of a segment's and not the row's (2.7 GB by
-    the compiler's count; 7.2 GB with the whole row's triangular systems
-    and states kept for the backward pass, with which the step's 10.8 GB
-    of scratch did not fit beside 4.2 GB of state and the publish's
-    buffers). The gated block: the two splash kernels under `attn_gated`
+    block: no kernel, and the rule's loops under `attn_linear` by count,
+    6 `while` operations where the parent of PR 40 had 15: one scan over
+    the row's 32 segments in the forward pass and one reverse scan in the
+    backward (the rule's own, `GD._rule_bwd`), inside each the scan of
+    the state over a segment's chunks, inside the forward's two two-step
+    loops that a `gather` of `_solve` becomes, and none in the
+    rematerialised forward, which finds the solved systems, the entering
+    states, u and o kept by name; the scratch 2.6 GB by the compiler's
+    count (0.74 GB of it the kept arrays; with the whole row's float32
+    intermediates kept for autodiff it was 7.2 GB, and 2.7 GB eight
+    chunks at a time under a checkpoint of their own, at three
+    forwards a step). The gated block: the two splash kernels under `attn_gated`
     at 16 query heads on 2 of 256, and between the one product and the
     kernel no slice, pad, concatenation or copy of part of a head (the
     rotary span is lanes 0-63 of whole heads; the gate reaches the heads
@@ -396,7 +432,7 @@ def test_the_linear_cells_blocks_compile_and_fit(topo, kind, scope):
     cfg = dataclasses.replace(policy, moe_experts=0, moe_experts_held=0, moe_shared_hidden=0,
                               tf_mlp_act="swiglu", tf_mlp_hidden=256)
     B, T, D = 4, 4096, cfg.lstm_hidden
-    keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS)
+    keep = jax.checkpoint_policies.save_only_these_names(A.FUSED_RESIDUALS, GD.RULE_RESIDUALS)
     block = nn.remat(Block, policy=keep)(cfg, kind, None, "tpu", True, False)
     positions = lambda: jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     x = jax.ShapeDtypeStruct((B, T, D), jnp.float32)
@@ -410,9 +446,11 @@ def test_the_linear_cells_blocks_compile_and_fit(topo, kind, scope):
     kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
     assert _fits(compiled)
     if kind == "linear":
-        loops = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("/while")]
-        assert not kernels and loops and all(f"/{scope}/" in n for n in loops), loops
-        assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+        loops = _loops(text)
+        assert not kernels and all(f"/{scope}/" in n for n in loops), loops
+        assert len(loops) <= 6 and sum("/while/body/" not in n for n in loops) == 2, loops  # one forward, one reverse
+        assert not [n for n in loops if "rematted_computation" in n], loops
+        assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
         return
     assert sum("splash_mha_fwd" in n for n in kernels) >= 1 and sum("splash_mha_dkv" in n for n in kernels) >= 1
     assert all(f"/{scope}/" in n for n in kernels), kernels
