@@ -106,6 +106,13 @@ SCALARS: Dict[str, str] = {
         "inside it (pipelined loop): how long a publish, or anything else "
         "on the host, kept the loop from handing the device its next step"
     ),
+    "loop_inflight_max": (
+        "most steps a dispatch of the metrics window found ahead of it, "
+        "dispatched and not yet complete (the loop polls is_ready() on the "
+        "results it holds; at most metrics_every - 1, since a sync empties "
+        "the queue)"
+    ),
+    "loop_inflight_mean": "mean of the same count over the window's dispatches",
     # --- compiles (obs/spans.py, a jax.monitoring listener the learner
     #     registers once; process-wide, cumulative) ---------------------
     "compile_count_total": (
@@ -289,14 +296,25 @@ PREFIXES: Dict[str, str] = {
     # learner loop with every metrics window): span_<name>_s_total and
     # span_<name>_n_total (cumulative seconds and count), the name's `.`
     # written `_`. Names: loop.dispatch / .publish_submit / .sync /
-    # .checkpoint; lane.retire / .handoff; staging.pop / .ingest / .pack
-    # / .ready_wait; publish.d2h / .serialize / .send / .latency;
+    # .sync_ready / .sync_get / .checkpoint; lane.retire / .handoff;
+    # staging.pop / .ingest / .pack / .ready_wait; publish.d2h /
+    # .ready_wait / .copy / .serialize / .send / .latency / .age;
     # setup.learner_init / .init_params / .restore / .publish0. The same
     # spans lie on the profiler's timeline while a session is open (there
     # also loop.take, lane.wait_batch and lane.device_put, whose sums are
     # pipeline_device_idle_s, time_wait_batch_s and time_device_put_s). A
     # family: one span more is one name more.
     "span_": "program spans: cumulative seconds and count (obs/spans.py)",
+    # dispatches that found no step in flight (runtime/learner.py
+    # _InFlight, emitted with every metrics window, cumulative):
+    # loop_starved_n_total counts them (a run's first is none), and
+    # loop_starved_<cause>_s_total takes each one's seconds since the
+    # loop last knew the device busy (its previous dispatch) or done
+    # (loop.sync_ready's exit), under what the loop spent most of them
+    # in: take (the lane had no batch), sync (the metrics read and the
+    # window's bookkeeping), publish (loop.publish_submit), checkpoint,
+    # other. Exact after a sync, an upper bound after a dispatch.
+    "loop_starved_": "dispatches that found the device's queue empty: count, and seconds by cause",
     # replay reservoir stats + age histogram, re-prefixed by staging:
     # replay_occupancy, replay_admitted, replay_age_le_<edge>, ...
     "replay_": "replay reservoir health (runtime/staging.py stats passthrough)",
@@ -324,7 +342,8 @@ PREFIXES: Dict[str, str] = {
     # pipeline_prefetch_fetch_s / _pack_s / _h2d_s (the lane's own phase
     # split, fenced ON THE LANE so attribution costs no overlap),
     # pipeline_device_idle_s (the loop's exposed wait for a prefetched
-    # batch — the device-idle-per-step upper bound),
+    # batch; no bound on the device's idle time while steps are queued:
+    # loop_starved_take_s_total counts the part that found none),
     # pipeline_overlap_ratio (share of lane work hidden behind the
     # device step; 1.0 = the host fully disappeared). A family: the
     # lane split can grow phases.

@@ -44,6 +44,8 @@ device except where semantics require it:
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import logging
 import os
 import queue
@@ -85,8 +87,10 @@ class ParamFlattener:
     concatenates the raveled leaves into one f32 buffer ON DEVICE
     (async dispatch, one copy: 0.9 MB at the 128-wide policy, 546 MB =
     136,584,631 f32 at the 4096-wide one); the blocking host read of
-    that single buffer happens on the publisher thread (`publish.d2h`,
-    0.45-0.73 s a publish at 4096 on a v5e; PERF.md section 5), and
+    that single buffer happens on the publisher thread (`publish.d2h`:
+    at 4096 on a v5e 0.32 s of waiting for the steps the loop had
+    dispatched ahead of the flatten, `publish.ready_wait`, then 0.16 s
+    of copy, `publish.copy`; PERF.md section 5), and
     `serialize_weights` then copies each leaf's slice of it once into
     the frame. Stream ordering makes this donation-safe: the flatten
     program is dispatched BEFORE the next (state-donating) train step,
@@ -214,7 +218,14 @@ class WeightPublisher:
                 self._slot = None
             try:
                 with span("publish.d2h", version=version):
-                    named = self._materialize(np_params)
+                    # The wait for every step dispatched before the
+                    # flatten, and the flatten: the device's time, not
+                    # the copy's. A host pytree passes through at once.
+                    with span("publish.ready_wait", version=version):
+                        jax.block_until_ready(np_params)
+                    t_ready = time.perf_counter_ns()
+                    with span("publish.copy", version=version):
+                        named = self._materialize(np_params)
                 with span("publish.serialize", version=version):
                     frame = serialize_weights(
                         named,
@@ -225,10 +236,14 @@ class WeightPublisher:
                 del named  # the host copy goes before the send, as it always did
                 with span("publish.send", version=version):
                     self._broker.publish_weights(frame)
-                # Submit on the loop thread to sent: how old the version
-                # is when an actor can first read it. No timeline span:
-                # it starts on another thread.
-                spans.add("publish.latency", time.perf_counter_ns() - t_submit)
+                # Submit on the loop thread to sent (the loop submits
+                # ahead of the device, so this counts the host's lead
+                # too), and the weights existing on the device to sent:
+                # how old the version is when an actor can first read it.
+                # No timeline spans: the first starts on another thread.
+                t_sent = time.perf_counter_ns()
+                spans.add("publish.latency", t_sent - t_submit)
+                spans.add("publish.age", t_sent - t_ready)
                 self.published += 1
                 if self._on_published is not None:
                     self._on_published(version)
@@ -322,6 +337,93 @@ class CheckpointWorker:
             t = self._thread
         if t is not None:
             t.join(timeout=60)
+
+
+class _InFlight:
+    """What the loop thread knows of the device's queue of steps, from
+    the results it holds: no fence, no callback, no thread.
+
+    `pending` holds one leaf of the metrics of each dispatched step not
+    yet known complete. `poll()`, just before a dispatch, drops from its
+    left those whose `is_ready()` is true and counts what is left: the
+    steps ahead of this one (`loop_inflight_max` / `_mean` over a metrics
+    window). `synced()` empties it at `loop.sync_ready`'s exit, where
+    every step is complete.
+
+    `t_known` is when the loop last knew the device busy (a dispatch) or
+    done (`synced`). A dispatch, other than a run's first, that finds
+    nothing pending is starved: `loop_starved_n_total` counts it and
+    `loop_starved_<cause>_s_total` takes the seconds since `t_known`,
+    under what the loop spent most of them in (`charge`, `during`). After
+    a sync those seconds are the device's idle time as the host can see
+    it; after a dispatch they are an upper bound, since the step may have
+    ended at any time since. Totals are the process's, the rest the
+    run's (`begin_run`)."""
+
+    CAUSES = ("take", "sync", "publish", "checkpoint", "other")
+
+    def __init__(self):
+        self.pending = collections.deque()
+        self.t_known: Optional[float] = None
+        self._spent = dict.fromkeys(self.CAUSES[:-1], 0.0)
+        self._win = [0, 0, 0]  # polls, sum and max of their counts, this metrics window
+        self.starved_n = 0
+        self.starved_s = dict.fromkeys(self.CAUSES, 0.0)
+
+    def begin_run(self) -> None:
+        self.pending.clear()
+        self.t_known = None
+        self._win = [0, 0, 0]
+
+    def _know(self) -> None:
+        self.t_known = time.perf_counter()
+        for cause in self._spent:
+            self._spent[cause] = 0.0
+
+    def charge(self, cause: str, seconds: float) -> None:
+        self._spent[cause] += seconds
+
+    @contextlib.contextmanager
+    def during(self, cause: str):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.charge(cause, time.perf_counter() - t0)
+
+    def poll(self) -> None:
+        pending = self.pending
+        while pending and pending[0].is_ready():
+            pending.popleft()
+        n = len(pending)
+        win = self._win
+        win[0] += 1
+        win[1] += n
+        win[2] = max(win[2], n)
+        if n == 0 and self.t_known is not None:
+            gap = time.perf_counter() - self.t_known
+            spent = dict(self._spent, other=max(gap - sum(self._spent.values()), 0.0))
+            self.starved_n += 1
+            self.starved_s[max(spent, key=spent.get)] += gap
+
+    def dispatched(self, leaf) -> None:
+        self.pending.append(leaf)
+        self._know()
+
+    def synced(self) -> None:
+        self.pending.clear()
+        self._know()
+
+    def window_scalars(self) -> dict:
+        """The window's two gauges (then reset) and the cumulative six."""
+        polls, total, most = self._win
+        self._win = [0, 0, 0]
+        return {
+            "loop_inflight_max": float(most),
+            "loop_inflight_mean": total / max(polls, 1),
+            "loop_starved_n_total": float(self.starved_n),
+            **{f"loop_starved_{cause}_s_total": s for cause, s in self.starved_s.items()},
+        }
 
 
 class _LaneItem(NamedTuple):
@@ -592,6 +694,7 @@ class Learner:
         # Long closed spans also go into the flight recorder's ring,
         # where one exists: a crash dump then holds the last stalls.
         spans.mirror_to(self.obs.recorder if self.obs is not None else None)
+        self._flight = _InFlight()
         self.staging = StagingBuffer(
             staging_cfg,
             broker,
@@ -1036,9 +1139,13 @@ class Learner:
 
     def _dispatch(self, batch_dev):
         """The loop's one call of the compiled step (async: returns once
-        the step is handed to the device)."""
+        the step is handed to the device), between a count of the steps
+        still ahead of it and the note that the device now has this one."""
+        self._flight.poll()
         with span("loop.dispatch", step=self.version + 1):
-            return self.train_step(self.state, batch_dev)
+            out = self.train_step(self.state, batch_dev)
+        self._flight.dispatched(out[1]["loss"])
+        return out
 
     def _submit_publish(self) -> None:
         """One async on-device flatten dispatch; the blocking host read
@@ -1047,7 +1154,7 @@ class Learner:
         (state-donating) train step in the loop thread's stream order
         (ParamFlattener docstring; the lane only ever touches batch
         buffers, never the state)."""
-        with span("loop.publish_submit", version=self.version):
+        with self._flight.during("publish"), span("loop.publish_submit", version=self.version):
             self.publisher.submit(
                 self.flattener.flatten_on_device(self.state.params), self.version
             )
@@ -1224,6 +1331,8 @@ class Learner:
         cfg = self.cfg
         compute = self.obs.compute if self.obs is not None else None
         timer = compute.timer if compute is not None else None
+        flight = self._flight
+        flight.begin_run()
         # The lane's staging wait is cancellable at teardown via the
         # lane's stop event — a stopping lane must never sit out a full
         # batch timeout (nor overlap a successor lane's pops on a phased
@@ -1273,6 +1382,7 @@ class Learner:
                 if item is None:
                     break  # abort / deadline
                 take_s = time.perf_counter() - t_take0
+                flight.charge("take", take_s)
                 if item.kind == "error":
                     raise item.error
                 if item.kind == "exhausted":
@@ -1312,7 +1422,8 @@ class Learner:
                 if timer is not None:
                     # Loop-lane "fetch" = the EXPOSED wait for a
                     # prefetched batch: host time the lane failed to
-                    # hide — the device-idle-per-step upper bound.
+                    # hide (the device idles in it only where no step is
+                    # queued: `flight` counts that part).
                     timer.add("fetch", take_s)
                 batch_dev, env_steps, batch_trace = item.batch, item.env_steps, item.trace
                 t_pass = time.perf_counter()
@@ -1337,7 +1448,7 @@ class Learner:
                 if self.version % cfg.publish_every == 0 and self._primary:
                     self._submit_publish()
                 if self.checkpointer is not None and self.version % cfg.checkpoint_every == 0:
-                    with span("loop.checkpoint", version=self.version):
+                    with flight.during("checkpoint"), span("loop.checkpoint", version=self.version):
                         self.checkpoint()
 
                 if timer is not None:
@@ -1363,6 +1474,9 @@ class Learner:
                     win_wait = win_put = win_take = win_gap = 0.0
                     win_env_steps = win_steps = 0
                     t_win = now
+                    # The device has had nothing since the sync found it
+                    # done: the read and the window's bookkeeping.
+                    flight.charge("sync", time.perf_counter() - flight.t_known)
         finally:
             lane.stop()
             self._prefetch_lane = None
@@ -1381,14 +1495,21 @@ class Learner:
         win_gap: float,
     ) -> float:
         """One metrics window — the ONLY routine device sync in the loop
-        (jax.device_get of the step metrics). `win_wait`/`win_put` are
+        (the wait for the newest step's metrics, then their read).
+        `win_wait`/`win_put` are
         the lane's fetch wait and device_put, `win_take` the loop's
         exposed take-wait and `win_gap` its longest interval between two
         dispatches. Returns the seconds the sync blocked."""
         compute = self.obs.compute if self.obs is not None else None
         t_sync = time.perf_counter()
         with span("loop.sync", step=self.version):
-            scalars = {k: float(v) for k, v in jax.device_get(metrics).items()}
+            # The wait for the newest dispatched step, then the read of
+            # its few scalars: the device's time and the host's, apart.
+            with span("loop.sync_ready", step=self.version):
+                jax.block_until_ready(metrics)
+            self._flight.synced()
+            with span("loop.sync_get", step=self.version):
+                scalars = {k: float(v) for k, v in jax.device_get(metrics).items()}
         sync_s = time.perf_counter() - t_sync
         stats = self.staging.stats()
         dt = max(now - t_win, 1e-9)
@@ -1412,6 +1533,7 @@ class Learner:
             max(0.0, min(1.0, 1.0 - win_take / lane_s)) if lane_s > 0 else 1.0
         )
         scalars["loop_dispatch_gap_max_s"] = win_gap
+        scalars.update(self._flight.window_scalars())
         scalars["active_actors"] = stats["active_actors"]
         scalars["staleness_dropped"] = stats["dropped_stale"]
         scalars["staging_quarantined"] = stats["quarantined"]
