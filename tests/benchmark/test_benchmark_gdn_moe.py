@@ -356,7 +356,8 @@ def test_the_new_readers_and_their_entries(bench):
     for name in new:
         assert cells.load_reader(bench, name)(nothing) is None  # the parent's program, an untraced run
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-4:]] == list(new)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[names.index(new[0]):][:4] == list(new)  # one run, wherever later entries leave it
     assert all(entries[n]["workloads"] == [CELL] for n in new)
     assert all(entries[n]["moves"] == "env_steps_per_s" and entries[n]["layer"] == "device step" for n in new)
     assert all(entries[n]["source"] == "device_trace" for n in new)
@@ -364,7 +365,12 @@ def test_the_new_readers_and_their_entries(bench):
     assert set(new) <= reported
     assert {"loss.ms", "optimizer.ms", "optimizer.roofline_pct", "step.unscoped_pct", "step.mfu_pct",
             "device.longest_gap_ms"} <= reported
-    assert not {"moe.ms", "trunk.ms", "heads.ms", "attn_full.ms", "attn_latent.ms", "lstm.ms"} & reported
+    # the scopes this configuration shares with the other transformer cells, which its scope file declares
+    shared = {"moe.ms", "moe.roofline_pct", "moe.load_max_over_mean", "moe.local_pairs_pct",
+              "moe_shared.ms", "moe_shared.roofline_pct", "trunk.ms", "heads.ms"}
+    assert shared <= reported
+    assert {n.split(".")[0] for n in shared} <= set(cells.load_scopes(bench, CONFIG))
+    assert not {"attn_full.ms", "attn_latent.ms", "mlp.ms", "lstm.ms"} & reported
     # a scoped trace of this configuration's step reads through them
     trace = {"scope_self_s": {"attn_linear": 0.4, "attn_gated": 0.06, "": 0.01}, "step_count": 2, "step_busy_s": 0.52}
     run = dict(nothing, trace=trace, scope_costs={"attn_linear": {"flops": 197e12 * 0.05, "bytes": 0.0},

@@ -4,12 +4,9 @@ where the program has no such scalars (the parent commit) or the window
 holds fewer than two metrics windows, the ten entries, and the tiny cell's
 traced run on the CPU reporting every one of them.
 
-The readers are in `benchmark/metrics/`; their entries are `ENTRIES` below
-and not yet in `BENCHMARK.json`: a PR that changes the program may only
-append to `per_layer`, and `test_benchmark_gdn_moe.py` holds that list's
-last four to be the `attn_*` ones (PERF.md section 7). The tests that need
-the entries put them at the end of a temporary copy's list, as the
-`benchmark` PR that takes them will."""
+The readers are in `benchmark/metrics/`; their entries in `BENCHMARK.json`
+are `ENTRIES` below, one run appended to `per_layer` after the entries that
+were there, so that no accepted entry moved."""
 
 import json
 import os
@@ -18,7 +15,7 @@ import pytest
 
 from bench_tiny import ROOT, make_tiny_root  # noqa: F401  (puts the root on the path)
 
-from benchmark import cells, trace_reduce
+from benchmark import cells, harness, trace_reduce
 
 NEW = ("publish.ready_wait_ms", "publish.copy_ms", "publish.age_ms", "loop.sync_ready_ms",
        "loop.sync_get_ms", "loop.inflight_max", "loop.starved_pct", "loop.starved_ms",
@@ -130,7 +127,11 @@ def test_the_parts_add_up_to_what_the_old_readers_read(bench):
 
 def test_the_ten_entries(bench):
     """The table of ISSUE 41, each with a reader's file beside the accepted
-    ones; appended to the accepted list they are reported in every cell."""
+    ones; in `BENCHMARK.json` as one run, and reported in every cell."""
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[names.index(NEW[0]):][:10] == list(NEW)  # one run, wherever later entries leave it
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    assert [entries[n] for n in NEW] == ENTRIES
     assert [sorted(e) for e in ENTRIES] == [sorted(bench["per_layer"][0])] * 10  # just the keys an entry has
     assert [(e["name"], e["unit"], e["source"], e["layer"]) for e in ENTRIES] == [
         ("publish.ready_wait_ms", "ms", "program_span", "weight publish"),
@@ -146,10 +147,9 @@ def test_the_ten_entries(bench):
     accepted = {m["layer"] for m in bench["per_layer"]}
     assert {e["layer"] for e in ENTRIES} <= accepted  # layers the benchmark already names, letter for letter
     assert all(os.path.exists(os.path.join(ROOT, "benchmark", "metrics", e["name"] + ".py")) for e in ENTRIES)
-    with_them = dict(bench, per_layer=[m for m in bench["per_layer"] if m["name"] not in NEW] + ENTRIES)
-    assert len({m["name"] for m in with_them["per_layer"]}) == len(with_them["per_layer"])
+    assert len(set(names)) == len(names)
     for cell in bench["workloads"]:  # no `workloads` key: every cell reports env_steps_per_s
-        assert set(NEW) <= {m["name"] for m in cells.metrics_for(with_them, cell["name"], "per_layer")}
+        assert set(NEW) <= {m["name"] for m in cells.metrics_for(bench, cell["name"], "per_layer")}
 
 
 def test_tiny_cell_reports_each_new_metric_in_a_traced_run(tmp_path, monkeypatch):
@@ -163,13 +163,10 @@ def test_tiny_cell_reports_each_new_metric_in_a_traced_run(tmp_path, monkeypatch
         recorded = json.load(f)
     reduce = trace_reduce.reduce
     monkeypatch.setattr(trace_reduce, "reduce", lambda events, chips, scopes=None: reduce(recorded, chips=2))
-    root = make_tiny_root(str(tmp_path / "root"))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["per_layer"] = [m for m in bench["per_layer"] if m["name"] not in NEW] + ENTRIES
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f, indent=1)
-    result = drive(root, trace=True, seconds=2.0)
+    # a profile directory of this test's own: the harness empties its directory before and
+    # after a trace, and another file's traced run may be in another worker at the same time
+    monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path / "trace"))
+    result = drive(make_tiny_root(str(tmp_path / "root")), trace=True, seconds=2.0)
     assert result["correct"] is True
     for name in NEW:
         assert name in result["metrics"], name

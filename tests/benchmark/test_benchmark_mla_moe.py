@@ -346,13 +346,18 @@ def test_the_new_readers_and_their_entries(bench):
     for name in new:
         assert cells.load_reader(bench, name)(nothing) is None  # the parent's program, an untraced run
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert all(entries[n]["workloads"] == [CELL] for n in new)
+    assert all(entries[n]["workloads"] == [CELL] for n in new[:3])
+    assert all({CELL, "learner-qwen3next-ep32-wire"} <= set(entries[n]["workloads"]) for n in new[3:])
     assert all(entries[n]["moves"] == "env_steps_per_s" and entries[n]["layer"] == "device step" for n in new)
     reported = {m["name"] for m in cells.metrics_for(bench, CELL, "per_layer")}
     assert set(new) <= reported
     assert {"loss.ms", "optimizer.ms", "optimizer.roofline_pct", "step.unscoped_pct",
             "device.longest_gap_ms"} <= reported
-    assert not {"moe.ms", "trunk.ms", "heads.ms", "attn_full.ms", "lstm.ms"} & reported
+    # the scopes this configuration shares with the other transformer cells, which its scope file declares
+    shared = {"moe.ms", "moe.roofline_pct", "moe.load_max_over_mean", "moe.local_pairs_pct", "trunk.ms", "heads.ms"}
+    assert shared <= reported
+    assert {n.split(".")[0] for n in shared} <= set(cells.load_scopes(bench, CONFIG))
+    assert not {"attn_full.ms", "attn_linear.ms", "lstm.ms"} & reported
     # a scoped trace of this configuration's step reads through them
     trace = {"scope_self_s": {"attn_latent": 0.4, "mlp": 0.06, "moe_shared": 0.05, "": 0.01},
              "step_count": 2, "step_busy_s": 0.52}
@@ -362,3 +367,21 @@ def test_the_new_readers_and_their_entries(bench):
     assert read["attn_latent.ms"] == pytest.approx(200.0) and read["mlp.ms"] == pytest.approx(30.0)
     assert read["attn_latent.roofline_pct"] == pytest.approx(50.0)
     assert read["moe_shared.ms"] == pytest.approx(25.0) and read["moe_shared.roofline_pct"] == pytest.approx(20.0)
+
+
+def test_the_local_pairs_are_counted_over_the_layers_that_have_experts(bench, config):
+    """`moe.local_pairs_pct` on this configuration's file: the leading dense
+    layer routes nothing, so even counts (4 sparse layers x frames x top_k
+    x 8 held of 64) read 12.5, held over experts, and not 10.0; Mellum2's
+    file, which has no dense layer, reads what it always read."""
+    read = cells.load_reader(bench, "moe.local_pairs_pct")
+    pol = config["policy"]
+    frames_ = 4 * (config["learner"]["seq_len"] + 1)
+    even = frames_ * pol["moe_top_k"] * (pol["tf_layers"] - pol["tf_dense_layers"]) * 8 / 64
+    syncs = [(0.0, 10, {"moe_local_pairs": even}), (1.0, 20, {"moe_local_pairs": even})]
+    assert read({"syncs": syncs, "rows_per_step": 4, "config": config}) == pytest.approx(12.5)
+    mellum = cells.load_cell(bench, "learner-mellum2-ep4-wire")["config_data"]
+    assert "tf_dense_layers" not in mellum["policy"]
+    run = {"syncs": [(0.0, 10, {"moe_local_pairs": 131072.0}), (1.0, 20, {"moe_local_pairs": 131072.0 * 1.02})],
+           "rows_per_step": 4, "config": mellum}
+    assert read(run) == pytest.approx(100.0 * 131072.0 * 1.01 / (4 * 4096 * 8 * 4))  # 25.25, as before

@@ -275,6 +275,14 @@ def test_the_counters_readers(bench):
                                                "device": {"platform": "tpu", "kind": "TPU v5 lite"}}) is None
     entries = {m["name"]: m for m in bench["per_layer"]}
     new = [n for n in entries if n.split(".")[0] in ("attn_window", "attn_full", "moe")]
-    assert len(new) == 8 and all(entries[n]["workloads"] == [CELL] for n in new)
+    assert len(new) == 8 and all(CELL in entries[n]["workloads"] for n in new)
+    # attention of this configuration's kinds is its own; the expert layer is every transformer cell's
+    assert all(entries[n]["workloads"] == [CELL] for n in new if not n.startswith("moe."))
+    assert all({"learner-glm47flash-ep8-wire", "learner-qwen3next-ep32-wire"} <= set(entries[n]["workloads"])
+               for n in new if n.startswith("moe."))
     assert all(entries[n]["moves"] == "env_steps_per_s" and entries[n]["layer"] == "device step"
                for n in new)
+    # the family's own trunk and heads, which its scope file declares
+    reported = {m["name"] for m in cells.metrics_for(bench, CELL, "per_layer")}
+    assert {"trunk.ms", "heads.ms"} <= reported
+    assert {"trunk", "heads"} <= set(cells.load_scopes(bench, CONFIG))

@@ -82,6 +82,30 @@ def test_entries_have_just_the_keys_shown(bench):
         assert set(m.get("workloads", [])) <= cells_
 
 
+# An index or slice of a metric list or a `workloads` list counted from its
+# end: the last four of `per_layer`, the last cell of a `workloads`.
+FROM_THE_END = re.compile(r"""\[\s*["'](?:per_layer|end_to_end|workloads)["']\s*\]\s*\[[^\]\n]*-""")
+
+
+def test_no_source_finds_an_entry_by_its_place_from_the_end(bench):
+    """A later PR adds a metric or a cell by appending its entry, so a
+    check that finds entries at the list's end fails on the first append:
+    the benchmark's code and tests find an entry by its name."""
+    assert FROM_THE_END.search('[m["name"] for m in bench["per_layer"]' + "[-4:]]")
+    assert FROM_THE_END.search("entry['workloads']" + "[-1]")
+    assert FROM_THE_END.search('bench["end_to_end"]' + "[:-1]")
+    assert not FROM_THE_END.search('names[names.index(new[0]):][:4]')
+    assert not FROM_THE_END.search('bench["per_layer"][0]')
+    found = []
+    for d in bench["paths"]:
+        for base, _, files in os.walk(os.path.join(ROOT, d)):
+            for f in sorted(files):
+                if f.endswith(".py"):
+                    with open(os.path.join(base, f)) as fh:
+                        found += [f"{f}:{i}" for i, line_ in enumerate(fh, 1) if FROM_THE_END.search(line_)]
+    assert not found, found
+
+
 def test_mfu_beside_the_step(bench):
     assert any("mfu" in re.split(r"[._\-]", m["name"]) for m in bench["per_layer"])
 

@@ -10,7 +10,7 @@ import pytest
 
 from bench_tiny import ROOT, make_tiny_root  # noqa: F401  (puts the root on the path)
 
-from benchmark import cells, trace_reduce
+from benchmark import cells, harness, trace_reduce
 
 NEW = ("publish.d2h_ms", "publish.serialize_ms", "publish.send_ms", "publish.latency_ms",
        "loop.dispatch_gap_max_ms", "loop.sync_ms", "h2d.lane_blocked_pct", "staging.busy_pct",
@@ -144,6 +144,9 @@ def test_tiny_cell_reports_each_new_metric_in_a_traced_run(tmp_path, monkeypatch
         return reduce(recorded, chips=2)
 
     monkeypatch.setattr(trace_reduce, "reduce", reduce_recorded)
+    # a profile directory of this test's own: the harness empties its directory before and
+    # after a trace, and another file's traced run may be in another worker at the same time
+    monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path / "trace"))
     result = drive(make_tiny_root(str(tmp_path / "root")), trace=True, seconds=2.0)
     assert result["correct"] is True
     for name in NEW:
