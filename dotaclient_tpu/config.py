@@ -50,7 +50,7 @@ class PolicyConfig:
     # intermediates [N, block, keys of a block's range] instead of
     # [N, T, T]), which runs on the CPU, in the ulysses SP path (whose
     # per-head-group attention sees the full time axis) and on shapes the
-    # kernel refuses. On a TPU, with head widths of a multiple of 128 and
+    # kernel refuses. On a TPU, with heads of 64 or a multiple of 128 and
     # T a multiple of 128, the blocked case goes through the fused Pallas
     # kernel instead, which chooses its own tiles (ops/attention.py
     # fused_tiles; ops/ring_attention.py fused_applies has the rule).
@@ -77,10 +77,17 @@ class PolicyConfig:
     # sigmoid gate on the heads' output, a column block of `qkv` beside q's.
     # "linear": a gated-delta-rule layer (ops/gated_delta.py; tf_lin_* below),
     # whose cost is linear in the chunk and whose state is a matrix a head.
+    # "conv": a gated short-convolution layer in attention's place
+    # (transformer_policy._short_conv), whose state is its last two gated
+    # inputs.
     tf_layer_kinds: str = ""
     tf_window: int = 0  # keys a query of a sliding layer sees, itself included
     tf_rope_theta: float = 10000.0  # rotary base, both kinds
     tf_rotary_dim: int = 0  # lanes of a full/sliding/gated head that rotate, from lane 0; 0 = the whole head
+    # A norm of tf_norm's kind on each head's q and k of a full or sliding
+    # layer, before the rotation (one weight vector for all heads); the
+    # gated kind has it whatever this says.
+    tf_qk_norm: bool = False
     # YaRN on the full layers' rotary table (0 = the default table there
     # too): inverse frequencies blended between the default and default /
     # factor by the linear ramp over the correction range of (beta_fast,

@@ -19,8 +19,9 @@ train step, staging and wire format are all family-agnostic:
   every frame's keys and values; a latent layer every frame's latent and
   the one rotated key; a linear layer keeps nothing per frame, only its
   rule's state (a matrix a value head) and the last frames its
-  convolution still reads. Each kind's arrays have a slot for the layers
-  of that kind alone.
+  convolution still reads; a short-convolution layer only the last
+  gated inputs its filter still reads. Each kind's arrays have a slot for
+  the layers of that kind alone.
 - `unroll=True` (learner): teacher-forced causal attention over the
   whole [B, T, ...] chunk; the passed state is IGNORED — context is
   chunk-local by design, and the actor resets its cache at every chunk
@@ -68,7 +69,11 @@ class KVCache(NamedTuple):
     linear layers keep no frame: s [B, Ll, Hv, d, d] f32 is each value
     head's state of the gated delta rule and conv [B, Ll, K - 1, channels]
     the inputs of the last K - 1 frames that the convolution of q, k and v
-    still reads; both None in a model without linear layers."""
+    still reads; both None in a model without linear layers. The Ls
+    short-convolution layers keep no frame either: short [B, Ls, K - 1, D]
+    holds the gated inputs B * x of the last K - 1 frames that the filter
+    still reads (K = CONV_TAPS); None in a model without such layers.
+    k and v then cover the layers that attend alone, L - Ll - Ls."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -77,6 +82,7 @@ class KVCache(NamedTuple):
     rsum: jnp.ndarray  # [B, L, 2, E] f32: each layer's sums of `ops.moe.standardize` over the frames so far
     s: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
+    short: Optional[jnp.ndarray] = None
 
 
 def is_latent(cfg: PolicyConfig) -> bool:
@@ -133,10 +139,11 @@ def layer_kinds(cfg: PolicyConfig) -> Tuple[str, ...]:
     comma list, repeated."""
     period = [k.strip() for k in (cfg.tf_layer_kinds or "full").split(",")]
     for k in period:
-        if k not in ("full", "sliding", "latent", "gated", "linear"):
+        if k not in ("full", "sliding", "latent", "gated", "linear", "conv"):
             raise ValueError(
                 f"tf_layer_kinds: unknown kind {k!r} (full|sliding|gated: keys and values of every frame in "
-                f"the carry; latent: every frame's latent; linear: a state matrix a head and no frame)")
+                f"the carry; latent: every frame's latent; linear: a state matrix a head and no frame; conv: "
+                f"the last {CONV_TAPS - 1} gated inputs and no frame)")
         if k == "sliding" and cfg.tf_window <= 0:
             raise ValueError("a sliding layer needs tf_window > 0")
         if k == "linear":
@@ -158,15 +165,18 @@ def init_cache(cfg: PolicyConfig, batch_shape) -> KVCache:
     # (2x actor cache bytes); scores still accumulate in f32 inside
     # attention (ADVICE r3 item 3). pos/idx stay int32.
     dt = jnp.dtype(cfg.dtype)
-    n_linear = layer_kinds(cfg).count("linear")
-    k_shape = v_shape = (B, L - n_linear, C, G, Dh)
+    kinds = layer_kinds(cfg)
+    n_linear, n_conv = kinds.count("linear"), kinds.count("conv")
+    k_shape = v_shape = (B, L - n_linear - n_conv, C, G, Dh)
     if is_latent(cfg):
         k_shape, v_shape = (B, L, C, 1, cfg.tf_qk_rope_dim), (B, L, C, 1, cfg.tf_kv_lora_rank)
-    s = conv = None
+    s = conv = short = None
     if n_linear:
         Hk, Hv, d, K = linear_shape(cfg)
         s = jnp.zeros((B, n_linear, Hv, d, d), jnp.float32)
         conv = jnp.zeros((B, n_linear, K - 1, (2 * Hk + Hv) * d), dt)
+    if n_conv:
+        short = jnp.zeros((B, n_conv, CONV_TAPS - 1, cfg.lstm_hidden), dt)
     return KVCache(
         k=jnp.zeros(k_shape, dt),
         v=jnp.zeros(v_shape, dt),
@@ -175,6 +185,7 @@ def init_cache(cfg: PolicyConfig, batch_shape) -> KVCache:
         rsum=jnp.zeros((B, L, 2, cfg.moe_experts), jnp.float32),
         s=s,
         conv=conv,
+        short=short,
     )
 
 
@@ -333,7 +344,8 @@ def _attention(block: "Block", x, positions, cache):
     A gated layer is a full one with a norm of the config's kind on each
     head's q and k (one weight vector for all heads) and the heads'
     output times a sigmoid of a gate, N more heads of `qkv`'s columns
-    after v's, applied to the heads as attention wrote them."""
+    after v's, applied to the heads as attention wrote them. A full or
+    sliding layer has the same norms, and no gate, with cfg.tf_qk_norm."""
     cfg = block.cfg
     N, G, Dh = head_shape(cfg)
     dt = jnp.dtype(cfg.dtype)
@@ -345,7 +357,7 @@ def _attention(block: "Block", x, positions, cache):
         A.rope_table(rotary, cfg.tf_rope_theta, cfg.tf_yarn_factor, cfg.tf_yarn_original_context,
                      cfg.tf_yarn_beta_fast, cfg.tf_yarn_beta_slow))
     heads = _by_head(cfg, _norm(cfg, "ln1")(x), N + 2 * G + (N if gated else 0), Dh, "qkv")
-    normed = (lambda a, name: _norm(cfg, name)(a).astype(dt)) if gated else (lambda a, name: a)
+    normed = (lambda a, name: _norm(cfg, name)(a).astype(dt)) if gated or cfg.tf_qk_norm else (lambda a, name: a)
     # RoPE at this token's absolute position; cached K were rotated
     # at write time, so angles are consistent across modes. The
     # fused kernel wants the scores' 1/sqrt(Dh) in q: it goes into
@@ -487,16 +499,46 @@ def _latent_attention(block: "Block", x, positions, cache):
     return _from_heads(cfg, attn, "attn_out"), new_cache
 
 
+CONV_TAPS = 3  # a short-convolution layer's filter: this frame and the two before it
+
+
+def _short_conv(block: "Block", x, positions, cache):
+    """The short-convolution layer's part, in attention's place: what is
+    added to x, and the new (tail,) or None.
+
+        [B | C | x'] = n(x) W_in        three blocks of D columns, in this order
+        y_t = C_t * sum_j w_j * (B * x')_(t - (K-1) + j)     causal, depthwise, K taps, no bias
+        out = y W_out
+
+    B, C and x' each leave their product with their block of W_in's
+    columns (`_by_head`: a slice of weights, no split of a [.., 3D]
+    output); B * x' is rounded to the compute type once, as the product
+    wrote it, and the filter (`GD.causal_conv`) and C's gate are float32.
+    The step reads the last K - 1 gated inputs from the carry and hands on
+    the new ones; the unroll starts from zeros before the row."""
+    cfg = block.cfg
+    D, K = x.shape[-1], CONV_TAPS
+    dt = jnp.dtype(cfg.dtype)
+    part = _by_head(cfg, _norm(cfg, "ln1")(x), 3, D, "in_proj")
+    gate_in, gate_out, inner = (part(i, 1)[..., 0, :] for i in range(3))  # [B, T, D] each
+    filt, _ = Kernel((K, D), name="conv")()
+    out, tail = GD.causal_conv((gate_in * inner).astype(dt), filt, None if cache is None else cache[0])
+    y = gate_out.astype(jnp.float32) * out
+    return _dense(cfg, D, "out_proj")(y.astype(dt)), None if cache is None else (tail,)
+
+
 # The named scope of a layer kind's attention part ("full": attn_full).
-SCOPES = {"latent": "attn_latent", "sliding": "attn_window", "gated": "attn_gated", "linear": "attn_linear"}
+SCOPES = {"latent": "attn_latent", "sliding": "attn_window", "gated": "attn_gated", "linear": "attn_linear",
+          "conv": "short_conv"}
 
 
 class Block(nn.Module):
     """Pre-norm transformer block: norm → causal attention (+residual) →
     norm → feed-forward (+residual). The sizes are the config's: grouped
     key/value heads, a head width of its own, a full, a sliding or a gated
-    layer with that kind's rotary table, latent attention, or a linear
-    layer (the gated delta rule in attention's place); LayerNorm or
+    layer with that kind's rotary table, latent attention, a linear
+    layer (the gated delta rule in attention's place) or a short-convolution
+    layer (a gated causal filter of a few taps there); LayerNorm or
     RMSNorm; a dense MLP (GELU of 4x, or SwiGLU of a width of its own) or
     a routed-expert layer with or without a shared expert beside it.
     Matmuls in cfg.dtype (MXU); norms, softmax, router and the residual
@@ -526,20 +568,23 @@ class Block(nn.Module):
         masks by position, a latent layer's two cache arrays are the
         rotated shared key [B,C,1,rope] and the latent [B,C,1,rank], a
         linear layer's its rule's state [B,Hv,d,d] and its convolution's
-        tail [B,K-1,channels], which it replaces.
+        tail [B,K-1,channels], which it replaces; a short-convolution
+        layer's cache has its one array, the tail of gated inputs
+        [B,K-1,D], in place of the pair.
         Returns (x_out, new cache or None, the routed-expert layer's
         counts or None)."""
         cfg = self.cfg
         # Named scopes: the layer an operation belongs to, in its
         # `op_name`, the norm that feeds a part inside that part's scope.
-        attention = {"latent": _latent_attention, "linear": _linear_attention}.get(self.kind, _attention)
+        attention = {"latent": _latent_attention, "linear": _linear_attention, "conv": _short_conv}.get(
+            self.kind, _attention)
         with jax.named_scope(SCOPES.get(self.kind, "attn_full")):
             out, new_cache = attention(self, x, positions, cache)
             x = x + out.astype(jnp.float32)
 
         if self.sparse:
             with jax.named_scope("moe"):
-                seen = None if cache is None else (cache[4], positions[:, 0])
+                seen = None if cache is None else (cache[-1], positions[:, 0])
                 h = _norm(cfg, "ln2")(x)
                 y, counts, rsum = ExpertLayer(cfg, self.platform, name="moe")(h, seen)
             if cfg.moe_shared_hidden:
@@ -560,7 +605,7 @@ class Block(nn.Module):
                 h = _dense(cfg, cfg.lstm_hidden, "mlp_down")(h)
             else:
                 raise ValueError(f"unknown tf_mlp_act {cfg.tf_mlp_act!r} (gelu|swiglu)")
-            return x + h.astype(jnp.float32), new_cache and new_cache + (cache[4],), None
+            return x + h.astype(jnp.float32), new_cache and new_cache + (cache[-1],), None
 
 
 def ff_sparse(cfg: PolicyConfig) -> Tuple[bool, ...]:
@@ -627,7 +672,7 @@ class TransformerCore(nn.Module):
             # Kept by name, so that each runs forward once a step: the
             # fused kernel's output and log-sum-exp, and a linear layer's
             # solved systems, chunk-entering states, u and o (GD.chunked).
-            n_linear = kinds.count("linear")
+            n_linear, n_conv = kinds.count("linear"), kinds.count("conv")
             names = [A.FUSED_RESIDUALS] * bool(fused) + [GD.RULE_RESIDUALS] * bool(n_linear)
             keep = jax.checkpoint_policies.save_only_these_names(*names) if names else None
             block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
@@ -637,7 +682,8 @@ class TransformerCore(nn.Module):
             for i, kind in enumerate(kinds):
                 h, _, n = block_cls(cfg, kind, self.sp_mesh, platform, fused, sparse[i], name=f"block{i}")(h, positions)
                 counts.append(n)
-            stats = {"attn_fused_layers": jnp.float32(len(kinds) - n_linear if fused else 0), **_moe_stats(counts)}
+            attending = len(kinds) - n_linear - n_conv
+            stats = {"attn_fused_layers": jnp.float32(attending if fused else 0), **_moe_stats(counts)}
             return carry, final(h), stats
 
         assert isinstance(carry, KVCache), "transformer step mode needs a KVCache carry"
@@ -653,23 +699,24 @@ class TransformerCore(nn.Module):
         new_pos = jnp.where(onehot > 0, positions, carry.pos).astype(jnp.int32)
 
         h = x.astype(jnp.float32)[:, None, :]  # [B, 1, D]
-        # each kind's pair of arrays has a slot for the layers of that kind, in layer order
-        old = {"linear": (carry.s, carry.conv), "attend": (carry.k, carry.v)}
-        new = {"linear": [], "attend": []}
+        # each kind's arrays have a slot for the layers of that kind, in layer order
+        old = {"linear": (carry.s, carry.conv), "conv": (carry.short,), "attend": (carry.k, carry.v)}
+        new = {group: [] for group in old}
         rs = []
         for i, kind in enumerate(kinds):
-            mine = "linear" if kind == "linear" else "attend"
-            a, b = (x[:, len(new[mine])] for x in old[mine])
-            h, (a, b, r), _ = Block(cfg, kind, platform=platform, sparse=sparse[i], name=f"block{i}")(
-                h, positions, cache=(a, b, new_pos, onehot, carry.rsum[:, i]))
-            new[mine].append((a, b))
+            mine = kind if kind in ("linear", "conv") else "attend"
+            state = tuple(x[:, len(new[mine])] for x in old[mine])
+            h, (*state, r), _ = Block(cfg, kind, platform=platform, sparse=sparse[i], name=f"block{i}")(
+                h, positions, cache=(*state, new_pos, onehot, carry.rsum[:, i]))
+            new[mine].append(state)
             rs.append(r)
 
-        def stacked(kind):  # a kind with no layer keeps what it came with (empty arrays, or None)
-            return tuple(jnp.stack(x, axis=1) for x in zip(*new[kind])) if new[kind] else old[kind]
+        def stacked(group):  # a kind with no layer keeps what it came with (empty arrays, or None)
+            return tuple(jnp.stack(x, axis=1) for x in zip(*new[group])) if new[group] else old[group]
 
-        (k, v), (s, conv) = stacked("attend"), stacked("linear")
-        new_carry = KVCache(k=k, v=v, pos=new_pos, idx=carry.idx + 1, rsum=jnp.stack(rs, axis=1), s=s, conv=conv)
+        (k, v), (s, conv), (short,) = stacked("attend"), stacked("linear"), stacked("conv")
+        new_carry = KVCache(k=k, v=v, pos=new_pos, idx=carry.idx + 1, rsum=jnp.stack(rs, axis=1), s=s, conv=conv,
+                            short=short)
         return new_carry, final(h)[:, 0, :], None
 
 

@@ -359,8 +359,13 @@ def fused_tiles(T: int, window: int = 0, head_dim: int = 128) -> Tuple[int, int]
 def fused_takes(T: int, N: int, G: int, Dh: int) -> bool:
     """Whether `fused_causal_attention` takes a chunk of T frames with N
     query heads on G key/value heads of width Dh: the head width fills
-    the lanes (a multiple of 128), T divides by a tile, N by G."""
-    return Dh % 128 == 0 and N % G == 0 and fused_tiles(T)[0] > 0
+    the lanes (a multiple of 128) or is 64 (a head's block is then the
+    whole of its last axis, which the TPU's compiler takes; its scratch
+    is sized in whole lanes), T divides by a tile, N by G. Heads of 64
+    match the dense masked softmax in Pallas' interpreter
+    (tests/test_attention.py) and compile for a v5e at 8 rows of 4,096
+    frames, 32 query heads on 8 (tests/test_tpu_compile.py)."""
+    return (Dh % 128 == 0 or Dh == 64) and N % G == 0 and fused_tiles(T)[0] > 0
 
 
 def fused_causal_attention(

@@ -55,8 +55,8 @@ def policy_forward_flops_per_frame(cfg: PolicyConfig, chunk_frames: int = 0) -> 
 
     # temporal core
     if cfg.arch == "transformer":
-        from dotaclient_tpu.models.transformer_policy import (ff_sparse, head_shape, is_latent, latent_shape,
-                                                              layer_kinds, linear_shape)
+        from dotaclient_tpu.models.transformer_policy import (CONV_TAPS, ff_sparse, head_shape, is_latent,
+                                                              latent_shape, layer_kinds, linear_shape)
 
         N, G, Dh = head_shape(cfg)
         frames = chunk_frames or cfg.tf_context
@@ -83,6 +83,10 @@ def policy_forward_flops_per_frame(cfg: PolicyConfig, chunk_frames: int = 0) -> 
                 # k u^T and S^T q a value head and frame (the convolution multiplies no matrices)
                 Hk, Hv, d, _ = linear_shape(cfg)
                 fl += 2.0 * H * (2 * Hk + 2 * Hv) * d + 2.0 * H * 2 * Hv + 2.0 * Hv * d * H + 3 * 2.0 * d * d * Hv
+                continue
+            if kind == "conv":
+                # in (B, C, x), out, and the depthwise filter's taps (the two gates are elementwise)
+                fl += 2.0 * H * 3 * H + 2.0 * H * H + 2.0 * CONV_TAPS * H
                 continue
             pairs = attended_pairs(frames, cfg.tf_window if kind == "sliding" else 0)
             gate = 2.0 * H * N * Dh if kind == "gated" else 0.0  # the output gate's columns of qkv

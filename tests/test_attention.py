@@ -583,11 +583,13 @@ class TestFusedKernel:
         assert A.fused_tiles(1536) == (512, 512) and A.fused_tiles(384) == (128, 128)
         assert A.fused_tiles(4096, 200) == (128, 128)  # no tile far wider than the window
         assert A.fused_tiles(4095) == (0, 0) and not A.fused_takes(4095, 32, 4, 128)
-        assert A.fused_takes(4096, 32, 4, 128) and not A.fused_takes(4096, 32, 4, 64)
-        assert not A.fused_takes(4096, 32, 5, 128)
+        # heads of 64 are taken, at the tiles of 128 (the scratch is sized in whole lanes); 96 is not
+        assert A.fused_takes(4096, 32, 4, 128) and A.fused_takes(4096, 32, 4, 64) and A.fused_takes(4096, 32, 8, 64)
+        assert A.fused_tiles(4096, 0, 64) == A.fused_tiles(4096, 0, 128) == (1024, 512)
+        assert not A.fused_takes(4096, 32, 4, 96) and not A.fused_takes(4096, 32, 5, 128)
         with pytest.raises(ValueError, match="fused kernel does not take"):
-            A.fused_causal_attention(jnp.zeros((1, 256, 2, 64)), jnp.zeros((1, 256, 1, 64)),
-                                     jnp.zeros((1, 256, 1, 64)), interpret=True)
+            A.fused_causal_attention(jnp.zeros((1, 256, 2, 96)), jnp.zeros((1, 256, 1, 96)),
+                                     jnp.zeros((1, 256, 1, 96)), interpret=True)
 
     @pytest.mark.parametrize("keep", [False, True], ids=["remat_all", "residuals_kept"])
     def test_the_forward_kernel_runs_once_where_its_residuals_are_kept(self, keep):
@@ -610,6 +612,14 @@ class TestFusedKernel:
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
+class TestFusedKernelHeadsOf64(TestFusedKernel):
+    """The same against heads of 64, the width of a head of the
+    short-convolution cell's attention layer: the kernel's blocks are
+    then half a lane register wide, and it has to agree all the same."""
+
+    Dh = 64
+
+
 # The transformer cell's shapes: 4 rows of 4,096 frames, 32 query heads on 4 of 128, blocks of 256.
 _CELL_Q, _CELL_K = (4, 4096, 32, 128), (4, 4096, 4, 128)
 
@@ -620,14 +630,16 @@ _CELL_Q, _CELL_K = (4, 4096, 32, 128), (4, 4096, 4, 128)
     ("cpu", _CELL_Q, _CELL_K, 256, "", False),
     ("gpu", _CELL_Q, _CELL_K, 256, "", False),
     ("tpu", (4, 1, 32, 128), _CELL_K, 256, "", False),  # the actor's step over its cache
-    ("tpu", (4, 4096, 32, 64), (4, 4096, 4, 64), 256, "", False),  # narrow heads
+    ("tpu", (4, 4096, 32, 64), (4, 4096, 8, 64), 256, "", True),  # heads of 64 fill half the lanes
+    ("tpu", (4, 4096, 32, 96), (4, 4096, 4, 96), 256, "", False),  # narrow heads of another width
     ("tpu", (4, 4095, 32, 128), (4, 4095, 4, 128), 256, "", False),  # a ragged chunk
     ("tpu", _CELL_Q, _CELL_K, 0, "", False),  # blocked attention is off
     ("tpu", (4, 256, 32, 128), (4, 256, 4, 128), 256, "", False),  # one block: the dense path
     ("tpu", _CELL_Q, _CELL_K, 256, "dp=1,sp=2", False),  # the time axis is sharded
     ("tpu", _CELL_Q, _CELL_K, 256, "dp=2,tp=2", False),  # heads may be sharded: not partitioned
     ("tpu", (6, 4096, 32, 128), (6, 4096, 4, 128), 256, "dp=4", False),  # rows do not divide
-], ids=["cell", "cell_dp4", "cpu", "gpu", "step_with_cache", "head_width_64", "ragged_T", "block_off",
+], ids=["cell", "cell_dp4", "cpu", "gpu", "step_with_cache", "head_width_64", "head_width_96", "ragged_T",
+        "block_off",
         "one_block", "sp_mesh", "tp_mesh", "rows_not_by_dp"])
 def test_where_the_fused_kernel_is_taken(platform, q, k, kv_block, mesh, takes):
     n = int(np.prod([int(a.split("=")[1]) for a in mesh.split(",")])) if mesh else 0

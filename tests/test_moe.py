@@ -227,13 +227,14 @@ def test_standardised_scores_unroll_step_and_equations():
     np.testing.assert_array_equal(np.asarray(again)[:, :5], np.asarray(got)[:, :5])
 
 
-def _sigmoid_written(scores, bias, top_k, scale):
+def _sigmoid_written(scores, bias, top_k, scale, eps=1e-20):
     """The sigmoid router as its equations read, in float64: s = sigmoid,
-    the top_k largest of s + b, w = scale * s_chosen / (sum + 1e-20)."""
+    the top_k largest of s + b, w = scale * s_chosen / (sum + eps), eps
+    1e-20 unless given."""
     s = 1.0 / (1.0 + np.exp(-np.asarray(scores, np.float64)))
     chosen = np.argsort(-(s + np.asarray(bias, np.float64)), axis=-1)[:, :top_k]
     picked = np.take_along_axis(s, chosen, -1)
-    return chosen, scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen, scale * picked / (picked.sum(-1, keepdims=True) + eps)
 
 
 def _by_expert(experts, weights, n):
@@ -304,3 +305,27 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
     assert sum(int(p[1].sum()) for p in parts) == F * K
     # eight times the shared expert, one from each share, is not the layer
     assert np.abs(np.asarray(8 * swiglu(x, sg, su, sd) + sum(p[0] for p in parts) - whole)).max() > 0.1
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer_with_no_shared_expert():
+    """LFM2-MoE's sparse layer, uncut: sum over the chosen e of w_e E_e(h)
+    with the sigmoid router and its bias, the chosen sigmoids over their
+    sum + eps, times 1.0, and no shared expert. Cut into eight shares of
+    one expert each, the shares' routed parts add up to the uncut layer
+    and every pair is computed by one share. The published model code's
+    eps of 1e-6 in `route`'s 1e-20's place moves no weight by 1e-6 of
+    itself, far below bfloat16's rounding."""
+    x, wr, wg, wu, wd = _weights(9)
+    bias = jnp.asarray(np.random.RandomState(9).randn(E) * 0.2, jnp.float32)
+    swiglu = lambda h, g, u, d: (jax.nn.silu(h @ g) * (h @ u)) @ d
+    scores = moe.router_scores(x, wr)
+    chosen, w = _sigmoid_written(scores, bias, K, 1.0)
+    w_all = jnp.asarray(_by_expert(chosen, w, E), jnp.float32)
+    whole = sum(w_all[:, e:e + 1] * swiglu(x, wg[e], wu[e], wd[e]) for e in range(E))
+    routing = moe.route(scores, K, "sigmoid", bias, 1.0)
+    np.testing.assert_allclose(_by_expert(routing.experts, routing.weights, E), _by_expert(chosen, w, E), rtol=1e-5)
+    parts = [moe.expert_layer(x, routing, wg[e:e + 1], wu[e:e + 1], wd[e:e + 1], e) for e in range(E)]
+    np.testing.assert_allclose(sum(p[0] for p in parts), whole, rtol=1e-4, atol=1e-5)
+    assert sum(int(p[1].sum()) for p in parts) == F * K
+    published = _sigmoid_written(scores, bias, K, 1.0, 1e-6)[1]
+    assert 0 < np.abs(published / w - 1).max() < 1e-6

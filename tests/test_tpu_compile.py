@@ -409,6 +409,68 @@ def test_the_latent_cells_step_compiles_and_fits(topo):
     assert m.temp_size_in_bytes + m.argument_size_in_bytes + 2 * params < HBM_BYTES
 
 
+def test_the_short_convolution_cells_step_compiles_and_fits(topo):
+    """The whole step of `learner-lfm2-ep8-wire` from its configuration's
+    file (8 rows of 4,096 frames, one attention layer and three
+    short-convolution layers, 364.6M parameters) for one described chip,
+    as the benchmark's harness builds it: the attention layer's heads of
+    64 through the fused kernel under `attn_full`, the four layers'
+    grouped products through theirs, no kernel under `short_conv`, and
+    arguments, scratch and the two flat buffers of a weight publish
+    together inside the chip's memory. The compiler's count that chose 8
+    rows: scratch 5.25 GB at 8 rows (3.31 at 4), arguments 4.40
+    GB, 12.56 GB in all with the two 1.46 GB buffers, 73% of the chip.
+    About 60 s."""
+    from benchmark import cells, harness
+
+    cell = cells.load_cell(cells.load_benchmark(), "learner-lfm2-ep8-wire")
+    cfg = harness.learner_config(cell, seed=0, broker_url="mem://x")
+    assert (cfg.batch_size, cfg.seq_len, cfg.policy.tf_layers, cfg.policy.tf_head_dim) == (8, 4095, 4, 64)
+    compiled = _compile_train_step(cfg, topo.devices[:1])
+    text = compiled.as_text()
+    kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
+    assert sum("/attn_full/" in n and "splash_mha_fwd" in n for n in kernels) >= 1
+    assert sum("/attn_full/" in n and "splash_mha_dkv" in n for n in kernels) >= 1
+    assert sum("/moe/" in n for n in kernels) >= 4 * 12 and not [n for n in kernels if "/short_conv/" in n]
+    m = compiled.memory_analysis()
+    params = 4 * 364_556_087
+    assert m.argument_size_in_bytes > 3 * params  # the parameters and Adam's two moments
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes + 2 * params < 0.8 * HBM_BYTES
+
+
+def test_the_short_convolution_block_writes_no_array_of_all_three_parts(topo):
+    """One short-convolution block of `learner-lfm2-ep8-wire` at its shapes
+    (8 rows of 4,096 frames; the feed-forward part a SwiGLU of 256, so that
+    it compiles in seconds), forward and backward under `nn.remat`, for one
+    described chip: B, C and x leave their products by W_in's column blocks
+    and their cotangents go back the same way, so no array of [8, 4096,
+    6144] (the three side by side) is written, forward or backward, and
+    every operation of the mixer carries `short_conv`."""
+    from flax import linen as nn
+
+    from benchmark import cells, harness
+    from dotaclient_tpu.models.transformer_policy import Block
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cell = cells.load_cell(cells.load_benchmark(), "learner-lfm2-ep8-wire")
+    policy = harness.learner_config(cell, seed=0, broker_url="mem://x").policy
+    cfg = dataclasses.replace(policy, moe_experts=0, moe_experts_held=0, tf_mlp_act="swiglu", tf_mlp_hidden=256)
+    B, T, D = 8, 4096, cfg.lstm_hidden
+    block = nn.remat(Block)(cfg, "conv", None, "tpu", False, False)
+    positions = lambda: jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    x = jax.ShapeDtypeStruct((B, T, D), jnp.float32)
+    params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), positions()))
+
+    def loss(params, x):
+        return jnp.sum(block.apply(params, x, positions())[0] ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*_on(one, (params, x))).compile()
+    text = compiled.as_text()
+    wide = [ln.strip()[:200] for ln in text.splitlines() if re.search(r"\[8,4096,6144\]", ln)]
+    assert not wide, wide
+    assert "/short_conv/" in text and _fits(compiled)
+
+
 def _loops(text: str) -> list:
     """The `op_name` of every `while` operation of a compiled program."""
     return [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in text.splitlines()
