@@ -670,10 +670,13 @@ class TransformerCore(nn.Module):
             # backward instead of storing them (jax.checkpoint) —
             # O(T·D) residuals per block instead of every intermediate.
             # Kept by name, so that each runs forward once a step: the
-            # fused kernel's output and log-sum-exp, and a linear layer's
-            # solved systems, chunk-entering states, u and o (GD.chunked).
+            # fused kernel's output and log-sum-exp, a linear layer's
+            # solved systems, chunk-entering states, u and o (GD.chunked),
+            # and an expert layer's scores, routing and sort and its first
+            # window's gathered rows and gate and up products (moe._windowed).
             n_linear, n_conv = kinds.count("linear"), kinds.count("conv")
             names = [A.FUSED_RESIDUALS] * bool(fused) + [GD.RULE_RESIDUALS] * bool(n_linear)
+            names += [moe.PASS_RESIDUALS] * any(sparse)
             keep = jax.checkpoint_policies.save_only_these_names(*names) if names else None
             block_cls = nn.remat(Block, policy=keep) if cfg.tf_remat else Block
             if n_linear and self.sp_mesh is not None and cfg.tf_sp_axis in self.sp_mesh.axis_names:

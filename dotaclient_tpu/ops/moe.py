@@ -23,16 +23,26 @@ collecting reads that buffer too (forward, and the frames' and weights'
 cotangents backward): a scatter-add of each of its rows into its frame,
 where a gather of each frame's slots would read all frames x top_k (on a
 TPU both cost by the row, not the byte). How many pairs are held is
-known on the device alone, so the device chooses: the one pass where
-they fit the buffer, and where a routing skewed a loop over the windows
-of the sorted pairs that hold a held one, as many turns as it takes, so
-no pair is ever dropped. Choice and loop stand in the forward and again
-in the backward rule of one `custom_vjp`, which keeps nothing but its
-inputs and the sort, so no pass's intermediates outlive it. Within a
-pass a row past the held pairs is zeroed, or sent out of bounds,
-wherever it would be read. Where the buffer would hold every pair anyway
-(every expert held, the actor's step over a batch) there is neither
-choice nor loop, and the frames collect by their slots.
+known on the device alone, so the device counts the passes: the one pass
+over the first window of the sorted pairs, where the held pairs fit the
+buffer, and where a routing skewed a loop over the further windows that
+hold a held one, as many turns as it takes, so no pair is ever dropped.
+Pass and loop stand in the forward and again in the backward rule of one
+`custom_vjp`. It keeps the first window's gathered rows and its gate and
+up products under the checkpoint name PASS_RESIDUALS, and the backward
+rule transposes that window from them, computing again the window's
+indices from the sort, and the activation and the last product (the
+weights' cotangent reads its rows: a buffer's rows that the chip would
+otherwise hold through the whole backward pass); the loop's windows it
+computes again, from its inputs and the sort, so their intermediates
+outlive no turn. The scores, the routing and the sort carry the same
+name: a `jax.checkpoint` whose policy saves it (the transformer block's
+remat) runs the layer forward once. Within a pass a row past the held
+pairs is zeroed, or sent out of bounds, wherever it would be read. Where
+the buffer would hold every pair anyway (every expert held, the actor's
+step over a batch) there is no loop, no rule of the layer's own and no
+name on the pass, which such a remat computes again, and the frames
+collect by their slots.
 
 Router product, softmax (or sigmoid) and top-k are float32 at the
 highest matmul precision whatever the compute type: which experts a
@@ -51,7 +61,11 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
+
+
+PASS_RESIDUALS = "moe_pass_residuals"  # the checkpoint name of what the layer's backward pass reads
 
 
 class Routing(NamedTuple):
@@ -62,9 +76,9 @@ class Routing(NamedTuple):
 def router_scores(x: jnp.ndarray, w_router: jnp.ndarray) -> jnp.ndarray:
     """x [.., D] . w_router [D, E] in float32 at the highest matmul
     precision, whatever the compute type."""
-    return jnp.matmul(
+    return checkpoint_name(jnp.matmul(
         x.astype(jnp.float32), w_router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
-    )
+    ), PASS_RESIDUALS)
 
 
 ROUTER_EPS = 1e-6  # under the root that a standardised score is divided by
@@ -87,7 +101,7 @@ def standardize(scores: jnp.ndarray, seen, n: jnp.ndarray, axis=None):
         total = seen[0] + scores
     diff = scores - total / n
     squares = jnp.cumsum(diff * diff, axis=axis) if axis is not None else seen[1] + diff * diff
-    return diff * jax.lax.rsqrt(squares / n + ROUTER_EPS), (total, squares)
+    return checkpoint_name(diff * jax.lax.rsqrt(squares / n + ROUTER_EPS), PASS_RESIDUALS), (total, squares)
 
 
 SIGMOID_EPS = 1e-20  # beside the sum that the chosen sigmoid scores are renormalised by
@@ -109,14 +123,16 @@ def route(scores: jnp.ndarray, top_k: int, score: str = "softmax",
         probs = jax.nn.sigmoid(scores.astype(jnp.float32))
         ranked = probs + bias.astype(jnp.float32)
     _, experts = jax.lax.top_k(jax.lax.stop_gradient(ranked), top_k)
+    experts = checkpoint_name(experts.astype(jnp.int32), PASS_RESIDUALS)  # before `chosen` reads it
     # The chosen probabilities by a one-hot sum: its transpose is dense,
     # where top_k's own is a scatter of every pair.
     chosen = experts[..., None] == jnp.arange(probs.shape[-1], dtype=experts.dtype)
     weights = jnp.sum(jnp.where(chosen, probs[..., None, :], 0.0), axis=-1)
     if score == "softmax":
-        return Routing(experts.astype(jnp.int32), weights / jnp.sum(weights, axis=-1, keepdims=True))
-    total = jnp.sum(weights, axis=-1, keepdims=True) + SIGMOID_EPS
-    return Routing(experts.astype(jnp.int32), weights / total * scale)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    else:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + SIGMOID_EPS) * scale
+    return Routing(experts, checkpoint_name(weights, PASS_RESIDUALS))
 
 
 def _rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -258,68 +274,105 @@ def buffer_rows(pairs: int, held: int, experts: int) -> int:
     return min(pairs, math.ceil(pairs * held * HEADROOM / (experts * tile)) * tile)
 
 
-def _pass(rows: int, impl: str, x, weights, w_gate, w_up, w_down, order, inverse, sizes, n_here, start=0):
-    """The layer's part from `rows` of the sorted pairs, from `start` on:
-    all of it where the held pairs end before `start + rows`. `order`
-    reaches that far. In a buffer of fewer rows than the pairs each frame
-    collects its part from them (a scatter-add of each row into its
-    frame), in the buffer of every pair by its frames x top_k slots."""
-    top_k = weights.shape[1]
+def _window(rows: int, weights, order, inverse, sizes, n_here, start):
+    """Of `rows` of the sorted pairs from `start` on (`order` reaches that
+    far): each row's pair, the held experts' pairs among them, which rows
+    hold a held pair, and where each frame collects its part. In a buffer
+    of fewer rows than the pairs each frame collects from the rows (a
+    scatter-add of each row into its frame), in the buffer of every pair
+    by its frames x top_k slots."""
     pair = jax.lax.dynamic_slice_in_dim(order, start, rows)
     ends = jnp.cumsum(sizes)
     window = lambda at: jnp.clip(at, start, start + rows)
     sizes = window(ends) - window(ends - sizes)  # of each held expert's pairs, those in the window
     here = (jnp.arange(rows, dtype=jnp.int32) < n_here - start)[:, None]
     if rows < weights.size:  # row i to its pair's frame, a row past the held pairs out of bounds
-        slot, taken = jnp.where(here[:, 0], pair // top_k, weights.shape[0]), None
+        slot, taken = jnp.where(here[:, 0], pair // weights.shape[1], weights.shape[0]), None
     else:  # each frame's choices where the sort put them
         slot, taken = inverse.reshape(weights.shape), (inverse < n_here).reshape(weights.shape)
-
-    # A row past the held pairs belongs to no group and the products leave
-    # it unwritten, so where such a row is read it is zeroed first: after
-    # the two products that feed arithmetic. The last product's rows go to
-    # `_combine`, which reads the held ones alone, as `_spread`'s backward
-    # pass does of the first two products' cotangent.
-    dot = lambda a, w: jnp.where(here, grouped_dot(a, w, sizes, impl), 0)
-    a = _spread(x, pair // top_k, slot, taken)  # each frame's row once per held choice, sorted
-    out = grouped_dot(jax.nn.silu(dot(a, w_gate)) * dot(a, w_up), w_down, sizes, impl)
-    return _combine(out, weights, pair, slot, taken)
+    return pair, sizes, here, slot, taken
 
 
-def _passes(rows: int, n_here, one, zero):
-    """The sum of `one(start)` over the windows of `rows` sorted pairs that
-    hold a held pair, chosen on the device: the one pass bare where the
-    held pairs fit the buffer, and else a loop that adds up its turns from
-    `zero`."""
+def _first_two(impl: str, x, weights, w_gate, w_up, pair, sizes, here, slot, taken):
+    """Each frame's row once per held choice, sorted, and its gate and up
+    products."""
+    a = _spread(x, pair // weights.shape[1], slot, taken)
+    return a, grouped_dot(a, w_gate, sizes, impl), grouped_dot(a, w_up, sizes, impl)
+
+
+def _last(impl: str, gate, up, weights, w_down, pair, sizes, here, slot, taken):
+    """A pass from its first two products on: the activation, the last
+    product, and each frame collecting its part. A row past the held pairs
+    belongs to no group and the products leave it unwritten, so where such
+    a row is read it is zeroed first: after the two products that feed
+    arithmetic. The last product's rows go to `_combine`, which reads the
+    held ones alone, as `_spread`'s backward pass does of the first two
+    products' cotangent."""
+    act = jax.nn.silu(jnp.where(here, gate, 0)) * jnp.where(here, up, 0)
+    return _combine(grouped_dot(act, w_down, sizes, impl), weights, pair, slot, taken)
+
+
+def _pass(rows: int, impl: str, x, weights, w_gate, w_up, w_down, order, inverse, sizes, n_here, start=0):
+    """The layer's part from `rows` of the sorted pairs, from `start` on:
+    all of it where the held pairs end before `start + rows`."""
+    window = _window(rows, weights, order, inverse, sizes, n_here, start)
+    _, gate, up = _first_two(impl, x, weights, w_gate, w_up, *window)
+    return _last(impl, gate, up, weights, w_down, *window)
+
+
+def _loop(rows: int, n_here, one, first):
+    """`first`, the part of the first window of `rows` sorted pairs, and
+    `one(start)` of each further window that holds a held pair, summed in
+    window order: a loop on the device that takes no turn where the held
+    pairs fit the buffer, and as many as they reach into where a routing
+    skewed."""
     turn = lambda c: (c[0] + rows, jax.tree.map(jnp.add, c[1], one(c[0])))
-    loop = lambda: jax.lax.while_loop(lambda c: c[0] < n_here, turn, (jnp.int32(0), zero))[1]
-    return jax.lax.cond(n_here <= rows, lambda: one(0), loop)
+    return jax.lax.while_loop(lambda c: c[0] < n_here, turn, (jnp.int32(rows), first))[1]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _windowed(rows: int, impl: str, *args):
     """`_pass` in a buffer of `rows` rows, over as many windows of the
     sorted pairs as the held ones reach into. One differentiation rule
-    around the choice and the loop: its backward rule has the inputs and
-    the sort, walks the same windows and differentiates each pass where it
-    runs, so nothing differentiates through `cond` or `while_loop` and no
-    pass's intermediates outlive it."""
-    x, weights, *_, n_here = args
-    return _passes(rows, n_here, lambda start: _pass(rows, impl, *args, start),
-                   jnp.zeros((weights.shape[0], x.shape[1]), jnp.float32))
+    around the loop, so nothing differentiates through `while_loop`: its
+    forward keeps the first window's gathered rows and its gate and up
+    products, under the checkpoint name PASS_RESIDUALS, and its backward
+    transposes that window from them and walks the further ones again,
+    differentiating each pass where it runs."""
+    one = lambda start: _pass(rows, impl, *args, start)
+    return _loop(rows, args[-1], one, one(0))
 
 
-def _windowed_bwd(rows, impl, args, dy):
-    floats, sort = args[:5], args[5:]
+def _windowed_fwd(rows, impl, *args):
+    x, weights, w_gate, w_up, w_down, *sort = args
+    window = _window(rows, weights, *sort, 0)
+    kept = checkpoint_name(_first_two(impl, x, weights, w_gate, w_up, *window), PASS_RESIDUALS)
+    first = _last(impl, *kept[1:], weights, w_down, *window)
+    return _loop(rows, sort[-1], lambda start: _pass(rows, impl, *args, start), first), (args, kept)
+
+
+def _windowed_bwd(rows, impl, res, dy):
+    args, (a, gate, up) = res
+    (x, weights, w_gate, w_up, w_down), sort = args[:5], args[5:]
+    window = _window(rows, weights, *sort, 0)
+    # The activation and the last product are computed again: the
+    # weights' cotangent reads the last product's rows, which kept would
+    # be one more buffer of the model's width a layer, held through the
+    # whole backward pass.
+    d_gate, d_up, d_weights, d_down = jax.vjp(lambda *v: _last(impl, *v, *window), gate, up, weights, w_down)[1](dy)
+    products = lambda a, w_gate, w_up: (grouped_dot(a, w_gate, window[1], impl), grouped_dot(a, w_up, window[1], impl))
+    d_a, d_w_gate, d_w_up = jax.vjp(products, a, w_gate, w_up)[1]((d_gate, d_up))
+    d_x, = jax.vjp(lambda x: _spread(x, window[0] // weights.shape[1], *window[3:]), x)[1](d_a)
 
     def grads(start):
-        _, vjp = jax.vjp(lambda *floats: _pass(rows, impl, *floats, *sort, start), *floats)
+        _, vjp = jax.vjp(lambda *floats: _pass(rows, impl, *floats, *sort, start), *args[:5])
         return vjp(dy)
 
-    return _passes(rows, sort[-1], grads, tuple(map(jnp.zeros_like, floats))) + (None,) * len(sort)
+    first = (d_x, d_weights, d_w_gate, d_w_up, d_down)
+    return _loop(rows, sort[-1], grads, first) + (None,) * len(sort)
 
 
-_windowed.defvjp(lambda rows, impl, *args: (_windowed(rows, impl, *args), args), _windowed_bwd)
+_windowed.defvjp(_windowed_fwd, _windowed_bwd)
 
 
 def expert_layer(
@@ -338,9 +391,13 @@ def expert_layer(
     frames x top_k: one pass over the buffer of every pair); and the rows
     its collecting read in them, forward (the buffer's where it has fewer
     than the pairs, else every slot). With a smaller buffer the backward
-    pass computes the forward again."""
+    pass transposes the first window from what its forward kept (the
+    activation and the last product computed again), and computes the
+    whole forward again only in the windows past it, where a routing
+    skewed."""
     pairs = routing.experts.size
-    order, inverse, sizes, n_here = held_pairs(routing.experts, first, w_gate.shape[0])
+    order, inverse, sizes, n_here = checkpoint_name(held_pairs(routing.experts, first, w_gate.shape[0]),
+                                                    PASS_RESIDUALS)
     floats = (x, routing.weights, w_gate, w_up, w_down)
     if rows is None or rows >= pairs:
         y = _pass(pairs, impl, *floats, order, inverse, sizes, n_here)

@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dotaclient_tpu.ops import attention as A
+from dotaclient_tpu.ops import gated_delta as GD
 from dotaclient_tpu.ops import moe
 
 F, D, E, I, K = 37, 16, 8, 12, 3
@@ -101,6 +103,91 @@ def test_a_buffer_of_fewer_rows_against_the_loop_over_its_experts(rows, impl, pa
     # the smaller buffer holds a loop on the device, the buffer of every pair does not
     text = str(jax.make_jaxpr(lambda *a: _program(*a, 2, 4, rows=rows)[0])(*args))
     assert ("while" in text) == (rows is not None and rows < F * K)
+
+
+# The names the transformer block's remat saves where a layer has routed
+# experts (models/transformer_policy.py TransformerCore).
+BLOCK_NAMES = (A.FUSED_RESIDUALS, GD.RULE_RESIDUALS, moe.PASS_RESIDUALS)
+
+
+def _ops(jaxpr, name, looped=False):
+    """[outside any loop, inside one]: the equations of primitive `name`
+    in a jaxpr and the programs under it whose results something reads
+    (a `jax.vjp` computes its function forward, and where only the
+    transpose is read the compiler drops that)."""
+    found = [0, 0]
+    for eqn in jaxpr.eqns:
+        read = not all(isinstance(v, jax.core.DropVar) for v in eqn.outvars)
+        found[looped] += eqn.primitive.name == name and read
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = [a + b for a, b in zip(found, _ops(sub, name, looped or eqn.primitive.name == "while"))]
+    return found
+
+
+def _checkpointed_loss(names, rows):
+    layer = lambda *a: _program(*a, 2, 4, rows=rows)[0]
+    kept = jax.checkpoint(layer, policy=jax.checkpoint_policies.save_only_these_names(*names))
+    return lambda *a: jnp.sum(jnp.sin(kept(*a))), layer
+
+
+def _checkpointed_gradient(names, rows, args):
+    loss, layer = _checkpointed_loss(names, rows)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), layer
+
+
+@pytest.mark.parametrize("rows,passes", [(64, 1), (32, 1), (16, 2), (7, 5)])
+def test_under_the_blocks_remat_the_layer_runs_forward_once(rows, passes):
+    """Under `jax.checkpoint` with the block's policy the layer's value and
+    the gradients of x, the router and the three matrices are the
+    un-checkpointed layer's (to a rounding where the router's backward
+    reads the forward's scores and routing and not their recomputation)
+    and the written layer's, where the held pairs fit the buffer and where
+    they overflow it. Outside the loop over further windows the gradient's
+    program holds the first window's three grouped products forward, the
+    last of them once more (its rows, which the weights' cotangent reads,
+    are computed again and not kept), their six transposes, and one router
+    product, top_k and sort, the forward's: the remat runs none of them
+    again. Where the held pairs overflow, the loop computes each further
+    window forward (three products), and in the backward rule again with
+    its transposes (nine)."""
+    args = _weights(1)
+    kept, layer = _checkpointed_gradient(BLOCK_NAMES, rows, args)
+    bare = jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(layer(*a))), argnums=(0, 1, 2, 3, 4))
+    written = jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(_written(*a, 2, 4))), argnums=(0, 1, 2, 3, 4))
+    got, want, exact = (jax.tree.leaves(jax.jit(f)(*args)) for f in (kept, written, bare))
+    for a, b, c in zip(got, want, exact):
+        assert np.abs(np.asarray(a) - np.asarray(c)).max() <= 1e-6 * np.abs(np.asarray(c)).max()
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    assert float(_program(*args, 2, 4, rows=rows)[2]) == passes
+    jaxpr = jax.make_jaxpr(kept)(*args).jaxpr
+    assert _ops(jaxpr, "ragged_dot_general") == [3 + 1 + 6, 3 + 9]
+    assert [_ops(jaxpr, name)[0] for name in ("dot_general", "top_k", "sort")] == [1 + 2, 1, 2]
+
+
+@pytest.mark.parametrize("names,again", [(BLOCK_NAMES, 0), (BLOCK_NAMES[:2], 1)])
+def test_a_remat_that_leaves_the_name_out_runs_the_pass_again(names, again):
+    """The name is what keeps them: with it left out of the policy the
+    remat computes the first window's gate and up products, the router
+    product, top_k and the sort of the pairs a second time (the twelve
+    products a pass that the layer took before it kept them)."""
+    kept, _ = _checkpointed_gradient(names, 32, _weights(1))
+    jaxpr = jax.make_jaxpr(kept)(*_weights(1)).jaxpr
+    assert _ops(jaxpr, "ragged_dot_general")[0] == 10 + 2 * again
+    assert [_ops(jaxpr, name)[0] for name in ("dot_general", "top_k", "sort")] == [3 + again, 1 + again, 2 + again]
+
+
+@pytest.mark.parametrize("rows", [32, 16])
+def test_under_the_blocks_remat_the_layer_keeps_its_buffers_rows_and_no_matrix(rows, capsys):
+    """What the block's remat keeps of the layer, besides its inputs: the
+    scores, the routing and the sort, and of the buffer's rows three
+    arrays, the gathered rows and the gate and up products. No expert
+    matrix (its cast to the compute type is computed again) and not the
+    last product's rows."""
+    jax.ad_checkpoint.print_saved_residuals(_checkpointed_loss(BLOCK_NAMES, rows)[0], *_weights(1))
+    saved = [line.split(" ", 1) for line in capsys.readouterr().out.splitlines()]
+    shapes = [shape for shape, source in saved if not source.startswith("from the argument")]
+    assert sorted(s for s in shapes if s.startswith(f"f32[{rows},")) == [f"f32[{rows},{I}]"] * 2 + [f"f32[{rows},{D}]"]
+    assert all(s.count(",") < 2 for s in shapes), shapes
 
 
 def test_the_buffers_rows_follow_the_shapes():

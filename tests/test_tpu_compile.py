@@ -200,12 +200,17 @@ def test_expert_layer_compiles_at_published_widths(topo, impl):
     """The routed-expert layer of the benchmark's transformer cell, forward
     and backward at its real size (16,384 frames of 2,304, 16 of 64 experts
     of 896 held, 8 a frame) in its buffer of 40,960 rows, with either
-    grouped product: a kernel on the chip (twelve to a pass: forward, and
-    in the backward rule the forward again and its transpose; the bare
-    pass and the loop's turn each hold them), the choice between the two
-    and the loop on the device, and with both in it the scratch well under
-    what the buffer of every pair took (2.66 GB). The frames collect from
-    the buffer's rows: nothing of the 131,072 pairs is read row by row."""
+    grouped product: a kernel on the chip. The first window's pass holds
+    ten: three forward, and in the backward rule the last product again
+    (its rows, which the weights' cotangent reads, are not kept) and the
+    six transposes, which read the gathered rows and the gate and up
+    products that the forward kept. The loop over further windows, on the
+    device and with no turn where the held pairs fit, holds twelve: three
+    forward, and in the backward rule the forward again and its
+    transposes. No choice between them is compiled, and with the kept
+    arrays live the scratch stays well under what the buffer of every
+    pair took (2.66 GB). The frames collect from the buffer's rows:
+    nothing of the 131,072 pairs is read row by row."""
     from dotaclient_tpu.ops import moe
 
     frames, D, E, held, I, K = 16384, 2304, 64, 16, 896, 8
@@ -213,7 +218,10 @@ def test_expert_layer_compiles_at_published_widths(topo, impl):
     assert rows == 40960
     compiled = _expert_layer_compiled(topo, frames, D, E, held, I, K, impl, rows)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 24 and " conditional(" in text and " while(" in text
+    assert text.count("tpu_custom_call") >= 22 and " conditional(" not in text and " while(" in text
+    if impl == "megablox":
+        kernels = [n for n in re.findall(r'op_name="([^"]*)"', text) if n.endswith("pallas_call")]
+        assert len(kernels) == 22 and sum("/while/body/" not in n for n in kernels) == 10, kernels
     reads = _row_reads(text, D)
     assert ("scatter", rows) in reads and max(n for _, n in reads) == rows
     assert ("ragged-dot" in text) == (impl == "ragged_dot")
